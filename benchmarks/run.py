@@ -609,6 +609,8 @@ BENCHES = [fig3_latency_breakdown, fig4_swamping, fig5a_pim_designs,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for bench in BENCHES:
         bench()

@@ -122,7 +122,9 @@ def sr_bits(shape, seed, offset=0) -> jnp.ndarray:
 
 def _u32_to_unit(bits: jnp.ndarray) -> jnp.ndarray:
     """uint32 -> uniform in [0, 1)."""
-    return bits.astype(jnp.float32) * jnp.float32(2.0 ** -32)
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & 0xFFFF).astype(jnp.int32).astype(jnp.float32)
+    return (hi * jnp.float32(65536.0) + lo) * jnp.float32(2.0 ** -32)
 
 
 def _round(x: jnp.ndarray, rounding: str, bits: Optional[jnp.ndarray]) -> jnp.ndarray:
@@ -151,6 +153,58 @@ def _frexp_exponent(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(x > 0, e, -MX8_EXP_BIAS + 1).astype(jnp.int32)
 
 
+# The MX8 math below keeps every value on its own lane: the group and pair
+# reductions are xor-butterflies over the last axis (``jnp.roll`` + select),
+# and the per-group bytes move between lanes and groups through 0/1 selector
+# matmuls on small integers (exact in bf16).  No reshape splits the last
+# axis, so the same functions lower inside the TPU kernels, whose compiler
+# refuses lane-splitting reshapes, and on the host.
+
+def _lane_partner(x: jnp.ndarray, k: int) -> jnp.ndarray:
+    """``x[..., i ^ k]`` along the last axis, for ``k`` a power of two."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane & k) == 0, jnp.roll(x, -k, axis=-1),
+                     jnp.roll(x, k, axis=-1))
+
+
+def _pair_index(shape) -> jnp.ndarray:
+    """Pair position (0..7) of every lane inside its MX group."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane % MX8_GROUP) // MX8_PAIR
+
+
+def _group_selector(n: int, stride: int = 1) -> jnp.ndarray:
+    """(n, n/16) 0/1 matrix selecting, for group g, its lanes ``l`` with
+    ``l % stride == 0``."""
+    shape = (n, n // MX8_GROUP)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    sel = lane // MX8_GROUP == jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (sel & (lane % stride == 0)).astype(jnp.bfloat16)
+
+
+def _small_int_matmul(x: jnp.ndarray, sel: jnp.ndarray) -> jnp.ndarray:
+    """Contract the last axis of integer ``x`` (0..255) with a 0/1 selector;
+    every product and sum is an integer below 256, so bf16 inputs with f32
+    accumulation are exact."""
+    out = jax.lax.dot_general(
+        x.astype(jnp.float32).astype(jnp.bfloat16), sel,
+        (((x.ndim - 1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+    return out.astype(jnp.int32)
+
+
+def _pow2(k: jnp.ndarray) -> jnp.ndarray:
+    """``2.0 ** k`` in f32 for integer ``k`` in [-149, 127], built from its
+    bit pattern: exact on every backend (XLA:CPU's vectorized ``exp2`` is
+    not, and an inexact scale would make the quantizer path-dependent)."""
+    k = k.astype(jnp.int32)
+    normal = jax.lax.bitcast_convert_type(
+        jnp.left_shift(jnp.maximum(k + 127, 1), 23), jnp.float32)
+    subnormal = jax.lax.bitcast_convert_type(
+        jnp.left_shift(1, jnp.clip(k + 149, 0, 22)), jnp.float32)
+    return jnp.where(k >= -126, normal, subnormal)
+
+
 def mx8_quantize(x: jnp.ndarray, rounding: str = "nearest",
                  bits: Optional[jnp.ndarray] = None) -> QuantizedTensor:
     """Quantize to MX8 along the last axis (length must divide MX8_GROUP)."""
@@ -158,47 +212,44 @@ def mx8_quantize(x: jnp.ndarray, rounding: str = "nearest",
     n = x.shape[-1]
     assert n % MX8_GROUP == 0, f"last dim {n} not divisible by {MX8_GROUP}"
     xf = x.astype(jnp.float32)
-    g = xf.reshape(*x.shape[:-1], n // MX8_GROUP, MX8_GROUP)
-    gmax = jnp.max(jnp.abs(g), axis=-1)                       # (..., G)
+    pmax = jnp.abs(xf)
+    pmax = jnp.maximum(pmax, _lane_partner(pmax, 1))           # pair max
+    gmax = pmax
+    for k in (2, 4, 8):
+        gmax = jnp.maximum(gmax, _lane_partner(gmax, k))       # group max
     e = _frexp_exponent(gmax)                                  # shared exponent
     e = jnp.clip(e, -MX8_EXP_BIAS + 1, 127)
 
-    p = g.reshape(*g.shape[:-1], MX8_GROUP // MX8_PAIR, MX8_PAIR)
-    pmax = jnp.max(jnp.abs(p), axis=-1)                        # (..., G, 8)
     # micro-exponent: 1 => pair magnitudes fit in half the group range, so we
     # can shift the pair scale down one binade and gain a mantissa bit.
-    micro = (pmax < jnp.exp2((e - 1)[..., None].astype(jnp.float32))).astype(jnp.int32)
-    scale = jnp.exp2((e[..., None] - MX8_MBITS - micro).astype(jnp.float32))  # (...,G,8)
-    q = p / scale[..., None]
-    if bits is not None:
-        bits = bits.reshape(p.shape)
-    q = _round(q, rounding, bits)
-    q = jnp.clip(q, -63, 63).astype(jnp.int8)
+    micro = (pmax < _pow2(e - 1)).astype(jnp.int32)
+    scale = _pow2(e - MX8_MBITS - micro)
+    q = _round(xf / scale, rounding, bits)
+    mant = jnp.clip(q, -63, 63).astype(jnp.int8)
 
-    mant = q.reshape(*x.shape[:-1], n)
-    exp_stored = (e + MX8_EXP_BIAS).astype(jnp.uint8)
-    # pack the 8 pair-bits of each group into one byte (iota-based so the
-    # same code can run inside Pallas kernel bodies)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, micro.shape, micro.ndim - 1)
-    micro_packed = jnp.sum(
-        jnp.left_shift(micro.astype(jnp.uint32), shifts), axis=-1).astype(jnp.uint8)
+    # one lane per group carries the exponent; one lane per pair its bit,
+    # shifted into place so the group's sum is its packed micro byte
+    exp_stored = _small_int_matmul(e + MX8_EXP_BIAS,
+                                   _group_selector(n, MX8_GROUP))
+    micro_packed = _small_int_matmul(
+        jnp.left_shift(micro, _pair_index(x.shape)),
+        _group_selector(n, MX8_PAIR))
     return QuantizedTensor("mx8", orig_shape, {
-        "mantissa": mant, "exponent": exp_stored, "micro": micro_packed,
+        "mantissa": mant, "exponent": exp_stored.astype(jnp.uint8),
+        "micro": micro_packed.astype(jnp.uint8),
     })
 
 
 def mx8_dequantize(qt: QuantizedTensor) -> jnp.ndarray:
     mant = qt.payload["mantissa"].astype(jnp.float32)
-    e = qt.payload["exponent"].astype(jnp.int32) - MX8_EXP_BIAS   # (..., G)
-    mp = qt.payload["micro"].astype(jnp.int32)                     # (..., G)
-    bshape = mp.shape + (MX8_GROUP // MX8_PAIR,)
-    shifts = jax.lax.broadcasted_iota(jnp.int32, bshape, mp.ndim)
-    micro = (mp[..., None] >> shifts) & 1                          # (..., G, 8)
-    scale = jnp.exp2((e[..., None] - MX8_MBITS - micro).astype(jnp.float32))
     n = qt.shape[-1]
-    p = mant.reshape(*mant.shape[:-1], n // MX8_GROUP, MX8_GROUP // MX8_PAIR, MX8_PAIR)
-    out = p * scale[..., None]
-    return out.reshape(qt.shape)
+    expand = _group_selector(n).T                                 # (G, n)
+    e = _small_int_matmul(qt.payload["exponent"].astype(jnp.int32),
+                          expand) - MX8_EXP_BIAS
+    mp = _small_int_matmul(qt.payload["micro"].astype(jnp.int32), expand)
+    micro = (mp >> _pair_index(mant.shape)) & 1
+    scale = _pow2(e - MX8_MBITS - micro)
+    return (mant * scale).reshape(qt.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,7 @@
 """Distribution layer: mesh-role description, sharding builders, and
-compressed collectives.
-
-Importing this package also backfills the handful of new-jax APIs the
-sharded call sites use (``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.make_mesh(axis_types=...)``) when running on the pinned jax 0.4.x
--- see :mod:`repro.dist.compat`.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
-
-from repro.dist import compression  # noqa: E402,F401
-from repro.dist.sharding import (  # noqa: E402,F401
+compressed collectives."""
+from repro.dist import compression  # noqa: F401
+from repro.dist.sharding import (  # noqa: F401
     Parallel,
     batch_shardings,
     cache_shardings,

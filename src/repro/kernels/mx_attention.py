@@ -13,17 +13,22 @@ the paper's two-phase GPU⇄PIM handoff (score -> host softmax -> attend):
 
 GQA is handled by processing all G = H / KV_heads query heads of a KV head
 together against each KV tile (operand reuse across the chunk group, the
-analogue of Pimba broadcasting shared operands once per chunk group).
+analogue of Pimba broadcasting shared operands once per chunk group).  One
+grid step reads the tile of *every* KV head of a row: the cache is stored
+``(..., t, KVH, d)``, and a TPU block must span its last two dims.
 
 MLA mode (DeepSeek-V2): the cache is a single compressed latent stream; the
 same tiles serve as keys (full width) and values (first ``v_width`` lanes),
-so pass ``v_width`` and leave the V refs aliased to the K refs at call site
-is not needed -- the kernel reads the K refs for both phases.
+so no V operand is passed -- the kernel reads the K refs for both phases.
+
+:func:`flash_decode` is the one kernel body and ``pallas_call`` shared by
+dense decode (here), paged decode (:mod:`repro.kernels.mx_paged_attention`)
+and speculative verify (:mod:`repro.kernels.mx_spec_attention`).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 
-MXG = F.MX8_GROUP
 NEG_INF = -1e30
 
 
@@ -42,16 +46,27 @@ def _deq(mant, exp, micro):
     return F.mx8_dequantize(qt)
 
 
-def _attn_kernel(
-    # inputs
-    len_ref, q_ref, km_ref, ke_ref, kmi_ref, vm_ref, ve_ref, vmi_ref,
-    # outputs
-    y_ref,
-    # scratch
-    m_scr, l_scr, acc_scr,
-    *, t_blk: int, n_t: int, v_width: int, mla: bool,
-):
-    t = pl.program_id(2)
+def _payload(qt: F.QuantizedTensor) -> Tuple[jnp.ndarray, ...]:
+    return qt.payload["mantissa"], qt.payload["exponent"], qt.payload["micro"]
+
+
+def _flash_kernel(*refs, n_prefetch: int, paged: bool, mla: bool, t_blk: int,
+                  n_t: int, n_q: int, g: int, v_width: int):
+    """One KV tile of streaming-softmax attention for every KV head of a row.
+
+    The query block is ``(KVH, n_q*g, dk)``: query row ``r`` belongs to
+    draft position ``r // g`` and sees positions ``< len - (n_q-1 - r//g)``
+    (``n_q == 1`` is plain decode).  The tile of all heads is dequantized at
+    once into VMEM scratch; a loop over heads then runs the flash update.
+    """
+    lens_ref = refs[n_prefetch - 1]
+    q_ref, *rest = refs[n_prefetch:]
+    n_kv = 3 if mla else 6
+    kv_refs, (y_ref, m_scr, l_scr, acc_scr, *kv_scr) = (rest[:n_kv],
+                                                         rest[n_kv:])
+    b, t = pl.program_id(0), pl.program_id(1)
+    length = lens_ref[b]
+    n_heads = q_ref.shape[1]
 
     @pl.when(t == 0)
     def _init():
@@ -59,36 +74,106 @@ def _attn_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qv = q_ref[0, 0].astype(jnp.float32)                        # (G, dk)
-    K = _deq(km_ref[0, :, 0, :], ke_ref[0, :, 0, :], kmi_ref[0, :, 0, :])
-    if mla:
-        V = K[:, :v_width]
-    else:
-        V = _deq(vm_ref[0, :, 0, :], ve_ref[0, :, 0, :], vmi_ref[0, :, 0, :])
+    for refs3, scr in zip((kv_refs[:3], kv_refs[3:]), kv_scr):
+        # (t_blk, KVH, w) payloads -> one 2-D dequantize -> f32 scratch
+        parts = [(r[0, 0] if paged else r[0]) for r in refs3]
+        flat = [a.reshape(t_blk * n_heads, a.shape[-1]) for a in parts]
+        scr[...] = _deq(*flat).reshape(scr.shape)
 
-    scores = jax.lax.dot_general(
-        qv, K, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (G, t_blk)
-    pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + t * t_blk
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid, scores, NEG_INF)
+    def head(h, carry):
+        K = kv_scr[0][:, pl.ds(h, 1), :].reshape(t_blk, -1)   # (t_blk, dk)
+        V = (K[:, :v_width] if mla
+             else kv_scr[1][:, pl.ds(h, 1), :].reshape(t_blk, -1))
+        qv = q_ref[0, h].astype(jnp.float32)                   # (n_q*g, dk)
+        scores = jax.lax.dot_general(
+            qv, K, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (n_q*g, t_blk)
+        pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + t * t_blk
+        qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g
+        valid = pos < length - (n_q - 1 - qidx)
+        scores = jnp.where(valid, scores, NEG_INF)
 
-    m_prev = m_scr[...]                                         # (G, 1)
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                                 # (G, t_blk)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, V, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (G, dv)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_scr[...] * alpha + pv
+        m_prev = m_scr[h]                                      # (n_q*g, 1)
+        l_prev = l_scr[h]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, V, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (n_q*g, dv)
+        m_scr[h] = m_new
+        l_scr[h] = l_new
+        acc_scr[h] = acc_scr[h] * alpha + pv
+        return carry
+
+    jax.lax.fori_loop(0, n_heads, head, 0)
 
     @pl.when(t == n_t - 1)
     def _finish():
-        y_ref[0, 0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        y_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+
+
+def flash_decode(
+    qg: jnp.ndarray,                     # (B, KVH, n_q*g, dk) scaled queries
+    k: F.QuantizedTensor,                # dense (B, T, KVH, dk) | pool (P, G, 128, KVH, dk)
+    v: Optional[F.QuantizedTensor],      # like k; None => MLA
+    lengths: jnp.ndarray,                # (B,) valid length incl. the n_q rows
+    *, n_q: int, v_width: int, interpret: bool, name: str,
+    t_block: int = 128, pages: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+) -> jnp.ndarray:
+    """Shared flash-decoding ``pallas_call``; returns (B, KVH, n_q*g, dv).
+
+    Dense caches tile the time axis in ``t_block`` steps.  With ``pages =
+    (bt, group)`` the caches are page pools and the grid's time axis walks
+    the scalar-prefetched block table ``bt[B, npg]`` instead, one 128-token
+    page per step, so no dense copy of the context exists.  ``name`` is the
+    kernel's name in the compiled program (its SPU op kind).
+    """
+    B, KVH, QG, dk = qg.shape
+    mla = v is None
+    lens = lengths.astype(jnp.int32).reshape(B)
+    parts: Sequence[jnp.ndarray] = _payload(k) + (() if mla else _payload(v))
+    if pages is None:
+        T = k.shape[1]
+        assert T % t_block == 0
+        n_t = T // t_block
+        prefetch = (lens,)
+        kv_map = lambda b, t, *_: (b, t, 0, 0)
+        kv_block = lambda w: (1, t_block, KVH, w)
+    else:
+        bt, group = pages
+        t_block, n_t = k.payload["mantissa"].shape[2], int(bt.shape[1])
+        prefetch = (bt, jnp.asarray(group, jnp.int32).reshape(1), lens)
+        kv_map = lambda b, t, bt_ref, g_ref, _: (bt_ref[b, t], g_ref[0],
+                                                 0, 0, 0)
+        kv_block = lambda w: (1, 1, t_block, KVH, w)
+    dv = v_width if mla else v.payload["mantissa"].shape[-1]
+
+    kernel = functools.partial(
+        _flash_kernel, n_prefetch=len(prefetch), paged=pages is not None,
+        mla=mla, t_blk=t_block, n_t=n_t, n_q=n_q, g=QG // n_q, v_width=dv)
+    row_map = lambda b, t, *_: (b, 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, n_t),
+        in_specs=[pl.BlockSpec((1, KVH, QG, dk), row_map)]
+        + [pl.BlockSpec(kv_block(a.shape[-1]), kv_map) for a in parts],
+        out_specs=pl.BlockSpec((1, KVH, QG, dv), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((KVH, QG, 1), jnp.float32),
+            pltpu.VMEM((KVH, QG, 1), jnp.float32),
+            pltpu.VMEM((KVH, QG, dv), jnp.float32),
+        ] + [pltpu.VMEM((t_block, KVH, parts[i].shape[-1]), jnp.float32)
+             for i in ((0,) if mla else (0, 3))],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KVH, QG, dv), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, qg.astype(jnp.float32), *parts)
 
 
 @functools.partial(
@@ -101,62 +186,16 @@ def mx_attention_decode(
     qV: Optional[F.QuantizedTensor],  # (B, T, KVH, dv) packed values; None => MLA
     lengths: jnp.ndarray,           # (B,) int32 valid cache length
     *, scale: Optional[float] = None, v_width: Optional[int] = None,
-    t_block: int = 128, interpret: bool = True,
+    t_block: int = 128, interpret: bool,
 ) -> jnp.ndarray:
     """Fused decode attention; returns (B, H, dv) f32."""
     B, H, dk = q.shape
-    _, T, KVH, dkc = qK.shape
-    assert dk == dkc and H % KVH == 0 and T % t_block == 0
-    G = H // KVH
-    n_t = T // t_block
-    mla = qV is None
-    dv = v_width if mla else qV.shape[-1]
-    assert dv is not None
-
+    KVH = qK.shape[2]
+    assert dk == qK.shape[3] and H % KVH == 0
+    assert qV is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
-    qg = (q.astype(jnp.float32) * scale).reshape(B, KVH, G, dk)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
-
-    km = qK.payload["mantissa"]
-    ke = qK.payload["exponent"]
-    kmi = qK.payload["micro"]
-    if mla:
-        vm, ve, vmi = km[:, :1], ke[:, :1], kmi[:, :1]   # dummies (unused)
-        vgroups = dkc // MXG
-    else:
-        vm = qV.payload["mantissa"]
-        ve = qV.payload["exponent"]
-        vmi = qV.payload["micro"]
-        vgroups = dv // MXG
-
-    v_t_blk = 1 if mla else t_block
-    kernel = functools.partial(
-        _attn_kernel, t_blk=t_block, n_t=n_t, v_width=dv, mla=mla)
-
-    y = pl.pallas_call(
-        kernel,
-        grid=(B, KVH, n_t),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, t: (b, 0)),                    # len
-            pl.BlockSpec((1, 1, G, dk), lambda b, h, t: (b, h, 0, 0)),       # q
-            pl.BlockSpec((1, t_block, 1, dk), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, t_block, 1, dk // MXG), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, t_block, 1, dk // MXG), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups * MXG),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, dv), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, dv), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lens, qg, km, ke, kmi, vm, ve, vmi)
-
-    return y.reshape(B, H, dv)
+    qg = (q.astype(jnp.float32) * scale).reshape(B, KVH, H // KVH, dk)
+    y = flash_decode(qg, qK, qV, lengths, n_q=1, v_width=v_width,
+                     t_block=t_block, interpret=interpret,
+                     name="spu_attn_decode")
+    return y.reshape(B, H, -1)

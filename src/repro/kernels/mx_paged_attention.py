@@ -25,10 +25,12 @@ can walk the block table directly:
     Writes the new token's already-quantized K/V payload rows into their
     page slot ``pool[bt[b, len//128], g, len%128]`` in place via
     ``input_output_aliases`` -- the software analogue of the PIM
-    read-modify-write of a single DRAM column, and the reason the steady
-    state decode loop moves one row, not the whole pool.
+    read-modify-write of a single DRAM column: the kernel moves one row,
+    not the whole pool.  (On a TPU, XLA still copies the pools into the
+    kernels' row-major layout around the decode step; see PERF.md.)
 
-Both run ``interpret=True`` on CPU; quantization math is shared with
+Both run in interpret mode on the CPU and compiled on a TPU
+(:mod:`repro.ops.platform` decides); quantization math is shared with
 :mod:`repro.core.formats`, so results match the jnp reference bitwise.
 """
 from __future__ import annotations
@@ -43,62 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 from repro.core.paged import PAGE_TOKENS
-from repro.kernels.mx_attention import NEG_INF, _deq
-
-MXG = F.MX8_GROUP
-
-
-def _paged_attn_kernel(
-    # scalar prefetch
-    bt_ref, grp_ref,
-    # inputs
-    len_ref, q_ref, km_ref, ke_ref, kmi_ref, vm_ref, ve_ref, vmi_ref,
-    # outputs
-    y_ref,
-    # scratch
-    m_scr, l_scr, acc_scr,
-    *, t_blk: int, n_t: int, v_width: int, mla: bool,
-):
-    t = pl.program_id(2)
-
-    @pl.when(t == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    qv = q_ref[0, 0].astype(jnp.float32)                        # (G, dk)
-    K = _deq(km_ref[0, 0, :, 0, :], ke_ref[0, 0, :, 0, :],
-             kmi_ref[0, 0, :, 0, :])                            # (t_blk, dk)
-    if mla:
-        V = K[:, :v_width]
-    else:
-        V = _deq(vm_ref[0, 0, :, 0, :], ve_ref[0, 0, :, 0, :],
-                 vmi_ref[0, 0, :, 0, :])
-
-    scores = jax.lax.dot_general(
-        qv, K, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (G, t_blk)
-    pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + t * t_blk
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid, scores, NEG_INF)
-
-    m_prev = m_scr[...]                                         # (G, 1)
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                                 # (G, t_blk)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, V, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (G, dv)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_scr[...] * alpha + pv
-
-    @pl.when(t == n_t - 1)
-    def _finish():
-        y_ref[0, 0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+from repro.kernels.mx_attention import flash_decode
 
 
 @functools.partial(
@@ -113,7 +60,7 @@ def mx_paged_attention_decode(
     group,                          # () int32 stacked-layer index
     lengths: jnp.ndarray,           # (B,) int32 valid cache length
     *, scale: Optional[float] = None, v_width: Optional[int] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused paged decode attention; returns (B, H, dv) f32.
 
@@ -121,78 +68,28 @@ def mx_paged_attention_decode(
     of the same pages (same tile order, same flash accumulators).
     """
     B, H, dk = q.shape
-    km = k_pool.payload["mantissa"]
-    P, G, TB, KVH, dkc = km.shape
+    P, G, TB, KVH, dkc = k_pool.payload["mantissa"].shape
     assert dk == dkc and H % KVH == 0 and TB == PAGE_TOKENS
-    Gq = H // KVH
-    npg = int(bt.shape[1])
-    mla = v_pool is None
-    dv = v_width if mla else v_pool.payload["mantissa"].shape[-1]
-    assert dv is not None
-
+    assert v_pool is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
-    qg = (q.astype(jnp.float32) * scale).reshape(B, KVH, Gq, dk)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
-    grp = jnp.asarray(group, jnp.int32).reshape(1)
-
-    ke, kmi = k_pool.payload["exponent"], k_pool.payload["micro"]
-    if mla:
-        vm, ve, vmi = km, ke, kmi        # dummies (kernel reads K for V)
-        v_blk, vgroups = 1, dkc // MXG
-    else:
-        vm = v_pool.payload["mantissa"]
-        ve, vmi = v_pool.payload["exponent"], v_pool.payload["micro"]
-        v_blk, vgroups = TB, dv // MXG
-
-    # index maps see (grid indices..., *scalar-prefetch refs): the page id
-    # comes straight off the prefetched block table, the stacked-layer
-    # coordinate off the prefetched group index
-    kpage = lambda b, h, t, bt_ref, g_ref: (bt_ref[b, t], g_ref[0], 0, h, 0)
-    vpage = ((lambda b, h, t, bt_ref, g_ref: (0, 0, 0, h, 0)) if mla
-             else kpage)
-
-    kernel = functools.partial(_paged_attn_kernel, t_blk=TB, n_t=npg,
-                               v_width=dv, mla=mla)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KVH, npg),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, t, *_: (b, 0)),            # len
-            pl.BlockSpec((1, 1, Gq, dk), lambda b, h, t, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, TB, 1, dk), kpage),                      # km
-            pl.BlockSpec((1, 1, TB, 1, dk // MXG), kpage),               # ke
-            pl.BlockSpec((1, 1, TB, 1, dk // MXG), kpage),               # kmi
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups * MXG), vpage),        # vm
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups), vpage),              # ve
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups), vpage),              # vmi
-        ],
-        out_specs=pl.BlockSpec((1, 1, Gq, dv), lambda b, h, t, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Gq, 1), jnp.float32),
-            pltpu.VMEM((Gq, 1), jnp.float32),
-            pltpu.VMEM((Gq, dv), jnp.float32),
-        ],
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, Gq, dv), jnp.float32),
-        interpret=interpret,
-    )(bt, grp, lens, qg, km, ke, kmi, vm, ve, vmi)
-    return y.reshape(B, H, dv)
+    qg = (q.astype(jnp.float32) * scale).reshape(B, KVH, H // KVH, dk)
+    y = flash_decode(qg, k_pool, v_pool, lengths, n_q=1, v_width=v_width,
+                     pages=(bt, group), interpret=interpret,
+                     name="spu_attn_decode")
+    return y.reshape(B, H, -1)
 
 
 # ---------------------------------------------------------------------------
 # in-place paged token append
 # ---------------------------------------------------------------------------
 
-def _append_kernel(bt_ref, pos_ref, grp_ref, *refs):
+def _append_kernel(page_ref, slot_ref, grp_ref, *refs):
     """Write each row's new-token block into its page slot (one column)."""
     n = len(refs) // 3
-    val_refs, pool_refs, out_refs = refs[:n], refs[n:2 * n], refs[2 * n:]
-    del pool_refs  # aliased storage; present only to seed the outputs
+    val_refs, out_refs = refs[:n], refs[2 * n:]
+    # the aliased pools (refs[n:2n]) stay in HBM, unread
     for v_ref, o_ref in zip(val_refs, out_refs):
-        o_ref[0, 0, 0] = v_ref[0]
+        o_ref[...] = v_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -202,13 +99,16 @@ def mx_paged_kv_append(
     bt: jnp.ndarray,                # (B, npg) int32
     group,                          # () int32
     lengths: jnp.ndarray,           # (B,) append position per row
-    *, interpret: bool = True,
+    *, interpret: bool,
 ) -> Tuple[jnp.ndarray, ...]:
     """Scatter one token's payload rows into their page slots in place.
 
     The pools are aliased input->output (``input_output_aliases``), so the
     unwritten 99.9% of every pool is never touched -- the paged analogue of
-    the dense path's full-cache scatter, at one-slot write traffic.
+    the dense path's full-cache scatter, at one-slot write traffic.  Each
+    row's page and slot are computed here and scalar-prefetched as flat
+    ``(B,)`` vectors: an index map that looked the page up in the 2-D block
+    table at a data-dependent column halted the TPU (bad SMEM address).
     """
     pools = tuple(pools)
     rows = tuple(rows)
@@ -217,16 +117,17 @@ def mx_paged_kv_append(
     P, G, TB, KVH, _ = pools[0].shape
     assert TB == PAGE_TOKENS
     pos = lengths.astype(jnp.int32)
+    page = bt[jnp.arange(B), pos // TB]
     grp = jnp.asarray(group, jnp.int32).reshape(1)
 
-    def slot(b, bt_ref, pos_ref, g_ref):
-        return (bt_ref[b, pos_ref[b] // TB], g_ref[0], pos_ref[b] % TB, 0, 0)
+    def slot(b, page_ref, slot_ref, g_ref):
+        return (page_ref[b], g_ref[0], slot_ref[b], 0, 0)
 
     n = len(pools)
     in_specs = (
-        [pl.BlockSpec((1, KVH, r.shape[-1]), lambda b, *_: (b, 0, 0))
-         for r in rows]
-        + [pl.BlockSpec((1, 1, 1, KVH, p.shape[-1]), slot) for p in pools])
+        [pl.BlockSpec((1, 1, 1, KVH, r.shape[-1]),
+                      lambda b, *_: (b, 0, 0, 0, 0)) for r in rows]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n)
     out_specs = [pl.BlockSpec((1, 1, 1, KVH, p.shape[-1]), slot)
                  for p in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -242,5 +143,7 @@ def mx_paged_kv_append(
         # alias pool i (input index: 3 scalars + n value rows + i) to out i
         input_output_aliases={3 + n + i: i for i in range(n)},
         interpret=interpret,
-    )(bt, pos, grp, *rows, *pools)
+        name="spu_kv_append",
+    )(page, pos % TB, grp, *(r.reshape((B, 1, 1) + r.shape[1:]) for r in rows),
+      *pools)
     return tuple(out)

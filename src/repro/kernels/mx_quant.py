@@ -33,7 +33,7 @@ def _quant_kernel(seed_ref, x_ref, m_ref, e_ref, mi_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("rounding", "interpret", "row_block"))
 def mx_quantize(x: jnp.ndarray, seed=0, *, rounding: str = "nearest",
-                row_block: int = 256, interpret: bool = True) -> F.QuantizedTensor:
+                row_block: int = 64, interpret: bool) -> F.QuantizedTensor:
     """Quantize a 2D-reshapeable array to MX8 (groups along the last axis)."""
     orig_shape = x.shape
     cols = x.shape[-1]
@@ -68,6 +68,7 @@ def mx_quantize(x: jnp.ndarray, seed=0, *, rounding: str = "nearest",
             jax.ShapeDtypeStruct((x2.shape[0], cols // MXG), jnp.uint8),
         ],
         interpret=interpret,
+        name="spu_mx_quantize",
     )(seed_arr, x2)
 
     if pad:

@@ -5,19 +5,19 @@ already holds the ``Kq`` appended rows, and query position ``j`` attends over
 every position strictly before ``lengths - (Kq-1-j)`` (its own row included).
 ``Kq == 1`` degenerates exactly to the plain decode kernels.
 
-Both kernels reuse the flash score -> streaming-softmax -> attend pipeline of
-:mod:`repro.kernels.mx_attention` / :mod:`repro.kernels.mx_paged_attention`
-by folding the query axis into the GQA group axis: the query block becomes
-``(Kq*G, dk)`` and the VMEM accumulators ``(Kq*G, .)``, so every query row
-keeps its own private max/sum/acc lane.  Row-wise the arithmetic is
-identical to running the single-query kernel once per position with the
-per-position length -- which is what makes greedy speculative decode
-bit-identical to sequential decode.
+Both kernels run the flash score -> streaming-softmax -> attend pipeline of
+:func:`repro.kernels.mx_attention.flash_decode` (dense, or paged over the
+block table) by folding the query axis into the GQA group axis: the query
+block becomes ``(Kq*G, dk)`` and the VMEM accumulators ``(Kq*G, .)``, so
+every query row keeps its own private max/sum/acc lane.  Row-wise the
+arithmetic is identical to running the single-query kernel once per position
+with the per-position length -- which is what makes greedy speculative
+decode bit-identical to sequential decode.
 
-The bandwidth story (paper §3, ISSUE 10): the K/V pages stream through the
-grid ONCE for all ``Kq`` queries -- the verify pass re-reads the same bytes
-one decode step does, amortized over the drafted tokens.  That is the whole
-reason speculation is nearly free in the memory-bound decode regime.
+The bandwidth story (paper §3): the K/V pages stream through the grid ONCE
+for all ``Kq`` queries -- the verify pass re-reads the same bytes one decode
+step does, amortized over the drafted tokens.  That is the whole reason
+speculation is nearly free in the memory-bound decode regime.
 """
 from __future__ import annotations
 
@@ -26,80 +26,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats as F
 from repro.core.paged import PAGE_TOKENS
-from repro.kernels.mx_attention import NEG_INF, _deq
-
-MXG = F.MX8_GROUP
-
-
-def _spec_attn_body(len_ref, q_ref, K, V, y_ref, m_scr, l_scr, acc_scr,
-                    *, t: int, t_blk: int, n_t: int, n_q: int, g: int):
-    """Shared flash body over a ``(n_q*g, dk)`` query block: query row ``r``
-    belongs to draft position ``r // g`` and masks positions accordingly."""
-    @pl.when(t == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    qv = q_ref[0, 0].astype(jnp.float32)                    # (n_q*g, dk)
-    scores = jax.lax.dot_general(
-        qv, K, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (n_q*g, t_blk)
-    pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + t * t_blk
-    qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g
-    valid = pos < len_ref[0, 0] - (n_q - 1 - qidx)
-    scores = jnp.where(valid, scores, NEG_INF)
-
-    m_prev = m_scr[...]                                     # (n_q*g, 1)
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, V, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (n_q*g, dv)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-    acc_scr[...] = acc_scr[...] * alpha + pv
-
-    @pl.when(t == n_t - 1)
-    def _finish():
-        y_ref[0, 0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-
-
-def _spec_kernel(len_ref, q_ref, km_ref, ke_ref, kmi_ref,
-                 vm_ref, ve_ref, vmi_ref, y_ref, m_scr, l_scr, acc_scr,
-                 *, t_blk, n_t, n_q, g, v_width, mla):
-    t = pl.program_id(2)
-    K = _deq(km_ref[0, :, 0, :], ke_ref[0, :, 0, :], kmi_ref[0, :, 0, :])
-    if mla:
-        V = K[:, :v_width]
-    else:
-        V = _deq(vm_ref[0, :, 0, :], ve_ref[0, :, 0, :], vmi_ref[0, :, 0, :])
-    _spec_attn_body(len_ref, q_ref, K, V, y_ref, m_scr, l_scr, acc_scr,
-                    t=t, t_blk=t_blk, n_t=n_t, n_q=n_q, g=g)
-
-
-def _paged_spec_kernel(bt_ref, grp_ref, len_ref, q_ref, km_ref, ke_ref,
-                       kmi_ref, vm_ref, ve_ref, vmi_ref, y_ref,
-                       m_scr, l_scr, acc_scr,
-                       *, t_blk, n_t, n_q, g, v_width, mla):
-    t = pl.program_id(2)
-    K = _deq(km_ref[0, 0, :, 0, :], ke_ref[0, 0, :, 0, :],
-             kmi_ref[0, 0, :, 0, :])
-    if mla:
-        V = K[:, :v_width]
-    else:
-        V = _deq(vm_ref[0, 0, :, 0, :], ve_ref[0, 0, :, 0, :],
-                 vmi_ref[0, 0, :, 0, :])
-    _spec_attn_body(len_ref, q_ref, K, V, y_ref, m_scr, l_scr, acc_scr,
-                    t=t, t_blk=t_blk, n_t=n_t, n_q=n_q, g=g)
+from repro.kernels.mx_attention import flash_decode
 
 
 def _fold_queries(q: jnp.ndarray, KVH: int, scale: float) -> jnp.ndarray:
@@ -126,65 +56,17 @@ def mx_spec_attention_decode(
     qV: Optional[F.QuantizedTensor],  # packed values; None => MLA
     lengths: jnp.ndarray,           # (B,) valid length INCLUDING the Kq rows
     *, scale: Optional[float] = None, v_width: Optional[int] = None,
-    t_block: int = 128, interpret: bool = True,
+    t_block: int = 128, interpret: bool,
 ) -> jnp.ndarray:
     """Fused dense spec-verify attention; returns (B, Kq, H, dv) f32."""
     B, Kq, H, dk = q.shape
-    _, T, KVH, dkc = qK.shape
-    assert dk == dkc and H % KVH == 0 and T % t_block == 0
-    G = H // KVH
-    n_t = T // t_block
-    mla = qV is None
-    dv = v_width if mla else qV.shape[-1]
-    assert dv is not None
-
+    KVH = qK.shape[2]
+    assert dk == qK.shape[3] and H % KVH == 0
+    assert qV is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
-    qg = _fold_queries(q, KVH, scale)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
-
-    km = qK.payload["mantissa"]
-    ke = qK.payload["exponent"]
-    kmi = qK.payload["micro"]
-    if mla:
-        vm, ve, vmi = km[:, :1], ke[:, :1], kmi[:, :1]
-        vgroups = dkc // MXG
-    else:
-        vm = qV.payload["mantissa"]
-        ve = qV.payload["exponent"]
-        vmi = qV.payload["micro"]
-        vgroups = dv // MXG
-    v_t_blk = 1 if mla else t_block
-    QG = Kq * G
-
-    kernel = functools.partial(_spec_kernel, t_blk=t_block, n_t=n_t,
-                               n_q=Kq, g=G, v_width=dv, mla=mla)
-    y = pl.pallas_call(
-        kernel,
-        grid=(B, KVH, n_t),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, t: (b, 0)),
-            pl.BlockSpec((1, 1, QG, dk), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, t_block, 1, dk), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, t_block, 1, dk // MXG),
-                         lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, t_block, 1, dk // MXG),
-                         lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups * MXG),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-            pl.BlockSpec((1, v_t_blk, 1, vgroups),
-                         lambda b, h, t: (b, 0 if v_t_blk == 1 else t, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, QG, dv), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, QG, dv), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((QG, 1), jnp.float32),
-            pltpu.VMEM((QG, 1), jnp.float32),
-            pltpu.VMEM((QG, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lens, qg, km, ke, kmi, vm, ve, vmi)
+    y = flash_decode(_fold_queries(q, KVH, scale), qK, qV, lengths, n_q=Kq,
+                     v_width=v_width, t_block=t_block, interpret=interpret,
+                     name="spu_spec_verify")
     return _unfold_outputs(y, Kq)
 
 
@@ -198,70 +80,20 @@ def mx_paged_spec_attention_decode(
     group,                          # () int32 stacked-layer index
     lengths: jnp.ndarray,           # (B,) valid length INCLUDING the Kq rows
     *, scale: Optional[float] = None, v_width: Optional[int] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Fused paged spec-verify attention; returns (B, Kq, H, dv) f32.
 
     The pages stream through the grid once for all ``Kq`` queries: the grid
-    is the same ``(B, KVH, npg)`` as the single-query paged kernel, only the
+    is the same ``(B, npg)`` as the single-query paged kernel, only the
     query block and the VMEM accumulators widen by ``Kq``.
     """
     B, Kq, H, dk = q.shape
-    km = k_pool.payload["mantissa"]
-    P, G, TB, KVH, dkc = km.shape
+    P, G, TB, KVH, dkc = k_pool.payload["mantissa"].shape
     assert dk == dkc and H % KVH == 0 and TB == PAGE_TOKENS
-    Gq = H // KVH
-    npg = int(bt.shape[1])
-    mla = v_pool is None
-    dv = v_width if mla else v_pool.payload["mantissa"].shape[-1]
-    assert dv is not None
-
+    assert v_pool is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
-    qg = _fold_queries(q, KVH, scale)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
-    grp = jnp.asarray(group, jnp.int32).reshape(1)
-
-    ke, kmi = k_pool.payload["exponent"], k_pool.payload["micro"]
-    if mla:
-        vm, ve, vmi = km, ke, kmi
-        v_blk, vgroups = 1, dkc // MXG
-    else:
-        vm = v_pool.payload["mantissa"]
-        ve, vmi = v_pool.payload["exponent"], v_pool.payload["micro"]
-        v_blk, vgroups = TB, dv // MXG
-
-    kpage = lambda b, h, t, bt_ref, g_ref: (bt_ref[b, t], g_ref[0], 0, h, 0)
-    vpage = ((lambda b, h, t, bt_ref, g_ref: (0, 0, 0, h, 0)) if mla
-             else kpage)
-    QG = Kq * Gq
-
-    kernel = functools.partial(_paged_spec_kernel, t_blk=TB, n_t=npg,
-                               n_q=Kq, g=Gq, v_width=dv, mla=mla)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KVH, npg),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, t, *_: (b, 0)),
-            pl.BlockSpec((1, 1, QG, dk), lambda b, h, t, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, TB, 1, dk), kpage),
-            pl.BlockSpec((1, 1, TB, 1, dk // MXG), kpage),
-            pl.BlockSpec((1, 1, TB, 1, dk // MXG), kpage),
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups * MXG), vpage),
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups), vpage),
-            pl.BlockSpec((1, 1, v_blk, 1, vgroups), vpage),
-        ],
-        out_specs=pl.BlockSpec((1, 1, QG, dv),
-                               lambda b, h, t, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((QG, 1), jnp.float32),
-            pltpu.VMEM((QG, 1), jnp.float32),
-            pltpu.VMEM((QG, dv), jnp.float32),
-        ],
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, QG, dv), jnp.float32),
-        interpret=interpret,
-    )(bt, grp, lens, qg, km, ke, kmi, vm, ve, vmi)
+    y = flash_decode(_fold_queries(q, KVH, scale), k_pool, v_pool, lengths,
+                     n_q=Kq, v_width=v_width, pages=(bt, group),
+                     interpret=interpret, name="spu_spec_verify")
     return _unfold_outputs(y, Kq)

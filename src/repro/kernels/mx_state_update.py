@@ -20,9 +20,10 @@ DMA-in and the previous tile's DMA-out overlap compute on the current tile
 via double buffering.  ``input_output_aliases`` keeps the update in place,
 mirroring the PIM read-modify-write of the same rows.
 
-Validation runs in ``interpret=True`` mode on CPU; the quantization math is
-shared with :mod:`repro.core.formats`, so results are bitwise equal to the
-pure-jnp oracle in :mod:`repro.kernels.ref`.
+On the CPU the kernel runs in Pallas interpret mode, on a TPU compiled
+(:mod:`repro.ops.platform` decides).  The quantization math is shared with
+:mod:`repro.core.formats`, so results are bitwise equal to the pure-jnp
+oracle in :mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import formats as F
+from repro.kernels.ref import decay_add
 
 MXG = F.MX8_GROUP
 
@@ -62,13 +64,13 @@ def _state_update_kernel(
 
     # ----- fetch + dequantize (stage 1) -----
     S = _dequant_tile(mant_ref[0], exp_ref[0], micro_ref[0])   # (dv_blk, dk)
-    d = d_ref[...].astype(jnp.float32)                         # (1, dk)
-    k = k_ref[...].astype(jnp.float32)                         # (1, dk)
-    q = q_ref[...].astype(jnp.float32)                         # (1, dk)
-    v = v_ref[...].astype(jnp.float32)                         # (1, dv_blk)
+    d = d_ref[0]                                               # (1, dk)
+    k = k_ref[0]                                               # (1, dk)
+    q = q_ref[0]                                               # (1, dk)
+    v = v_ref[0]                                               # (dv_blk, 1)
 
     # ----- decay ∥ outer product (stage 2), update (stage 3) -----
-    Sn = S * d + jnp.transpose(v) * k                          # (dv_blk, dk)
+    Sn = decay_add(S, d, v, k)                                 # (dv_blk, dk)
 
     # ----- requantize with stochastic rounding (LFSR analogue) -----
     bits = None
@@ -87,7 +89,7 @@ def _state_update_kernel(
 
     # ----- output GEMV on the *stored* (requantized) state (stage 4) -----
     Snq = _dequant_tile(nm, ne, nmi)
-    y_ref[...] = jnp.sum(Snq * q, axis=-1)[None, :]            # (1, dv_blk)
+    y_ref[0] = jnp.sum(Snq * q, axis=-1, keepdims=True)        # (dv_blk, 1)
 
 
 def _pick_dv_block(dv: int) -> int:
@@ -105,7 +107,7 @@ def mx_state_update(
     qS: F.QuantizedTensor,
     d: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, q: jnp.ndarray,
     seed: jnp.ndarray,
-    *, rounding: str = "stochastic", interpret: bool = True,
+    *, rounding: str = "stochastic", interpret: bool,
     dv_block: int | None = None,
 ) -> Tuple[F.QuantizedTensor, jnp.ndarray]:
     """Fused quantized state update.
@@ -128,10 +130,12 @@ def mx_state_update(
     mant = qS.payload["mantissa"].reshape(BH, dv, dk)
     exp = qS.payload["exponent"].reshape(BH, dv, dk // MXG)
     micro = qS.payload["micro"].reshape(BH, dv, dk // MXG)
-    d = jnp.broadcast_to(d.astype(jnp.float32), (B, H, dk)).reshape(BH, dk)
-    k = k.astype(jnp.float32).reshape(BH, dk)
-    q = q.astype(jnp.float32).reshape(BH, dk)
-    v = v.astype(jnp.float32).reshape(BH, dv)
+    # per-head operands as (1, dk) rows and (dv, 1) columns: TPU blocks
+    # must span the last two array dims (or 8 x 128 multiples of them)
+    d = jnp.broadcast_to(d.astype(jnp.float32), (B, H, dk)).reshape(BH, 1, dk)
+    k = k.astype(jnp.float32).reshape(BH, 1, dk)
+    q = q.astype(jnp.float32).reshape(BH, 1, dk)
+    v = v.astype(jnp.float32).reshape(BH, dv, 1)
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
 
     grid = (BH, n_tiles)
@@ -142,23 +146,23 @@ def mx_state_update(
         jax.ShapeDtypeStruct((BH, dv, dk), jnp.int8),
         jax.ShapeDtypeStruct((BH, dv, dk // MXG), jnp.uint8),
         jax.ShapeDtypeStruct((BH, dv, dk // MXG), jnp.uint8),
-        jax.ShapeDtypeStruct((BH, dv), jnp.float32),
+        jax.ShapeDtypeStruct((BH, dv, 1), jnp.float32),
     ]
     in_specs = [
         pl.BlockSpec((1, 1), lambda i, j: (0, 0)),                      # seed
         pl.BlockSpec((1, dv_blk, dk), lambda i, j: (i, j, 0)),          # mant
         pl.BlockSpec((1, dv_blk, dk // MXG), lambda i, j: (i, j, 0)),   # exp
         pl.BlockSpec((1, dv_blk, dk // MXG), lambda i, j: (i, j, 0)),   # micro
-        pl.BlockSpec((1, dk), lambda i, j: (i, 0)),                     # d
-        pl.BlockSpec((1, dk), lambda i, j: (i, 0)),                     # k
-        pl.BlockSpec((1, dv_blk), lambda i, j: (i, j)),                 # v
-        pl.BlockSpec((1, dk), lambda i, j: (i, 0)),                     # q
+        pl.BlockSpec((1, 1, dk), lambda i, j: (i, 0, 0)),               # d
+        pl.BlockSpec((1, 1, dk), lambda i, j: (i, 0, 0)),               # k
+        pl.BlockSpec((1, dv_blk, 1), lambda i, j: (i, j, 0)),           # v
+        pl.BlockSpec((1, 1, dk), lambda i, j: (i, 0, 0)),               # q
     ]
     out_specs = [
         pl.BlockSpec((1, dv_blk, dk), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, dv_blk, dk // MXG), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, dv_blk, dk // MXG), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, dv_blk), lambda i, j: (i, j)),
+        pl.BlockSpec((1, dv_blk, 1), lambda i, j: (i, j, 0)),
     ]
 
     nm, ne, nmi, y = pl.pallas_call(
@@ -170,6 +174,7 @@ def mx_state_update(
         # in-place state update: read bank / write bank of the same rows
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="spu_state_update",
     )(seed_arr, mant, exp, micro, d, k, v, q)
 
     qSn = F.QuantizedTensor("mx8", qS.shape, {

@@ -67,6 +67,8 @@ def quantize_mx8(x: jnp.ndarray, seed=0, *, rounding: str = "nearest",
     _warn("quantize_mx8", "repro.core.formats.quantize")
     if backend == "pallas":
         from repro.kernels.mx_quant import mx_quantize as _quant_pallas
-        return _quant_pallas(x, seed, rounding=rounding, interpret=True)
+        from repro.ops.platform import interpret_pallas
+        return _quant_pallas(x, seed, rounding=rounding,
+                             interpret=interpret_pallas())
     from repro.kernels import ref as _ref
     return _ref.mx_quantize_ref(x, rounding=rounding, seed=seed)

@@ -33,6 +33,20 @@ def state_update_ref(S: jnp.ndarray, d: jnp.ndarray, k: jnp.ndarray,
     return Sn, y
 
 
+def decay_add(S: jnp.ndarray, d: jnp.ndarray, v: jnp.ndarray,
+              k: jnp.ndarray) -> jnp.ndarray:
+    """``S * d + v * k`` with each product rounded on its own.
+
+    A compiler may contract ``a * b + c`` into one FMA, which rounds once;
+    XLA:CPU does so in some fused loops and not in others, so the same
+    expression would round differently in the kernel and in this oracle.
+    Adding a zero made at run time (``d * 0``) to each product leaves it
+    unchanged and leaves the final add no product to contract.  The MX8
+    kernel evaluates its update through this function too.
+    """
+    return (S * d + d * 0.0) + (v * k + k * 0.0)
+
+
 # ---------------------------------------------------------------------------
 # Quantized state update: dequant -> update -> requant(SR) -> output GEMV
 # ---------------------------------------------------------------------------
@@ -80,7 +94,8 @@ def quantized_state_update_stored_ref(
     B, H, dv, dk = qS.shape
     St = F.dequantize(qS)                                     # (B,H,dv,dk)
     d_ = jnp.broadcast_to(d.astype(jnp.float32), (B, H, dk))[:, :, None, :]
-    Sn = St * d_ + v.astype(jnp.float32)[..., :, None] * k.astype(jnp.float32)[..., None, :]
+    Sn = decay_add(St, d_, v.astype(jnp.float32)[..., :, None],
+                   k.astype(jnp.float32)[..., None, :])
     bits = None
     if rounding == "stochastic":
         bits = F.sr_bits(Sn.shape, seed)

@@ -146,8 +146,6 @@ def _compile_step(cfg, sc, par, p_shapes, p_shard, grad_accum: int = 1,
 
 def _probe_costs(compiled, par) -> Dict[str, float]:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x wraps it per-device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = RL.parse_collectives(hlo, default_group=par.mesh.shape[par.model_axis])
     return {"flops": float(cost.get("flops", 0.0)),

@@ -85,11 +85,13 @@ def main(argv=None):
     import numpy as np
     from repro import ops as OPS
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.serving.api import Engine, ServeConfig
     from repro.serving.sampler import SamplingConfig
     from repro.serving.scheduler import SchedulerConfig
 
+    enable_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke_size
            else get_config(args.arch))
     if cfg.encoder_only:

@@ -45,10 +45,12 @@ def main(argv=None):
     from repro.configs import get_config, get_smoke_config
     from repro.data.pipeline import make_batch_fn
     from repro.dist import sharding as SH
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.train import optimizer as O
     from repro.train.train_loop import LoopConfig, make_train_step, train_loop
 
+    enable_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke_size
            else get_config(args.arch))
     opt = O.OptimizerConfig(lr=args.lr, total_steps=args.steps,

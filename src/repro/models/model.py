@@ -114,21 +114,16 @@ def init_model(key, cfg: ModelConfig) -> Params:
             _init_element(pks[i], cfg, kind, i, dense_ffn=True)
             for i, kind in enumerate(cfg.prelude))
 
-    # stacked group params
-    if cfg.n_groups == 1:
-        groups = [tuple(_init_element(kk, cfg, kind, pos)
-                        for pos, (kk, kind) in enumerate(
-                            zip(jax.random.split(keys[0], len(cfg.pattern)),
-                                cfg.pattern)))]
-        params["groups"] = jax.tree.map(lambda x: x[None], groups[0])
-    else:
-        def one_group(key, gidx):
-            eks = jax.random.split(key, len(cfg.pattern))
-            return tuple(
-                _init_element(eks[i], cfg, kind, int(gidx) * len(cfg.pattern) + i)
-                for i, kind in enumerate(cfg.pattern))
-        gs = [one_group(keys[g], g) for g in range(cfg.n_groups)]
-        params["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *gs)
+    # stacked group params, vmapped over the group keys: each op writes all
+    # groups into the stacked arrays at once, where building the groups one
+    # by one and stacking them would hold them twice (2x the model's bytes)
+    def one_group(key, gidx):
+        eks = jax.random.split(key, len(cfg.pattern))
+        return tuple(
+            _init_element(eks[i], cfg, kind, gidx * len(cfg.pattern) + i)
+            for i, kind in enumerate(cfg.pattern))
+    params["groups"] = jax.vmap(one_group)(keys[:cfg.n_groups],
+                                           jnp.arange(cfg.n_groups))
 
     if cfg.shared_attn:
         params["shared"] = _init_shared_block(keys[-4], cfg)
@@ -336,6 +331,23 @@ def embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]
 # step functions
 # ---------------------------------------------------------------------------
 
+def _f32_matmuls(step):
+    """Trace a step with f32 matmul precision.
+
+    On a TPU an f32 dot at DEFAULT precision rounds its operands to bf16,
+    and XLA hoists those converts of the stacked group weights out of the
+    layer scan: a bf16 copy of every scanned weight lives through the step
+    (4.2 GiB for zamba2-2.7b in f32).  The models compute in f32, so they
+    contract in f32; CPU dots are f32 either way.
+    """
+    @functools.wraps(step)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return step(*args, **kwargs)
+    return traced
+
+
+@_f32_matmuls
 def train_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                mesh_axes=None) -> jnp.ndarray:
     x, positions, prefix_len = embed_inputs(params, cfg, batch)
@@ -352,6 +364,7 @@ def train_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                                   unroll=cfg.cost_probe)
 
 
+@_f32_matmuls
 def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
             mesh_axes=None) -> Tuple[jnp.ndarray, Any]:
     """Full-sequence forward; returns (last-position logits, caches)."""
@@ -454,6 +467,7 @@ def _element_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
     return x, cache
 
 
+@_f32_matmuls
 def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                 caches: Any, lengths: jnp.ndarray, seed=0,
                 mesh_axes=None) -> Tuple[jnp.ndarray, Any]:
@@ -516,6 +530,7 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     return logits, new_caches
 
 
+@_f32_matmuls
 def paged_decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                       caches: Any, lengths: jnp.ndarray, seed=0,
                       mesh_axes=None) -> Tuple[jnp.ndarray, Any]:
@@ -698,6 +713,7 @@ def _element_spec_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
     return x, cache, snap
 
 
+@_f32_matmuls
 def paged_spec_decode_step(params: Params, cfg: ModelConfig,
                            tokens: jnp.ndarray, caches: Any,
                            lengths: jnp.ndarray, seed=0, mesh_axes=None

@@ -27,6 +27,7 @@ Typical call sites::
 from repro.ops.base import (LAYOUTS, OpPlan, SpuDeprecationWarning, SpuOp,
                             StateQuantConfig, TrafficBytes, fmt_bits,
                             fmt_of_state)
+from repro.ops.platform import interpret_pallas
 from repro.ops.registry import (BACKEND_PREFERENCE, OP_KINDS, backends_for,
                                 execute, get_op, plan, register, registered,
                                 resolve_backend, supports, traffic)

@@ -35,6 +35,7 @@ from repro.kernels.mx_attention import mx_attention_decode as _attn_pallas
 from repro.ops import registry
 from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
                             StateQuantConfig, TrafficBytes, fmt_of_state)
+from repro.ops.platform import interpret_pallas
 
 
 def _cache_row_vals(plan: OpPlan) -> int:
@@ -115,7 +116,8 @@ class _AttnDecodePallas(_AttnDecodeBase):
         out = _attn_pallas(inputs["q"], cache.k, cache.v, cache.lengths,
                            scale=plan.opt("scale"),
                            v_width=plan.opt("v_width"),
-                           t_block=plan.opt("t_block", 128), interpret=True)
+                           t_block=plan.opt("t_block", 128),
+                           interpret=interpret_pallas())
         return cache, out
 
 

@@ -50,6 +50,7 @@ from repro.ops import registry
 from repro.ops.attention import _cache_row_vals
 from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
                             TrafficBytes)
+from repro.ops.platform import interpret_pallas
 
 
 def _gather_stream(pool, bt: jnp.ndarray, group) -> Any:
@@ -104,7 +105,7 @@ class _PagedAttnPallas(_PagedAttnBase):
         out = mx_paged_attention_decode(
             inputs["q"], cache.k, cache.v, cache.bt, cache.group,
             cache.lengths, scale=plan.opt("scale"),
-            v_width=plan.opt("v_width"), interpret=True)
+            v_width=plan.opt("v_width"), interpret=interpret_pallas())
         return cache, out
 
 
@@ -235,7 +236,7 @@ class PagedKVAppendPallas(_PagedKVAppendBase):
             rows += list(self._quant_rows(cache, v_new, plan, seed + 1))
             pools += list(self._pools_of(cache.v))
         out = mx_paged_kv_append(pools, rows, cache.bt, cache.group,
-                                 cache.lengths, interpret=True)
+                                 cache.lengths, interpret=interpret_pallas())
         nk = self._rebuild(cache.k, out[:nk_count])
         nv = (cache.v if v_new is None
               else self._rebuild(cache.v, out[nk_count:]))

@@ -50,6 +50,7 @@ from repro.ops.attention import (_cache_dims, _cache_quant, _cache_row_vals,
                                  _layout_of, kv_append)
 from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
                             StateQuantConfig, TrafficBytes)
+from repro.ops.platform import interpret_pallas
 
 
 class _SpecVerifyBase(SpuOp):
@@ -106,7 +107,7 @@ class SpecVerifyPallas(_SpecVerifyBase):
         out = mx_spec_attention_decode(
             inputs["q"], cache.k, cache.v, cache.lengths,
             scale=plan.opt("scale"), v_width=plan.opt("v_width"),
-            t_block=plan.opt("t_block", 128), interpret=True)
+            t_block=plan.opt("t_block", 128), interpret=interpret_pallas())
         return cache, out
 
 
@@ -152,7 +153,7 @@ class PagedSpecVerifyPallas(_PagedSpecVerifyBase):
         out = mx_paged_spec_attention_decode(
             inputs["q"], cache.k, cache.v, cache.bt, cache.group,
             cache.lengths, scale=plan.opt("scale"),
-            v_width=plan.opt("v_width"), interpret=True)
+            v_width=plan.opt("v_width"), interpret=interpret_pallas())
         return cache, out
 
 

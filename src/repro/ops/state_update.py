@@ -5,8 +5,8 @@
 Storage layout for the resident state is ``(B, H, dv, dk)`` (Sᵀ) with MX
 groups along dk; see ``kernels/mx_state_update.py`` for why.  Two backends:
 
-* ``pallas`` -- the fused kernel (``interpret=True`` on CPU; compiled
-  natively on real TPUs).  MX8 only.
+* ``pallas`` -- the fused kernel (interpreted on the CPU, compiled on a
+  TPU; :mod:`repro.ops.platform` decides).  MX8 only.
 * ``jnp``    -- mathematically identical pure-jnp path for every storage
   format (bitwise identical packed state for MX8).  This is what the
   multi-pod dry-run lowers: interpret-mode pallas would trace its grid as an
@@ -29,6 +29,7 @@ from repro.ops import registry
 from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
                             StateQuantConfig, TrafficBytes, fmt_bits,
                             fmt_of_state)
+from repro.ops.platform import interpret_pallas
 
 StateLike = Union[F.QuantizedTensor, jnp.ndarray]
 
@@ -83,7 +84,8 @@ class StateUpdatePallas(_StateUpdateBase):
         return _su_pallas(state, inputs["d"], inputs["k"], inputs["v"],
                           inputs["q"],
                           jnp.asarray(inputs.get("seed", 0), jnp.int32),
-                          rounding=plan.rounding, interpret=True)
+                          rounding=plan.rounding,
+                          interpret=interpret_pallas())
 
 
 @registry.register

@@ -16,6 +16,7 @@ from repro.kernels import ref
 from repro.kernels.mx_attention import mx_attention_decode
 from repro.kernels.mx_quant import mx_quantize
 from repro.kernels.mx_state_update import mx_state_update
+from repro.ops import interpret_pallas
 
 
 def _su_inputs(B, H, dk, dv, seed=0, dtype=jnp.float32):
@@ -40,7 +41,8 @@ def test_state_update_kernel_bitwise(B, H, dk, dv, rounding):
     qS, d, k, v, q = _su_inputs(B, H, dk, dv)
     qr, yr = ref.quantized_state_update_stored_ref(
         qS, d, k, v, q, rounding=rounding, seed=11)
-    qk, yk = mx_state_update(qS, d, k, v, q, seed=11, rounding=rounding)
+    qk, yk = mx_state_update(qS, d, k, v, q, seed=11, rounding=rounding,
+                             interpret=interpret_pallas())
     for f in ("mantissa", "exponent", "micro"):
         assert jnp.array_equal(qr.payload[f], qk.payload[f]), f
     np.testing.assert_allclose(yr, yk, rtol=1e-5, atol=1e-5)
@@ -49,7 +51,8 @@ def test_state_update_kernel_bitwise(B, H, dk, dv, rounding):
 @pytest.mark.parametrize("in_dtype", [jnp.float32, jnp.bfloat16])
 def test_state_update_kernel_dtypes(in_dtype):
     qS, d, k, v, q = _su_inputs(2, 2, 128, 64, dtype=in_dtype)
-    qk, yk = mx_state_update(qS, d, k, v, q, seed=0)
+    qk, yk = mx_state_update(qS, d, k, v, q, seed=0,
+                             interpret=interpret_pallas())
     assert yk.dtype == jnp.float32
     assert jnp.all(jnp.isfinite(yk))
 
@@ -57,9 +60,11 @@ def test_state_update_kernel_dtypes(in_dtype):
 def test_state_update_scalar_decay_broadcast():
     qS, d, k, v, q = _su_inputs(2, 2, 128, 64)
     d_scalar = d[..., :1]
-    q1, y1 = mx_state_update(qS, d_scalar, k, v, q, seed=3)
+    q1, y1 = mx_state_update(qS, d_scalar, k, v, q, seed=3,
+                             interpret=interpret_pallas())
     d_full = jnp.broadcast_to(d_scalar, d.shape)
-    q2, y2 = mx_state_update(qS, d_full, k, v, q, seed=3)
+    q2, y2 = mx_state_update(qS, d_full, k, v, q, seed=3,
+                             interpret=interpret_pallas())
     assert jnp.array_equal(q1.payload["mantissa"], q2.payload["mantissa"])
     np.testing.assert_allclose(y1, y2, rtol=1e-6)
 
@@ -69,7 +74,8 @@ def test_state_update_multi_step_matches_ref():
     qS, d, k, v, q = _su_inputs(1, 2, 64, 32)
     qR = qS
     for step in range(5):
-        qS, _ = mx_state_update(qS, d, k, v, q, seed=step)
+        qS, _ = mx_state_update(qS, d, k, v, q, seed=step,
+                                interpret=interpret_pallas())
         qR, _ = ref.quantized_state_update_stored_ref(
             qR, d, k, v, q, rounding="stochastic", seed=step)
     assert jnp.array_equal(qS.payload["mantissa"], qR.payload["mantissa"])
@@ -88,7 +94,8 @@ def test_attention_kernel_vs_ref(B, H, KVH, dh, T, t_blk):
     lengths = jnp.arange(1, B + 1) * (T // (B + 1)) + 1
     qK, qV = F.mx8_quantize(K), F.mx8_quantize(V)
     y_ref = ref.mx_attention_decode_ref(q, qK, qV, lengths)
-    y_k = mx_attention_decode(q, qK, qV, lengths, t_block=t_blk)
+    y_k = mx_attention_decode(q, qK, qV, lengths, t_block=t_blk,
+                              interpret=interpret_pallas())
     np.testing.assert_allclose(y_ref, y_k, rtol=2e-4, atol=2e-5)
 
 
@@ -99,7 +106,8 @@ def test_attention_kernel_mla_mode():
     C = jax.random.normal(ks[1], (B, T, 1, dkc))
     qC = F.mx8_quantize(C)
     lengths = jnp.array([200, 64], jnp.int32)
-    y = mx_attention_decode(q, qC, None, lengths, v_width=vw)
+    y = mx_attention_decode(q, qC, None, lengths, v_width=vw,
+                            interpret=interpret_pallas())
     kf = F.dequantize(qC)
     y_ref = ref.attention_decode_ref(q, kf, kf[..., :vw], lengths,
                                      scale=dkc ** -0.5)
@@ -115,11 +123,11 @@ def test_attention_kernel_respects_lengths():
     V = jax.random.normal(ks[2], (B, T, KVH, dh))
     L = 100
     y1 = mx_attention_decode(q, F.mx8_quantize(K), F.mx8_quantize(V),
-                             jnp.array([L]))
+                             jnp.array([L]), interpret=interpret_pallas())
     K2 = K.at[:, L:].set(99.0)
     V2 = V.at[:, L:].set(-99.0)
     y2 = mx_attention_decode(q, F.mx8_quantize(K2), F.mx8_quantize(V2),
-                             jnp.array([L]))
+                             jnp.array([L]), interpret=interpret_pallas())
     np.testing.assert_allclose(y1, y2, rtol=1e-5, atol=1e-6)
 
 
@@ -127,7 +135,8 @@ def test_attention_kernel_respects_lengths():
 @pytest.mark.parametrize("shape", [(16, 64), (300, 128), (5, 7, 32)])
 def test_quant_kernel_bitwise(rounding, shape):
     x = jax.random.normal(jax.random.PRNGKey(3), shape)
-    qk = mx_quantize(x, seed=9, rounding=rounding, row_block=64)
+    qk = mx_quantize(x, seed=9, rounding=rounding, row_block=64,
+                     interpret=interpret_pallas())
     qr = ref.mx_quantize_ref(x, rounding=rounding, seed=9)
     for f in ("mantissa", "exponent", "micro"):
         assert jnp.array_equal(qk.payload[f], qr.payload[f]), f
