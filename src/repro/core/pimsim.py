@@ -239,27 +239,6 @@ def placement_step_latency(bursts: "np.ndarray", sys: SystemConfig,
             "conflict_factor": t_real / t_ideal if t_ideal > 0 else 1.0}
 
 
-def bank_trace_counters(bursts: "np.ndarray",
-                        sys: SystemConfig = None,
-                        design: str = "pimba") -> Dict[str, float]:
-    """One decode step's bank traffic as a flat numeric dict for a
-    Chrome-trace ``C`` counter event (``repro.obs``): per-pseudo-channel
-    burst totals (Perfetto stacks the series), total bursts, and the
-    placement model's ``conflict_factor`` / real step latency for the same
-    map.  Per-bank-pair detail stays in ``placement_step_latency``; the
-    per-step counter keeps a bounded key count."""
-    if sys is None:
-        sys = SystemConfig()
-    bursts = np.asarray(bursts, float)
-    rep = placement_step_latency(bursts, sys, design)
-    out = {f"pch{p:02d}_bursts": float(b)
-           for p, b in enumerate(bursts.sum(axis=1))}
-    out["total_bursts"] = float(bursts.sum())
-    out["conflict_factor"] = rep["conflict_factor"]
-    out["t_real_us"] = rep["t_real_s"] * 1e6
-    return out
-
-
 # ---------------------------------------------------------------------------
 # end-to-end generation model (Figs. 12/13/15)
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Request-lifecycle spans: queued -> prefill -> decode -> spilled -> terminal.
+"""Request-lifecycle spans: queued -> prefill -> ingest -> decode -> spilled
+-> terminal.
 
 Every ``Request`` the engines touch gets a ``RequestRecord`` here: an
 ordered chain of phase spans with engine-supplied timestamps (the same
@@ -14,9 +15,15 @@ Phases:
 
   ``queued``   submitted, waiting for admission (or re-queued post-spill)
   ``prefill``  full-sequence prompt ingestion
-  ``decode``   resident in the decode batch (chunked prompt tails, fork
-               continuations, and steady-state generation all decode)
+  ``ingest``   in the decode batch before the first token, feeding the
+               prompt tokens the prefill left (a bucketed prompt's tail,
+               the rest after a prefix-store hit, a fork's new turn) one
+               a step; the first token closes it
+  ``decode``   in the decode batch, generating
   ``spilled``  preempted: pages on host, waiting to resume
+
+For a request never spilled, ``queued`` + ``prefill`` + ``ingest`` is its
+``ttft_s``.
 
 A terminal request has a **complete chain**: starts at ``queued``, every
 span closed, terminal status recorded.  ``run(max_steps)`` surfacing a
@@ -35,7 +42,7 @@ from typing import Dict, List, Optional
 
 __all__ = ["PhaseSpan", "RequestRecord", "LifecycleTracker", "PHASES"]
 
-PHASES = ("queued", "prefill", "decode", "spilled")
+PHASES = ("queued", "prefill", "ingest", "decode", "spilled")
 
 
 @dataclasses.dataclass
@@ -161,10 +168,15 @@ class LifecycleTracker:
         rec.spans.append(PhaseSpan(phase, t))
 
     def first_token(self, rid: int, t: Optional[float] = None) -> None:
+        """Stamp the first token; a request ingesting its prompt moves on
+        to ``decode`` at that instant."""
         rec = self.records.get(rid)
         if rec is None or rec.t_first > 0:
             return
         rec.t_first = self._now() if t is None else t
+        if rec.open_span is not None and rec.open_span.phase == "ingest":
+            self._close_open(rec, rec.t_first)
+            rec.spans.append(PhaseSpan("decode", rec.t_first))
         if self.metrics is not None:
             self.metrics.histogram("ttft_s").observe(
                 rec.t_first - rec.t_submit)

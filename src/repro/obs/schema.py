@@ -7,7 +7,7 @@ timestamps.  ``trace_features`` reports which observability signals the
 trace actually contains, so CI can require them:
 
     PYTHONPATH=src python -m repro.obs.schema out.json \
-        --require steps,spans,bank,recompile
+        --require steps,spans,phases,recompile
 
 exits non-zero if the trace is structurally invalid or any required
 feature is missing.
@@ -26,9 +26,10 @@ _NUMERIC = (int, float)
 
 #: feature name -> human description (see ``trace_features``)
 FEATURES = {
-    "steps": "decode-step X events (cat='step')",
+    "steps": "engine-step X events (cat='step')",
+    "phases": "serve.* X span events nested in an engine step "
+              "(args.parent == 'serve.step')",
     "spans": "request lifecycle b/e span pairs (cat='request')",
-    "bank": "per-bank traffic C counter events",
     "recompile": "recompile instant events (cat='jit')",
     "recompile_signature": "a recompile event carrying a changed-shape "
                            "signature",
@@ -116,6 +117,9 @@ def trace_features(obj) -> Set[str]:
         ph, cat = ev.get("ph"), ev.get("cat")
         if ph == "X" and cat == "step":
             feats.add("steps")
+        if ph == "X" and str(ev.get("name", "")).startswith("serve.") \
+                and (ev.get("args") or {}).get("parent") == "serve.step":
+            feats.add("phases")
         if ph in ("b", "e") and cat == "request":
             feats.add("spans")
         if (ph in ("b", "e") and cat == "prefetch") or \
@@ -123,8 +127,6 @@ def trace_features(obj) -> Set[str]:
             feats.add("tiered")
         if ph in ("i", "I") and cat == "fault":
             feats.add("resilience")
-        if ph == "C" and "bank" in str(ev.get("name", "")):
-            feats.add("bank")
         if ph == "C" and ev.get("name") == "spec":
             feats.add("speculation")
         if ph in ("i", "I") and cat == "jit":

@@ -1,8 +1,8 @@
 """Per-step structured trace: a bounded ring buffer of Chrome-trace events.
 
-Engines, scheduler, pool, and placement emit events here -- step
-boundaries, admissions, evictions, fork/copy-on-write copies, per-bank
-traffic counters, recompiles -- and the buffer exports them as
+Engines, scheduler, pool, and placement emit events here -- step spans,
+admissions, evictions, fork/copy-on-write copies, pool counters,
+recompiles -- and the buffer exports them as
 
   * **Chrome-trace JSON** (``{"traceEvents": [...]}``) loadable in
     Perfetto / ``chrome://tracing`` (``save("out.json")``), or
@@ -11,7 +11,10 @@ traffic counters, recompiles -- and the buffer exports them as
 
 Event vocabulary (Trace Event Format phase codes):
 
-  * ``X`` complete events -- decode steps (``cat="step"``), with duration;
+  * ``X`` complete events -- spans (:class:`Span`): the engine step
+    (``serve.step``, ``cat="step"``) and the boundaries inside it
+    (``serve.admit``, ``serve.prefill``, ``serve.prepare``, ...), each with
+    its args and the name of the span it nests in (``parent``);
   * ``b``/``e`` async pairs -- request lifecycle phase spans
     (``cat="request"``, ``id=rid``): queued / prefill / decode / spilled;
     and host-tier prefetches (``cat="prefetch"``, ``id=rid``): dispatch of
@@ -21,12 +24,17 @@ Event vocabulary (Trace Event Format phase codes):
     a dangling ``b`` in the trace;
   * ``i`` instants -- admissions, evictions, forks, recompiles; tier
     movement (``cat="tier"``): promote / demote / prefix_hit / evict;
-  * ``C`` counters -- per-bank traffic + ``conflict_factor`` each step.
+  * ``C`` counters -- pool occupancy / fragmentation each step.
 
 Tracks (Perfetto rows) are logical: engine, scheduler, pool, requests.
 The buffer is a ``deque(maxlen=capacity)`` -- a long serve run keeps the
 most recent window; ``dropped`` counts what aged out.  Timestamps are
 microseconds since the buffer's construction (``perf_counter``-based).
+
+A span also holds a ``jax.profiler.TraceAnnotation`` of its name and args
+while open, so whenever a profiler session runs, the same span lands in the
+profiler's trace on the clock of the device ops (the annotation costs
+about a microsecond when no session runs).
 """
 from __future__ import annotations
 
@@ -35,7 +43,9 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["TraceBuffer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["TraceBuffer", "Span"]
 
 #: stable track (Chrome "tid") assignment for the logical emitters
 _TRACKS = ("engine", "requests", "scheduler", "pool", "counters", "jit")
@@ -52,6 +62,7 @@ class TraceBuffer:
         self._t0 = time.perf_counter()
         self._tids: Dict[str, int] = {}
         self._meta: List[dict] = []     # thread_name events survive eviction
+        self._open: List[str] = []      # open spans' names, innermost last
         for track in _TRACKS:
             self._tid(track)
 
@@ -140,3 +151,43 @@ class TraceBuffer:
         else:
             with open(path, "w") as f:
                 json.dump(self.to_chrome(), f)
+
+
+class Span:
+    """A context manager for one span: while open, a profiler annotation of
+    its name and args; on exit, one ``X`` event on the engine track of the
+    ring carrying the args and the name of the span it opened inside
+    (``parent``).
+
+    ``set(**args)`` adds args known only inside the span (a step's row
+    count, whether it compiled); they reach the ring event and the
+    profiler's event alike."""
+
+    __slots__ = ("_buf", "name", "cat", "args", "_ann", "_t0")
+
+    def __init__(self, buf: TraceBuffer, name: str, cat: str = "span",
+                 **args):
+        self._buf, self.name, self.cat, self.args = buf, name, cat, args
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if self._buf._open:
+            self.args["parent"] = self._buf._open[-1]
+        self._buf._open.append(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        buf = self._buf
+        buf._open.pop()
+        buf.complete(self.name, self.cat, ts=buf.ts_of(self._t0),
+                     dur=(t1 - self._t0) * 1e6, track="engine",
+                     **self.args)
+        self._ann.__exit__(*exc)
+        return False

@@ -38,7 +38,6 @@ import numpy as np
 
 from repro import ops as OPS
 from repro.core import attention_cache as AC
-from repro.core import pimsim
 from repro.core.paged import PAGE_TOKENS, pages_for
 from repro.models import model as M
 from repro.obs import Observability
@@ -160,6 +159,14 @@ def _sample_tokens(key, logits, sampling: SamplingConfig):
     return key, sample(logits, sampling, sub)
 
 
+def _prefill_program(cfg: ModelConfig, mesh_axes):
+    """The jitted full-sequence prefill, under a name of its own: the
+    profiler shows it as ``jit_prefill``."""
+    def prefill(params, batch):
+        return M.prefill(params, cfg=cfg, batch=batch, mesh_axes=mesh_axes)
+    return jax.jit(prefill)
+
+
 def _row_insert(pool_leaf, row_leaf, slot):
     """Write one batch row into a pooled cache leaf (leading dims may include
     the n_groups stack: (G, B, ...) vs row (G, 1, ...))."""
@@ -191,9 +198,10 @@ class _EngineCore:
 
     Every engine carries an :class:`repro.obs.Observability` bundle:
     ``stats()`` is a schema-stable view over its metrics registry, request
-    phase transitions land in its lifecycle tracker, decode steps and
-    per-bank traffic stream into its trace ring buffer, and the jitted
-    steppers are wrapped by its recompile watcher.
+    phase transitions land in its lifecycle tracker, each step and the
+    boundaries inside it are spans in its trace ring buffer (and in a
+    running ``jax.profiler`` session), and the jitted steppers are wrapped
+    by its recompile watcher.
     """
 
     backend: str = "?"
@@ -403,21 +411,16 @@ class _EngineCore:
         self.prefill_tokens += int(n)
         self.obs.metrics.counter("prefill_tokens_total").inc(int(n))
 
-    def _record_step(self, t0: float, dt: float, compiled: bool,
-                     batch: int):
+    def _record_step(self, dt: float, compiled: bool):
         """Shared per-step bookkeeping: the step-time series with its
-        compile tag, the ``step_s`` histogram split by tag, and the
-        ``decode_step`` X event on the engine track."""
+        compile tag and the ``step_s`` histogram split by tag (the step's
+        trace event is its ``serve.step`` span)."""
         self.step_times.append(dt)
         self.step_compiled.append(compiled)
         if self.watchdog is not None:
             self.watchdog.observe(self.step_count, dt)
         self.obs.metrics.histogram(
             "step_s", compile="true" if compiled else "false").observe(dt)
-        self.obs.tracer.complete(
-            "decode_step", cat="step", ts=self.obs.tracer.ts_of(t0),
-            dur=dt * 1e6, track="engine", step=self.step_count,
-            batch=batch, compiled=compiled)
 
 
 # ===========================================================================
@@ -455,9 +458,8 @@ class ServingEngine(_EngineCore):
             jax.jit(partial(M.decode_step, cfg=cfg, mesh_axes=mesh_axes),
                     donate_argnames=("caches",)),
             "engine.decode")
-        self._prefill = self.obs.wrap_jit(
-            jax.jit(partial(M.prefill, cfg=cfg, mesh_axes=mesh_axes)),
-            "engine.prefill")
+        self._prefill = self.obs.wrap_jit(_prefill_program(cfg, mesh_axes),
+                                          "engine.prefill")
 
     # ------------- lifecycle -------------
 
@@ -465,9 +467,14 @@ class ServingEngine(_EngineCore):
         self.queue.append(req)
 
     def step(self) -> bool:
-        self._admit()
-        if self.active.any():
-            self._decode_step()
+        with self.obs.span("serve.step", cat="step") as st:
+            c0 = self.obs.recompiles.n_events
+            self._admit()
+            rows = int(self.active.sum())
+            if rows:
+                self._decode_step()
+            st.set(step=self.step_count, rows=rows,
+                   compiled=self.obs.recompiles.n_events > c0)
         return self.has_work()
 
     def has_work(self) -> bool:
@@ -499,7 +506,9 @@ class ServingEngine(_EngineCore):
         while self.queue and not self.active.all():
             slot = int(np.flatnonzero(~self.active)[0])
             req = self.queue.pop(0)
-            self._prefill_into(slot, req)
+            with self.obs.span("serve.prefill", cat="prefill", rid=req.rid,
+                               tokens=len(req.prompt), tail=0):
+                self._prefill_into(slot, req)
 
     def _prefill_into(self, slot: int, req: Request):
         t_p0 = time.perf_counter()
@@ -524,10 +533,6 @@ class ServingEngine(_EngineCore):
         tok = int(toks[0])
         req.t_first = time.perf_counter()
         self.obs.lifecycle.first_token(req.rid, t=req.t_first)
-        self.obs.tracer.complete(
-            "prefill", cat="prefill", ts=self.obs.tracer.ts_of(t_p0),
-            dur=(req.t_first - t_p0) * 1e6, track="engine",
-            rid=req.rid, tokens=int(S))
         req.output.append(tok)
         hit_eos = req.eos_id is not None and tok == req.eos_id
         if len(req.output) >= req.max_new_tokens or hit_eos:
@@ -556,9 +561,8 @@ class ServingEngine(_EngineCore):
         # lengths ledger lives host-side (see __init__) and needs none
         toks_np = np.asarray(toks)
         lengths_np = self.lengths
-        self._record_step(t0, time.perf_counter() - t0,
-                          compiled=self.obs.recompiles.n_events > c0,
-                          batch=int(self.active.sum()))
+        self._record_step(time.perf_counter() - t0,
+                          compiled=self.obs.recompiles.n_events > c0)
         self._traffic.account_step(lengths_np[self.active])
         for slot in np.flatnonzero(self.active):
             req = self.slot_req[slot]
@@ -684,7 +688,8 @@ class PagedServingEngine(_EngineCore):
         self.preemptions = 0
         self._occ: List[float] = []
         self._frag: List[float] = []
-        self.last_traffic: Optional[np.ndarray] = None
+        self._step_prefilled = 0          # full-sequence prefill tokens
+                                          # of the step under way
         # --- resilience wiring (all None/empty => zero overhead) ---
         self.faults = FaultPlan.maybe(pcfg.fault_plan, seed=pcfg.seed)
         self.pool.faults = self.faults
@@ -697,9 +702,8 @@ class PagedServingEngine(_EngineCore):
         self._reprefills: Dict[int, int] = {}
         #: rid -> consecutive failed admission attempts (degradation rung)
         self._admit_fails: Dict[int, int] = {}
-        self._prefill = self.obs.wrap_jit(
-            jax.jit(partial(M.prefill, cfg=cfg, mesh_axes=mesh_axes)),
-            "engine.prefill")
+        self._prefill = self.obs.wrap_jit(_prefill_program(cfg, mesh_axes),
+                                          "engine.prefill")
         max_chunk_pages = pages_for(pcfg.prefill_chunk)
         assert max_chunk_pages <= self.pool.usable_pages, \
             "prefill_chunk does not fit the page pool"
@@ -751,27 +755,47 @@ class PagedServingEngine(_EngineCore):
         self.sched.push(req)
 
     def step(self) -> bool:
-        if self.faults is not None:
-            self.faults.set_step(self.step_count)
-        if self.pcfg.request_timeout_s is not None:
-            self._expire_queued()
-        admitted = self._admit()
-        if self.active:
-            self._ensure_headroom()
-        if self.active:
-            # stage prefetches *before* dispatching decode: the host->device
-            # copies ride JAX's async dispatch behind the decode kernels, so
-            # the next admission window's data lands while this step runs
-            self._issue_prefetches()
-            self._decode_step()
-        elif self.sched and not admitted:
-            # queue non-empty but nothing fits and nothing runs: shed the
-            # head loudly rather than spinning (a request whose admission
-            # can *never* be satisfied would otherwise wedge the engine)
-            self._drop_queued(
-                self.sched.peek(), "rejected",
-                detail="cannot admit with the pool idle (request does not "
-                       "fit the page budget)")
+        """One engine step.  Its ``serve.step`` span holds a span for each
+        part (admit, headroom, prefetch, then the decode step's prepare,
+        dispatch, sync, account and commit) and, as args, the step number,
+        the rows decoded, how many of them fed a prompt token
+        (``tail_rows``), the tokens prefilled, and whether anything
+        compiled."""
+        span = self.obs.span
+        with span("serve.step", cat="step") as st:
+            c0 = self.obs.recompiles.n_events
+            self._step_prefilled = rows = tail_rows = 0
+            if self.faults is not None:
+                self.faults.set_step(self.step_count)
+            with span("serve.admit"):
+                if self.pcfg.request_timeout_s is not None:
+                    self._expire_queued()
+                admitted = self._admit()
+            if self.active:
+                with span("serve.headroom"):
+                    self._ensure_headroom()
+            if self.active:
+                # stage prefetches *before* dispatching decode: the
+                # host->device copies ride JAX's async dispatch behind the
+                # decode kernels, so the next admission window's data lands
+                # while this step runs
+                with span("serve.prefetch"):
+                    self._issue_prefetches()
+                rows = len(self.active)
+                tail_rows = sum(1 for a in self.active.values() if a.pending)
+                self._decode_step()
+            elif self.sched and not admitted:
+                # queue non-empty but nothing fits and nothing runs: shed
+                # the head loudly rather than spinning (a request whose
+                # admission can *never* be satisfied would otherwise wedge
+                # the engine)
+                self._drop_queued(
+                    self.sched.peek(), "rejected",
+                    detail="cannot admit with the pool idle (request does "
+                           "not fit the page budget)")
+            st.set(step=self.step_count, rows=rows, tail_rows=tail_rows,
+                   prefill_tokens=self._step_prefilled,
+                   compiled=self.obs.recompiles.n_events > c0)
         return self.has_work()
 
     def _expire_queued(self) -> None:
@@ -1044,8 +1068,7 @@ class PagedServingEngine(_EngineCore):
             self.pool.note_prefix_miss()
         src = np.asarray(replay, np.int32) if replay is not None \
             else req.prompt
-        t_p0 = time.perf_counter()
-        self.obs.lifecycle.phase(req.rid, "prefill", t=t_p0)
+        self.obs.lifecycle.phase(req.rid, "prefill")
         s0 = self._bucket_prefill_len(len(src))
         if not self._retry("alloc",
                            lambda: self.pool.register(req.rid, pages_for(s0))):
@@ -1056,35 +1079,32 @@ class PagedServingEngine(_EngineCore):
         # prefill_buckets set, s0 comes from a fixed bucket set, so the
         # slice below feeds a bounded family of compiled shapes.
         self._count_prefill(len(src))
-        prompt = jnp.asarray(src[:s0], jnp.int32)[None]  # lint: disable=JH103
-        logits, row_caches = self._prefill(
-            self.params, batch={"tokens": prompt, "targets": prompt})
-        self.pool.insert_prefill(req.rid, row_caches)
-        if replay is None and s0 % PAGE_TOKENS == 0:
-            # the prefilled pages are full and immutable: remember them in
-            # the prefix store for future requests sharing this prompt
-            # (replay streams contain generated tokens -- never stored)
-            self.pool.store_insert(req.rid, req.prompt[:s0])
-        self.obs.tracer.complete(
-            "prefill", cat="prefill", ts=self.obs.tracer.ts_of(t_p0),
-            dur=(time.perf_counter() - t_p0) * 1e6, track="engine",
-            rid=req.rid, tokens=s0, chunked=bool(len(src) > s0),
-            replay=bool(replay is not None))
+        self._step_prefilled += s0
         a = _Active(req, length=s0, pending=list(map(int, src[s0:])),
                     cur_token=-1, replayed=replay is not None)
-        if not a.pending:
-            self._key, toks = _sample_tokens(self._key, logits,
-                                             self.pcfg.sampling)
-            tok = int(toks[0])
-            if not req.t_first:
-                req.t_first = time.perf_counter()
-                self.obs.lifecycle.first_token(req.rid, t=req.t_first)
-            req.output.append(tok)
-            a.cur_token = tok
-        self.active[req.rid] = a
-        self._assign_row(req.rid)
-        req.status = "running"
-        self.obs.lifecycle.phase(req.rid, "decode")
+        with self.obs.span("serve.prefill", cat="prefill", rid=req.rid,
+                           tokens=s0, tail=len(a.pending),
+                           replay=replay is not None):
+            prompt = jnp.asarray(src[:s0], jnp.int32)[None]  # lint: disable=JH103
+            logits, row_caches = self._prefill(
+                self.params, batch={"tokens": prompt, "targets": prompt})
+            self.pool.insert_prefill(req.rid, row_caches)
+            if replay is None and s0 % PAGE_TOKENS == 0:
+                # the prefilled pages are full and immutable: remember them
+                # in the prefix store for future requests sharing this
+                # prompt (replay streams contain generated tokens -- never
+                # stored)
+                self.pool.store_insert(req.rid, req.prompt[:s0])
+            if not a.pending:
+                self._key, toks = _sample_tokens(self._key, logits,
+                                                 self.pcfg.sampling)
+                tok = int(toks[0])
+                if not req.t_first:
+                    req.t_first = time.perf_counter()
+                    self.obs.lifecycle.first_token(req.rid, t=req.t_first)
+                req.output.append(tok)
+                a.cur_token = tok
+        self._running(a)
         if req.output and (len(req.output) >= req.max_new_tokens
                            or (req.eos_id is not None
                                and req.output[-1] == req.eos_id)):
@@ -1105,11 +1125,19 @@ class PagedServingEngine(_EngineCore):
         assert pending, "prefix match must leave a prompt tail"
         # only the un-cached tail is fresh context -- that is the whole point
         self._count_prefill(len(pending))
-        a = _Active(req, length=length, pending=pending, cur_token=-1)
-        self.active[req.rid] = a
-        self._assign_row(req.rid)
-        req.status = "running"
-        self.obs.lifecycle.phase(req.rid, "decode")
+        self._running(_Active(req, length=length, pending=pending,
+                              cur_token=-1))
+
+    def _running(self, a: _Active) -> None:
+        """Put an admitted request into the decode batch: it ingests what
+        is left of its prompt until its first token (the lifecycle's
+        ``ingest`` phase), then decodes."""
+        rid = a.req.rid
+        self.active[rid] = a
+        self._assign_row(rid)
+        a.req.status = "running"
+        self.obs.lifecycle.phase(
+            rid, "ingest" if a.pending and not a.req.t_first else "decode")
 
     def _issue_prefetches(self) -> None:
         """Scheduler-lookahead prefetch: for requests in the next admission
@@ -1147,11 +1175,8 @@ class PagedServingEngine(_EngineCore):
             return False
         pending = [int(parent.cur_token)] + list(map(int, req.prompt))
         self._count_prefill(len(pending))
-        a = _Active(req, length=parent.length, pending=pending, cur_token=-1)
-        self.active[req.rid] = a
-        self._assign_row(req.rid)
-        req.status = "running"
-        self.obs.lifecycle.phase(req.rid, "decode")
+        self._running(_Active(req, length=parent.length, pending=pending,
+                              cur_token=-1))
         return True
 
     def _resume(self, req: Request) -> bool:
@@ -1163,10 +1188,7 @@ class PagedServingEngine(_EngineCore):
                            lambda: self.pool.resume(req.rid, sp)):
             return False
         del self.spilled[req.rid]
-        self.active[req.rid] = _Active(req, sp.length, pending, cur)
-        self._assign_row(req.rid)
-        req.status = "running"
-        self.obs.lifecycle.phase(req.rid, "decode")
+        self._running(_Active(req, sp.length, pending, cur))
         return True
 
     def _preempt(self, rid: int):
@@ -1226,105 +1248,121 @@ class PagedServingEngine(_EngineCore):
             self._spec_decode_step()
             return
         self.step_count += 1
-        B = self.pcfg.max_decode_batch
-        tokens = np.zeros((B,), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            a = self.active[rid]
-            tokens[row] = a.pending[0] if a.pending else a.cur_token
-            lengths[row] = a.length
+        span = self.obs.span
+        with span("serve.prepare"):
+            B = self.pcfg.max_decode_batch
+            tokens = np.zeros((B,), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            for row, rid in enumerate(self.rows):
+                if rid is None:
+                    continue
+                a = self.active[rid]
+                tokens[row] = a.pending[0] if a.pending else a.cur_token
+                lengths[row] = a.length
         c0 = self.obs.recompiles.n_events
         t0 = time.perf_counter()
+        self._maybe_stall()
+        # the pool's own serve.prepare (block table, slab ids, device
+        # arrays) and serve.dispatch (the jitted step) spans sit in here
+        logits = self.pool.decode(self.params, self.rows, tokens, lengths,
+                                  seed=self.step_count)
+        with span("serve.dispatch"):
+            if self.faults is not None:
+                logits = self._inject_nan(logits)
+            self._key, toks = _sample_tokens(self._key, logits,
+                                             self.pcfg.sampling)
+        with span("serve.sync"):
+            toks_np = np.asarray(toks)
+            bad_rows = self._scan_nonfinite(logits) if self._nan_guard \
+                else ()
+        self._record_step(time.perf_counter() - t0,
+                          compiled=self.obs.recompiles.n_events > c0)
+        self._account(lengths, 1)
+
+        with span("serve.commit"):
+            for row, rid in enumerate(self.rows):
+                if rid is None:
+                    continue
+                if row in bad_rows:
+                    # quarantine exactly this request -- its logits are
+                    # non-finite and its sampled token is garbage.  Every
+                    # other row's token stream is untouched (sampling is
+                    # row-wise).
+                    self._fail_active(rid,
+                                      "non-finite logits after decode step")
+                    continue
+                a = self.active[rid]
+                a.length += 1
+                self._store_prompt_page(rid, a)
+                if a.pending:
+                    fed = a.pending.pop(0)
+                    a.cur_token = fed
+                    if a.pending:
+                        continue            # still consuming the prompt
+                    # that was the last prompt token: this step's logits
+                    # are the first-generation distribution
+                    tok = int(toks_np[row])
+                    if not a.req.t_first:   # replays already emitted tokens
+                        a.req.t_first = time.perf_counter()
+                        self.obs.lifecycle.first_token(rid, t=a.req.t_first)
+                    a.req.output.append(tok)
+                    a.cur_token = tok
+                else:
+                    tok = int(toks_np[row])
+                    a.req.output.append(tok)
+                    a.cur_token = tok
+                req = a.req
+                hit_eos = (req.eos_id is not None and req.output
+                           and req.output[-1] == req.eos_id)
+                if len(req.output) >= req.max_new_tokens or hit_eos:
+                    self._finish(rid)
+
+    def _maybe_stall(self) -> None:
+        """A planted ``slow_step`` fault: sleep inside the timed part of the
+        step, where the watchdog must see (and flag) the blown budget."""
         if self.faults is not None and self.faults.should_fire("slow_step"):
             stall_s = self.faults.param("slow_step", "ms") / 1000.0
             self.obs.metrics.counter("faults_injected_total",
                                      site="slow_step").inc()
             self.obs.tracer.instant("fault.slow_step", cat="fault",
                                     track="engine", ms=stall_s * 1e3)
-            time.sleep(stall_s)     # inside the timed window: the watchdog
-                                    # must see (and flag) the blown budget
-        logits = self.pool.decode(self.params, self.rows, tokens, lengths,
-                                  seed=self.step_count)
-        if self.faults is not None:
-            logits = self._inject_nan(logits)
-        self._key, toks = _sample_tokens(self._key, logits,
-                                         self.pcfg.sampling)
-        toks_np = np.asarray(toks)
-        bad_rows = self._scan_nonfinite(logits) if self._nan_guard else ()
-        self._record_step(t0, time.perf_counter() - t0,
-                          compiled=self.obs.recompiles.n_events > c0,
-                          batch=sum(1 for r in self.rows if r is not None))
-        # account at the attended length: the step appends one token at
-        # `length` and attends over length+1 (matches ServingEngine, which
-        # accounts after its post-step lengths increment).  Copy-on-write
-        # shared pages are deduplicated across rows -- a physical page
-        # streamed for several forks of one prefix is attributed once.
-        seen_pages = set()
-        units = []
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            npg = pages_for(int(lengths[row]) + 1)
-            fresh = [p for p in self.pool.page_table[rid][:npg]
-                     if p not in seen_pages]
-            seen_pages.update(fresh)
-            units.append(max(len(fresh), 1))
-        self._traffic.account_units(units)
+            time.sleep(stall_s)
 
-        rids = [r for r in self.rows if r is not None]
-        self.last_traffic = self.pool.bank_traffic(rids)
-        self._occ.append(self.pool.occupancy())
-        self._frag.append(self.pool.fragmentation(
-            {r: self.active[r].length for r in rids}))
-        self.obs.tracer.counter(
-            "bank_traffic", pimsim.bank_trace_counters(self.last_traffic))
-        self.obs.tracer.counter(
-            "pool", {"occupancy": self._occ[-1],
-                     "fragmentation": self._frag[-1]})
+    def _store_prompt_page(self, rid: int, a: _Active) -> None:
+        """A chunk-streamed prompt that just filled a page: the page is
+        immutable from here on and the slab holds the recurrent state at
+        this exact boundary -- store both in the prefix store."""
+        if (a.req.parent_rid is None and not a.replayed
+                and a.length % PAGE_TOKENS == 0
+                and a.length <= len(a.req.prompt)):
+            self.pool.store_insert(rid, a.req.prompt[:a.length])
 
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            if row in bad_rows:
-                # quarantine exactly this request -- its logits are
-                # non-finite and its sampled token is garbage.  Every other
-                # row's token stream is untouched (sampling is row-wise).
-                self._fail_active(rid, "non-finite logits after decode step")
-                continue
-            a = self.active[rid]
-            a.length += 1
-            if (a.req.parent_rid is None
-                    and not a.replayed
-                    and a.length % PAGE_TOKENS == 0
-                    and a.length <= len(a.req.prompt)):
-                # a chunk-streamed prompt just filled a page: the page is
-                # immutable from here on and the slab holds the recurrent
-                # state at this exact boundary -- store both
-                self.pool.store_insert(rid, a.req.prompt[:a.length])
-            if a.pending:
-                fed = a.pending.pop(0)
-                a.cur_token = fed
-                if a.pending:
-                    continue            # still consuming the prompt
-                # that was the last prompt token: this step's logits are
-                # the first-generation distribution
-                tok = int(toks_np[row])
-                if not a.req.t_first:   # replays already emitted tokens
-                    a.req.t_first = time.perf_counter()
-                    self.obs.lifecycle.first_token(rid, t=a.req.t_first)
-                a.req.output.append(tok)
-                a.cur_token = tok
-            else:
-                tok = int(toks_np[row])
-                a.req.output.append(tok)
-                a.cur_token = tok
-            req = a.req
-            hit_eos = (req.eos_id is not None and req.output
-                       and req.output[-1] == req.eos_id)
-            if len(req.output) >= req.max_new_tokens or hit_eos:
-                self._finish(rid)
+    def _account(self, lengths: np.ndarray, n: int) -> None:
+        """The step's ``serve.account`` span: op traffic at the pages each
+        row attended (``length + n`` positions), pool occupancy and
+        fragmentation.  Copy-on-write shared pages are deduplicated across
+        rows -- a physical page streamed for several forks of one prefix is
+        attributed once."""
+        with self.obs.span("serve.account"):
+            seen_pages = set()
+            units = []
+            rids = []
+            for row, rid in enumerate(self.rows):
+                if rid is None:
+                    continue
+                rids.append(rid)
+                table = self.pool.page_table[rid]
+                npg = min(pages_for(int(lengths[row]) + n), len(table))
+                fresh = [p for p in table[:npg] if p not in seen_pages]
+                seen_pages.update(fresh)
+                units.append(max(len(fresh), 1))
+            self._traffic.account_units(units)
+            self._occ.append(self.pool.occupancy())
+            self._frag.append(self.pool.fragmentation(
+                {r: self.active[r].length for r in rids}))
+            self.obs.tracer.counter(
+                "pool", {"occupancy": self._occ[-1],
+                         "fragmentation": self._frag[-1]})
 
     # ------------- the speculative decode step -------------
 
@@ -1347,191 +1385,169 @@ class PagedServingEngine(_EngineCore):
         the non-speculative sampling distribution.
         """
         self.step_count += 1
+        span = self.obs.span
         B = self.pcfg.max_decode_batch
         n = self.pcfg.spec_k + 1
-        tokens = np.zeros((B, n), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        drafts: Dict[int, List[int]] = {}
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            a = self.active[rid]
-            lengths[row] = a.length
-            if a.pending:
-                tokens[row, 0] = a.pending[0]   # positions 1.. are garbage
-                continue
-            # the budget keeps one fully-accepted step inside the request's
-            # remaining token allowance, so emitted tokens never need a
-            # post-hoc cut that would desync length from committed state
-            budget = min(self.kctl.k_for(rid), self.pcfg.spec_k,
-                         a.req.max_new_tokens - len(a.req.output) - 1)
-            d = []
-            if budget > 0:
-                ctx = list(map(int, a.req.prompt)) + list(a.req.output)
-                d = [int(t) for t in
-                     self.draft.propose(rid, ctx, budget)[:budget]]
-            drafts[rid] = d
-            tokens[row, 0] = a.cur_token
-            tokens[row, 1:1 + len(d)] = d
+        with span("serve.prepare"):
+            tokens = np.zeros((B, n), np.int32)
+            lengths = np.zeros((B,), np.int32)
+            drafts: Dict[int, List[int]] = {}
+            for row, rid in enumerate(self.rows):
+                if rid is None:
+                    continue
+                a = self.active[rid]
+                lengths[row] = a.length
+                if a.pending:
+                    tokens[row, 0] = a.pending[0]  # positions 1.. garbage
+                    continue
+                # the budget keeps one fully-accepted step inside the
+                # request's remaining token allowance, so emitted tokens
+                # never need a post-hoc cut that would desync length from
+                # committed state
+                budget = min(self.kctl.k_for(rid), self.pcfg.spec_k,
+                             a.req.max_new_tokens - len(a.req.output) - 1)
+                d = []
+                if budget > 0:
+                    ctx = list(map(int, a.req.prompt)) + list(a.req.output)
+                    d = [int(t) for t in
+                         self.draft.propose(rid, ctx, budget)[:budget]]
+                drafts[rid] = d
+                tokens[row, 0] = a.cur_token
+                tokens[row, 1:1 + len(d)] = d
+            # every row's block table must span the garbage positions too,
+            # or an out-of-width page index would clamp onto a live
+            # physical page
+            min_pages = max(pages_for(int(lengths[row]) + n)
+                            for row, rid in enumerate(self.rows)
+                            if rid is not None)
         c0 = self.obs.recompiles.n_events
         t0 = time.perf_counter()
-        if self.faults is not None and self.faults.should_fire("slow_step"):
-            stall_s = self.faults.param("slow_step", "ms") / 1000.0
-            self.obs.metrics.counter("faults_injected_total",
-                                     site="slow_step").inc()
-            self.obs.tracer.instant("fault.slow_step", cat="fault",
-                                    track="engine", ms=stall_s * 1e3)
-            time.sleep(stall_s)
-        # every row's block table must span the garbage positions too, or
-        # an out-of-width page index would clamp onto a live physical page
-        min_pages = max(pages_for(int(lengths[row]) + n)
-                        for row, rid in enumerate(self.rows)
-                        if rid is not None)
+        self._maybe_stall()
         seed = self._spec_seed
         self._spec_seed += n
         logits, snaps = self.pool.decode_spec(
             self.params, self.rows, tokens, lengths, seed=seed,
             min_pages=min_pages)
-        if self.faults is not None:
-            logits = self._inject_nan(logits)
-        bad_rows = self._scan_nonfinite(logits) if self._nan_guard else ()
-        greedy = self.pcfg.sampling.temperature <= 0.0
-        if greedy:
-            # same device op as the sampler's greedy branch, so ties break
-            # identically to non-speculative decoding
-            g = np.asarray(jnp.argmax(logits, axis=-1))
-        else:
-            probs = np.asarray(filtered_probs(logits, self.pcfg.sampling))
-        sel = np.zeros((B,), np.int32)
-        emits: Dict[int, List[int]] = {}
-        for row, rid in enumerate(self.rows):
-            if rid is None or row in bad_rows:
-                continue
-            a = self.active[rid]
-            if a.pending:
-                continue                      # single real position: sel = 0
-            d = drafts.get(rid, [])
+        with span("serve.sync"):
+            if self.faults is not None:
+                logits = self._inject_nan(logits)
+            bad_rows = self._scan_nonfinite(logits) if self._nan_guard \
+                else ()
+            greedy = self.pcfg.sampling.temperature <= 0.0
             if greedy:
-                m = 0
-                while m < len(d) and d[m] == int(g[row, m]):
-                    m += 1
-                emit = [int(g[row, j]) for j in range(m + 1)]
+                # same device op as the sampler's greedy branch, so ties
+                # break identically to non-speculative decoding
+                g = np.asarray(jnp.argmax(logits, axis=-1))
             else:
-                rng = np.random.default_rng(
-                    (self.pcfg.seed, self.step_count, row))
-                emit = []
-                for j, t in enumerate(d):
-                    pj = probs[row, j]
-                    pj = pj / pj.sum()
-                    if rng.random() < pj[t]:
-                        emit.append(t)        # accepted with probability p(t)
-                        continue
-                    # rejected: the correction comes from the residual
-                    # distribution max(0, p - q) with the one-hot draft q
-                    q = pj.copy()
-                    q[t] = 0.0
-                    s = q.sum()
-                    if s <= 0.0:
-                        emit.append(t)        # p was a point mass on t
-                        continue
-                    emit.append(int(rng.choice(len(q), p=q / s)))
-                    break
+                probs = np.asarray(filtered_probs(logits, self.pcfg.sampling))
+        with span("serve.commit"):
+            sel = np.zeros((B,), np.int32)
+            emits: Dict[int, List[int]] = {}
+            for row, rid in enumerate(self.rows):
+                if rid is None or row in bad_rows:
+                    continue
+                a = self.active[rid]
+                if a.pending:
+                    continue                  # single real position: sel = 0
+                d = drafts.get(rid, [])
+                if greedy:
+                    m = 0
+                    while m < len(d) and d[m] == int(g[row, m]):
+                        m += 1
+                    emit = [int(g[row, j]) for j in range(m + 1)]
                 else:
-                    pj = probs[row, len(d)]
-                    emit.append(int(rng.choice(len(pj), p=pj / pj.sum())))
-            if a.req.eos_id is not None and a.req.eos_id in emit:
-                emit = emit[:emit.index(a.req.eos_id) + 1]
-            sel[row] = len(emit) - 1
-            emits[rid] = emit
-        # roll state back to the accepted prefix *before* any host-side
-        # bookkeeping -- every row (prompt rows included: their garbage
-        # padding advanced recurrent state too) needs its slab restored
-        self.pool.commit_spec(self.rows, snaps, sel)
-        self._record_step(t0, time.perf_counter() - t0,
-                          compiled=self.obs.recompiles.n_events > c0,
-                          batch=sum(1 for r in self.rows if r is not None))
+                    rng = np.random.default_rng(
+                        (self.pcfg.seed, self.step_count, row))
+                    emit = []
+                    for j, t in enumerate(d):
+                        pj = probs[row, j]
+                        pj = pj / pj.sum()
+                        if rng.random() < pj[t]:
+                            emit.append(t)    # accepted with probability p(t)
+                            continue
+                        # rejected: the correction comes from the residual
+                        # distribution max(0, p - q) with the one-hot draft q
+                        q = pj.copy()
+                        q[t] = 0.0
+                        s = q.sum()
+                        if s <= 0.0:
+                            emit.append(t)    # p was a point mass on t
+                            continue
+                        emit.append(int(rng.choice(len(q), p=q / s)))
+                        break
+                    else:
+                        pj = probs[row, len(d)]
+                        emit.append(int(rng.choice(len(pj), p=pj / pj.sum())))
+                if a.req.eos_id is not None and a.req.eos_id in emit:
+                    emit = emit[:emit.index(a.req.eos_id) + 1]
+                sel[row] = len(emit) - 1
+                emits[rid] = emit
+            # roll state back to the accepted prefix *before* any host-side
+            # bookkeeping -- every row (prompt rows included: their garbage
+            # padding advanced recurrent state too) needs its slab restored
+            self.pool.commit_spec(self.rows, snaps, sel)
+        self._record_step(time.perf_counter() - t0,
+                          compiled=self.obs.recompiles.n_events > c0)
         # one cache stream serves the whole verify span: account the pages
         # attended at length + n once, amortized over the accepted tokens
-        seen_pages = set()
-        units = []
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            npg = min(pages_for(int(lengths[row]) + n),
-                      len(self.pool.page_table[rid]))
-            fresh = [p for p in self.pool.page_table[rid][:npg]
-                     if p not in seen_pages]
-            seen_pages.update(fresh)
-            units.append(max(len(fresh), 1))
-        self._traffic.account_units(units)
-        rids = [r for r in self.rows if r is not None]
-        self.last_traffic = self.pool.bank_traffic(rids)
-        self._occ.append(self.pool.occupancy())
-        self._frag.append(self.pool.fragmentation(
-            {r: self.active[r].length for r in rids}))
-        self.obs.tracer.counter(
-            "bank_traffic", pimsim.bank_trace_counters(self.last_traffic))
-        self.obs.tracer.counter(
-            "pool", {"occupancy": self._occ[-1],
-                     "fragmentation": self._frag[-1]})
-        n_proposed = n_accepted = n_steps = 0
-        for row, rid in enumerate(self.rows):
-            if rid is None:
-                continue
-            if row in bad_rows:
-                self._fail_active(rid, "non-finite logits after decode step")
-                continue
-            a = self.active[rid]
-            if a.pending:
-                a.length += 1
-                if (a.req.parent_rid is None
-                        and not a.replayed
-                        and a.length % PAGE_TOKENS == 0
-                        and a.length <= len(a.req.prompt)):
-                    self.pool.store_insert(rid, a.req.prompt[:a.length])
-                fed = a.pending.pop(0)
-                a.cur_token = fed
-                if a.pending:
+        self._account(lengths, n)
+        with span("serve.commit"):
+            n_proposed = n_accepted = n_steps = 0
+            for row, rid in enumerate(self.rows):
+                if rid is None:
                     continue
-                tok = (int(g[row, 0]) if greedy else int(
-                    np.random.default_rng(
-                        (self.pcfg.seed, self.step_count, row)
-                    ).choice(probs.shape[-1],
-                             p=probs[row, 0] / probs[row, 0].sum())))
-                if not a.req.t_first:
-                    a.req.t_first = time.perf_counter()
-                    self.obs.lifecycle.first_token(rid, t=a.req.t_first)
-                a.req.output.append(tok)
-                a.cur_token = tok
-            else:
-                emit = emits[rid]
-                proposed = len(drafts.get(rid, []))
-                # the last emitted token is the model's own (correction or
-                # bonus), so drafts surviving into the stream are len - 1,
-                # capped by proposed (an eos cut can only shorten the prefix)
-                accepted = min(len(emit) - 1, proposed)
-                self.kctl.observe(rid, proposed, accepted)
-                n_proposed += proposed
-                n_accepted += accepted
-                n_steps += 1
-                a.length += len(emit)
-                if not a.req.t_first:
-                    a.req.t_first = time.perf_counter()
-                    self.obs.lifecycle.first_token(rid, t=a.req.t_first)
-                a.req.output.extend(emit)
-                a.cur_token = emit[-1]
-            req = a.req
-            hit_eos = (req.eos_id is not None and req.output
-                       and req.output[-1] == req.eos_id)
-            if len(req.output) >= req.max_new_tokens or hit_eos:
-                self._finish(rid)
-        m = self.obs.metrics
-        m.counter("spec_proposed_tokens_total").inc(n_proposed)
-        m.counter("spec_accepted_tokens_total").inc(n_accepted)
-        m.counter("spec_verify_steps_total").inc(n_steps)
-        if n_steps:
-            self.obs.tracer.counter(
-                "spec", {"proposed": n_proposed, "accepted": n_accepted})
+                if row in bad_rows:
+                    self._fail_active(rid,
+                                      "non-finite logits after decode step")
+                    continue
+                a = self.active[rid]
+                if a.pending:
+                    a.length += 1
+                    self._store_prompt_page(rid, a)
+                    fed = a.pending.pop(0)
+                    a.cur_token = fed
+                    if a.pending:
+                        continue
+                    tok = (int(g[row, 0]) if greedy else int(
+                        np.random.default_rng(
+                            (self.pcfg.seed, self.step_count, row)
+                        ).choice(probs.shape[-1],
+                                 p=probs[row, 0] / probs[row, 0].sum())))
+                    if not a.req.t_first:
+                        a.req.t_first = time.perf_counter()
+                        self.obs.lifecycle.first_token(rid, t=a.req.t_first)
+                    a.req.output.append(tok)
+                    a.cur_token = tok
+                else:
+                    emit = emits[rid]
+                    proposed = len(drafts.get(rid, []))
+                    # the last emitted token is the model's own (correction
+                    # or bonus), so drafts surviving into the stream are
+                    # len - 1, capped by proposed (an eos cut can only
+                    # shorten the prefix)
+                    accepted = min(len(emit) - 1, proposed)
+                    self.kctl.observe(rid, proposed, accepted)
+                    n_proposed += proposed
+                    n_accepted += accepted
+                    n_steps += 1
+                    a.length += len(emit)
+                    if not a.req.t_first:
+                        a.req.t_first = time.perf_counter()
+                        self.obs.lifecycle.first_token(rid, t=a.req.t_first)
+                    a.req.output.extend(emit)
+                    a.cur_token = emit[-1]
+                req = a.req
+                hit_eos = (req.eos_id is not None and req.output
+                           and req.output[-1] == req.eos_id)
+                if len(req.output) >= req.max_new_tokens or hit_eos:
+                    self._finish(rid)
+            m = self.obs.metrics
+            m.counter("spec_proposed_tokens_total").inc(n_proposed)
+            m.counter("spec_accepted_tokens_total").inc(n_accepted)
+            m.counter("spec_verify_steps_total").inc(n_steps)
+            if n_steps:
+                self.obs.tracer.counter(
+                    "spec", {"proposed": n_proposed, "accepted": n_accepted})
 
     # ------------- fault handling -------------
 
@@ -1631,11 +1647,11 @@ class PagedServingEngine(_EngineCore):
         return out
 
     def bank_report(self) -> Dict[str, float]:
-        """Score the pool's *actual* page map with the PIM timing model."""
+        """Score the pool's *actual* page map with the PIM timing model:
+        the bank traffic of one decode step over the requests now active,
+        computed on demand (no decode step accounts it)."""
         from repro.core import pimsim
-        m = self.last_traffic
-        if m is None:
-            m = self.pool.bank_traffic(list(self.active))
+        m = self.pool.bank_traffic(list(self.active))
         rep = pimsim.placement_step_latency(m, pimsim.SystemConfig())
         rep["imbalance"] = self.pool.placement.imbalance()
         return rep

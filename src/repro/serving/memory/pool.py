@@ -24,6 +24,7 @@ re-pins them to fresh physical ids (identical logits, different placement).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence
@@ -191,6 +192,12 @@ class PagedStatePool:
         self._insert_blob = obs.wrap_jit(self._insert_blob,
                                          "pool.resume_insert")
         self.placement.metrics = obs.metrics
+
+    def _span(self, name: str):
+        """A span of the attached engine's trace (none on a bare pool)."""
+        if self._obs is None:
+            return contextlib.nullcontext()
+        return self._obs.span(name)
 
     def _instant(self, name: str, **args) -> None:
         if self._obs is not None:
@@ -488,17 +495,26 @@ class PagedStatePool:
         ``decode_mode="paged"`` (default) runs the block-table-native ops in
         place over the donated pools; ``"gather"`` runs the dense-gather
         reference path (parity testing; old pool buffers stay valid).
+        The host part (``serve.prepare``) and the jitted call
+        (``serve.dispatch``) are spans of the attached engine's trace.
         """
-        bt = jnp.asarray(self.block_table(rids))
-        slabs = jnp.asarray([self.slab_of.get(r, 0) if r is not None else 0
-                             for r in rids], jnp.int32)
+        with self._span("serve.prepare"):
+            inputs = self._decode_inputs(rids, tokens, lengths, seed)
         step = self._decode if self.decode_mode == "paged" \
             else self._decode_gather
-        logits, self.pools = step(
-            params, self.pools, bt, slabs,
-            jnp.asarray(lengths, jnp.int32), jnp.asarray(tokens, jnp.int32),
-            jnp.int32(seed))
+        with self._span("serve.dispatch"):
+            logits, self.pools = step(params, self.pools, *inputs)
         return logits
+
+    def _decode_inputs(self, rids, tokens, lengths, seed, min_pages=1):
+        """Block table, slab ids, lengths, tokens and seed on the device."""
+        return (jnp.asarray(self.block_table(rids, min_pages=min_pages)),
+                self._slab_ids(rids), jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(tokens, jnp.int32), jnp.int32(seed))
+
+    def _slab_ids(self, rids: Sequence[Optional[int]]):
+        return jnp.asarray([self.slab_of.get(r, 0) if r is not None else 0
+                            for r in rids], jnp.int32)
 
     def decode_spec(self, params, rids: Sequence[Optional[int]],
                     tokens: np.ndarray, lengths: np.ndarray, seed: int,
@@ -513,13 +529,12 @@ class PagedStatePool:
         """
         assert self.decode_mode == "paged", \
             "speculative decode requires the block-table-native path"
-        bt = jnp.asarray(self.block_table(rids, min_pages=min_pages))
-        slabs = jnp.asarray([self.slab_of.get(r, 0) if r is not None else 0
-                             for r in rids], jnp.int32)
-        logits, self.pools, snaps = self._decode_spec(
-            params, self.pools, bt, slabs,
-            jnp.asarray(lengths, jnp.int32), jnp.asarray(tokens, jnp.int32),
-            jnp.int32(seed))
+        with self._span("serve.prepare"):
+            inputs = self._decode_inputs(rids, tokens, lengths, seed,
+                                         min_pages=min_pages)
+        with self._span("serve.dispatch"):
+            logits, self.pools, snaps = self._decode_spec(
+                params, self.pools, *inputs)
         return logits, snaps
 
     def commit_spec(self, rids: Sequence[Optional[int]], snaps,
@@ -528,9 +543,8 @@ class PagedStatePool:
         (``sel`` (B,), an index into the verify step's n positions).  KV
         needs no rollback -- the engine's host lengths mask rejected rows
         and later appends overwrite them."""
-        slabs = jnp.asarray([self.slab_of.get(r, 0) if r is not None else 0
-                             for r in rids], jnp.int32)
-        self.pools = self._commit_spec(self.pools, snaps, slabs,
+        self.pools = self._commit_spec(self.pools, snaps,
+                                       self._slab_ids(rids),
                                        jnp.asarray(sel, jnp.int32))
 
     # ------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Observability subsystem: registry semantics, span lifecycle ordering,
 Chrome-trace export validity, recompile watcher, engine integration."""
+import glob
 import json
 
 import jax
@@ -109,7 +110,9 @@ def test_trace_ring_keeps_metadata_and_counts_drops():
 def test_trace_export_chrome_and_jsonl(tmp_path):
     tr = TraceBuffer()
     tr.complete("step", cat="step", ts=tr.now_us(), dur=100.0, batch=2)
-    tr.counter("bank_traffic", {"pch00_bursts": 3.0})
+    tr.complete("serve.sync", cat="span", ts=tr.now_us(), dur=80.0,
+                parent="serve.step")
+    tr.counter("pool", {"occupancy": 0.5})
     tr.async_span("decode", 7, "request", 0.0, 50.0, rid=7)
     p_json, p_jsonl = tmp_path / "t.json", tmp_path / "t.jsonl"
     tr.save(str(p_json))
@@ -117,7 +120,7 @@ def test_trace_export_chrome_and_jsonl(tmp_path):
     obj = json.loads(p_json.read_text())
     assert validate_chrome_trace(obj) == []
     feats = trace_features(obj)
-    assert {"steps", "spans", "bank"} <= feats
+    assert {"steps", "spans", "phases"} <= feats
     lines = [json.loads(L) for L in p_jsonl.read_text().splitlines()]
     assert len(lines) == len(tr.events())
 
@@ -188,7 +191,102 @@ def test_phases_vocabulary_enforced():
     lc.enqueued(1)
     with pytest.raises(AssertionError):
         lc.phase(1, "warp_drive")
-    assert set(PHASES) == {"queued", "prefill", "decode", "spilled"}
+    assert set(PHASES) == {"queued", "prefill", "ingest", "decode",
+                           "spilled"}
+
+
+def test_ingest_ends_at_first_token():
+    """With a prompt tail: queued, prefill, ingest, then decode from the
+    first token on; without one, no ingest."""
+    lc = LifecycleTracker(TraceBuffer(), MetricsRegistry())
+    lc.enqueued(1, t=0.0)
+    lc.phase(1, "prefill", t=1.0)
+    lc.phase(1, "ingest", t=2.0)
+    lc.first_token(1, t=5.0)
+    lc.finish(1, "done", n_tokens=3, t=6.0)
+    rec = lc.record(1)
+    assert rec.phase_sequence() == ["queued", "prefill", "ingest", "decode"]
+    assert [s.duration for s in rec.spans] == [1.0, 1.0, 3.0, 1.0]
+    assert rec.ttft_s == 5.0 and rec.complete_chain()
+    lc.enqueued(2, t=0.0)
+    lc.phase(2, "prefill", t=1.0)
+    lc.first_token(2, t=1.5)
+    lc.phase(2, "decode", t=1.5)
+    lc.finish(2, "done", n_tokens=1, t=2.0)
+    assert lc.record(2).phase_sequence() == ["queued", "prefill", "decode"]
+
+
+def test_interrupt_and_reopen_inside_ingest():
+    lc = LifecycleTracker(TraceBuffer(), MetricsRegistry())
+    lc.enqueued(4, t=0.0)
+    lc.phase(4, "prefill", t=1.0)
+    lc.phase(4, "ingest", t=2.0)
+    lc.interrupt(4, t=3.0)
+    assert lc.open_spans() == []
+    lc.reopen(4, t=4.0)
+    assert lc.record(4).open_span.phase == "ingest"
+    lc.first_token(4, t=6.0)
+    lc.finish(4, "done", n_tokens=2, t=7.0)
+    rec = lc.record(4)
+    assert rec.phase_sequence() == ["queued", "prefill", "ingest", "ingest",
+                                    "decode"]
+    assert rec.spans[2].interrupted and rec.complete_chain()
+    assert sum(s.duration for s in rec.spans
+               if s.phase == "ingest") == 3.0
+
+
+# ---------------------------------------------------------------------------
+# spans
+# ---------------------------------------------------------------------------
+
+def test_span_writes_ring_event_and_profiler_annotation(monkeypatch):
+    import repro.obs.trace as T
+    made = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **args):
+            self.name, self.args, self.late, self.open = name, args, {}, False
+            made.append(self)
+
+        def set_metadata(self, **args):
+            self.late.update(args)
+
+        def __enter__(self):
+            self.open = True
+            return self
+
+        def __exit__(self, *exc):
+            self.open = False
+
+    monkeypatch.setattr(T, "TraceAnnotation", FakeAnnotation)
+    obs = Observability()
+    with obs.span("serve.step", cat="step", step=3) as st:
+        assert made[0].open and made[0].name == "serve.step"
+        with obs.span("serve.prefill", rid=7, tokens=64):
+            assert made[1].open
+        st.set(rows=20, compiled=False)
+    assert not any(a.open for a in made)
+    assert made[0].args == {"step": 3}
+    assert made[0].late == {"rows": 20, "compiled": False}
+    assert made[1].args == {"rid": 7, "tokens": 64}
+    evs = [e for e in obs.tracer.events() if e["ph"] == "X"]
+    assert [e["name"] for e in evs] == ["serve.prefill", "serve.step"]
+    inner, outer = evs
+    assert inner["args"] == {"rid": 7, "tokens": 64,
+                             "parent": "serve.step"}
+    assert outer["cat"] == "step" and "parent" not in outer["args"]
+    assert outer["args"] == {"step": 3, "rows": 20, "compiled": False}
+    assert outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    obj = obs.tracer.to_chrome()
+    assert validate_chrome_trace(obj) == []
+    assert {"steps", "phases"} <= trace_features(obj)
+    # an exception inside a span still closes it
+    with pytest.raises(ValueError):
+        with obs.span("serve.commit"):
+            raise ValueError("x")
+    assert obs.tracer._open == []
+    assert obs.tracer.events()[-1]["name"] == "serve.commit"
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +358,7 @@ def test_engine_trace_valid_and_chains_complete(tiny_fp32, backend):
     feats = trace_features(obj)
     assert {"steps", "spans", "recompile"} <= feats
     if backend == "paged":
-        assert "bank" in feats
+        assert "phases" in feats
     # every terminal request has a complete queued->terminal chain
     recs = eng.obs.lifecycle.terminal_records()
     assert len(recs) == 3
@@ -332,3 +430,110 @@ def test_prometheus_endpoint_smoke(tiny_fp32):
     assert "# TYPE requests_total counter" in text
     assert "# TYPE step_s summary" in text
     assert "pages_alloc_total" in text
+
+
+# ---------------------------------------------------------------------------
+# the paged engine's step spans and ingest phase
+# ---------------------------------------------------------------------------
+
+#: every span of a paged engine step
+SERVE_SPANS = {"serve.step", "serve.admit", "serve.prefill",
+               "serve.headroom", "serve.prefetch", "serve.prepare",
+               "serve.dispatch", "serve.sync", "serve.account",
+               "serve.commit"}
+
+
+def _bucketed(params, cfg):
+    """A paged engine that prefills 8 tokens of a prompt and streams the
+    rest through the decode batch."""
+    return Engine(params, cfg, ServeConfig(
+        backend="paged", batch=2, n_pages=9, n_slabs=5, prefill_chunk=16,
+        prefill_buckets=(8,)))
+
+
+def _prompts(cfg, lengths, seed=4):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+            for n in lengths]
+
+
+def test_ttft_is_queued_prefill_ingest(tiny_fp32):
+    """For every request finished and never spilled, the queued, prefill
+    and ingest spans add up to its TTFT; a request whose prompt the
+    prefill takes whole has no ingest span."""
+    params, cfg = tiny_fp32
+    eng = _bucketed(params, cfg)
+    hs = [eng.submit(p, max_new_tokens=3)
+          for p in _prompts(cfg, [13, 8, 11, 5])]
+    eng.run()
+    for h in hs:
+        rec = eng.lifecycle(h)
+        seq = rec.phase_sequence()
+        assert rec.complete_chain() and "spilled" not in seq
+        assert ("ingest" in seq) == (len(h.request.prompt) > 8)
+        front = sum(s.duration for s in rec.spans
+                    if s.phase in ("queued", "prefill", "ingest"))
+        assert front == pytest.approx(rec.ttft_s, abs=1e-3)
+    # every step's tail_rows counts the rows that fed a prompt token
+    steps = [e for e in eng.obs.tracer.events()
+             if e["ph"] == "X" and e["name"] == "serve.step"]
+    assert sum(e["args"]["tail_rows"] for e in steps) == (13 - 8) + (11 - 8)
+    assert sum(e["args"]["prefill_tokens"] for e in steps) == 8 + 8 + 8 + 5
+    assert all(e["args"]["tail_rows"] <= e["args"]["rows"] for e in steps)
+
+
+def test_spill_during_ingest_resumes_into_ingest(tiny_fp32):
+    params, cfg = tiny_fp32
+    eng = _bucketed(params, cfg)
+    h, = [eng.submit(p, max_new_tokens=2) for p in _prompts(cfg, [14])]
+    eng.step()                              # prefill 8, stream one token
+    core = eng.engine
+    assert eng.lifecycle(h).open_span.phase == "ingest"
+    core._preempt(h.rid)
+    eng.run()
+    assert eng.lifecycle(h).phase_sequence() == [
+        "queued", "prefill", "ingest", "spilled", "ingest", "decode"]
+    assert h.status == "done"
+
+
+def _profile_events(trace_dir):
+    """(plane, name, t0, t1) of every event of the newest xplane."""
+    path = sorted(glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(plane.name, ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes for line in plane.lines
+            for ev in line.events]
+
+
+def test_spans_reach_the_profiler_trace(tiny_fp32, tmp_path):
+    """A real jax.profiler session over a paged run: every serve.* span on
+    the host plane, nested in a serve.step; no per-step bank counter; the
+    simulated-PIM bank report still computes on demand."""
+    params, cfg = tiny_fp32
+    eng = _bucketed(params, cfg)
+    eng.submit(_prompts(cfg, [11])[0], max_new_tokens=2)
+    eng.run()                               # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for p in _prompts(cfg, [13, 6], seed=5):
+            eng.submit(p, max_new_tokens=3)
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    evs = [e for e in _profile_events(tmp_path)
+           if e[1].startswith("serve.")]
+    assert {name for _, name, _, _ in evs} == SERVE_SPANS
+    assert {plane for plane, _, _, _ in evs} == {"/host:CPU"}
+    steps = [(a, b) for _, name, a, b in evs if name == "serve.step"]
+    for _, name, a, b in evs:
+        assert any(s0 <= a and b <= s1 for s0, s1 in steps), name
+    ring = eng.obs.tracer.events()
+    assert not any(e["ph"] == "C" and "bank" in e["name"] for e in ring)
+    assert {e["name"] for e in ring if e["ph"] == "X"} == SERVE_SPANS
+    # the bank report computes the traffic of the requests now active
+    assert eng.engine.bank_report()["t_real_s"] == 0.0
+    eng.submit(_prompts(cfg, [9])[0], max_new_tokens=3)
+    eng.step()
+    rep = eng.engine.bank_report()
+    assert rep["t_real_s"] > 0 and "imbalance" in rep
