@@ -15,7 +15,8 @@ from repro.core import formats as F
 from repro.kernels import ref
 from repro.kernels.mx_attention import mx_attention_decode
 from repro.kernels.mx_quant import mx_quantize
-from repro.kernels.mx_state_update import mx_state_update
+from repro.kernels.mx_state_update import (VMEM_BUDGET, block_bytes,
+                                           mx_state_update, plan_blocks)
 from repro.ops import interpret_pallas
 
 
@@ -35,6 +36,9 @@ def _su_inputs(B, H, dk, dv, seed=0, dtype=jnp.float32):
     (1, 2, 64, 128),       # zamba-like
     (2, 1, 256, 512),      # retnet-like
     (1, 1, 128, 1040),     # mlstm-like augmented dv
+    (3, 5, 128, 64),       # B·H = 15: no divisor but itself past 5
+    (2, 80, 128, 64),      # mamba2 heads: several blocks of pairs
+    (2, 10, 256, 512),     # retnet width, vector decay, two dv tiles
 ])
 @pytest.mark.parametrize("rounding", ["nearest", "stochastic"])
 def test_state_update_kernel_bitwise(B, H, dk, dv, rounding):
@@ -67,6 +71,32 @@ def test_state_update_scalar_decay_broadcast():
                              interpret=interpret_pallas())
     assert jnp.array_equal(q1.payload["mantissa"], q2.payload["mantissa"])
     np.testing.assert_allclose(y1, y2, rtol=1e-6)
+
+
+# (heads, dk, dv) of every family that decodes through the kernel
+_SU_FAMILIES = {
+    "mamba2": (80, 128, 64), "zamba2": (80, 64, 64), "retnet": (10, 256, 512),
+    "gla": (4, 320, 640), "hgrn2": (20, 128, 128), "mlstm": (4, 1024, 1040),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_SU_FAMILIES))
+@pytest.mark.parametrize("B", [1, 8, 20])
+def test_state_update_block_rule(family, B):
+    """Each grid step takes the most (row, head) pairs that divide B·H and
+    fit the fast-memory budget, for every family's shape."""
+    H, dk, dv = _SU_FAMILIES[family]
+    dv_blk, rows, grid = plan_blocks(B * H, dv, dk)
+    assert (B * H) % rows == 0
+    assert block_bytes(rows, dv_blk, dk) <= VMEM_BUDGET
+    assert grid == (B * H // rows, dv // dv_blk)
+    larger = [r for r in range(rows + 1, B * H + 1) if (B * H) % r == 0]
+    assert all(block_bytes(r, dv_blk, dk) > VMEM_BUDGET for r in larger)
+
+
+def test_state_update_block_rule_at_the_chat_cell():
+    """mamba2-2.7b at 20 decode rows: 40 pairs a step, 40 steps a call."""
+    assert plan_blocks(20 * 80, 64, 128) == (64, 40, (40, 1))
 
 
 def test_state_update_multi_step_matches_ref():

@@ -9,7 +9,7 @@ results or times is checked.
 
 Widths: zamba2-2.7b (80 Mamba-2 heads with dk = dv = 64; shared attention
 with 32 KV heads of width 80, stacked over 9 layer groups) at decode batch
-8, and mamba2-2.7b's state update (dk = 128).
+8, and mamba2-2.7b's state update (dk = 128) at 8 and at 20 rows.
 """
 import jax
 import jax.numpy as jnp
@@ -67,17 +67,21 @@ def _compile(fn, *args):
     return text
 
 
-@pytest.mark.parametrize("arch,heads,dk,dv", [
-    ("zamba2-2.7b", 80, 64, 64),
-    ("mamba2-2.7b", 80, 128, 64),
+@pytest.mark.parametrize("arch,heads,dk,dv,batch", [
+    pytest.param("zamba2-2.7b", 80, 64, 64, B, id="zamba2-2.7b-80-64-64"),
+    pytest.param("mamba2-2.7b", 80, 128, 64, B, id="mamba2-2.7b-80-128-64"),
+    # the mamba2-chat-open cell's decode width
+    pytest.param("mamba2-2.7b", 80, 128, 64, 20,
+                 id="mamba2-2.7b-80-128-64-20"),
 ])
-def test_state_update_compiles(one_chip, arch, heads, dk, dv):
+def test_state_update_compiles(one_chip, arch, heads, dk, dv, batch):
     f32 = lambda *s: _sds(s, jnp.float32, one_chip)
     _compile(lambda qS, d, k, v, q, seed: mx_state_update(
                  qS, d, k, v, q, seed, rounding="stochastic",
                  interpret=False),
-             _mx8((B, heads, dv, dk), one_chip), f32(B, heads, dk),
-             f32(B, heads, dk), f32(B, heads, dv), f32(B, heads, dk),
+             _mx8((batch, heads, dv, dk), one_chip), f32(batch, heads, dk),
+             f32(batch, heads, dk), f32(batch, heads, dv),
+             f32(batch, heads, dk),
              _sds((), jnp.int32, one_chip))
 
 
