@@ -219,7 +219,7 @@ def analytic_cost(cfg, sc, *, chips: int, tp: int, fs: int, pods: int,
         state_stream = (S / chunk) * H_ssm * dk_ * dv_ * 4 * 2  # r+w, f32
     n_attn_layers = (sum(cfg.pattern.count(k) for k in ("attn", "mla"))
                      * cfg.n_groups + len(cfg.prelude)
-                     + (cfg.n_groups if cfg.shared_attn else 0))
+                     + cfg.n_shared_apps)
 
     q_chunk = getattr(cfg, "attn_q_chunk", 512)
     attn_stream_per_seq = (S / q_chunk) * S * kv_width * 2.0   # bf16

@@ -252,6 +252,29 @@ def mx8_dequantize(qt: QuantizedTensor) -> jnp.ndarray:
     return (mant * scale).reshape(qt.shape)
 
 
+def mx8_dequantize_rows(mant: jnp.ndarray, exp: jnp.ndarray,
+                        micro: jnp.ndarray) -> jnp.ndarray:
+    """Dequantize an MX8 payload whose groups run down the rows.
+
+    ``mant`` is ``(16 n, t)``, ``exp`` and ``micro`` ``(n, t)``: row ``r``
+    belongs to group ``r // 16``, as in the paged K/V pools, which keep a
+    page's tokens on the lanes.  The same values as :func:`mx8_dequantize`
+    of the transposed payload."""
+    n, t = exp.shape
+    sel = _group_selector(MX8_GROUP * n)                          # (16n, n)
+
+    def expand(x):
+        return jax.lax.dot_general(
+            sel, x.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    e = expand(exp) - MX8_EXP_BIAS
+    row = jax.lax.broadcasted_iota(jnp.int32, (MX8_GROUP * n, t), 0)
+    micro = (expand(micro) >> ((row % MX8_GROUP) // MX8_PAIR)) & 1
+    return mant.astype(jnp.float32) * _pow2(e - MX8_MBITS - micro)
+
+
 # ---------------------------------------------------------------------------
 # int8 with per-group scale
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """Paged decode-cache *views*: block-table-native operand containers.
 
 The paged serving pool (``repro/serving/memory``) stores every KV leaf as a
-page pool ``(n_pages, ..., 128, ...)`` and every recurrent-state leaf as a
+page pool ``(n_pages, ..., R, 128)`` -- a page's tokens on the last axis,
+its ``R`` values a token before it -- and every recurrent-state leaf as a
 slab pool ``(n_slabs, ...)``.  Until the block-table-native kernels landed,
 the decode step gathered those pools into dense per-step cache trees and
 scattered one token back -- tripling the decode path's own DRAM traffic.
@@ -64,16 +65,23 @@ def _payload_dims(k) -> Tuple[int, ...]:
     return tuple(k.shape)
 
 
+def _row_width(k) -> int:
+    """Values a token of a stored page stream holds: its ``R``."""
+    return _payload_dims(k)[2]
+
+
 @jax.tree_util.register_pytree_with_keys_class
 @dataclasses.dataclass
 class PagedKVCache:
     """Block-table view of one attention layer's shared K/V page pools.
 
-    ``k``/``v`` hold the *whole pool* in the normalized physical layout
-    ``(n_pages, G, PAGE_TOKENS, KVH, d)`` (``G = 1`` for unstacked layers;
-    quantized streams keep one pool per payload field).  ``bt`` is the
-    step's dense block table, ``lengths`` the valid context per row, and
-    ``group`` selects which stacked layer this view addresses.
+    ``k``/``v`` hold the *whole pool* in the normalized stored layout
+    ``(n_pages, G, KVH*d, PAGE_TOKENS)``, tokens on the lanes (``G = 1``
+    for unstacked layers; quantized streams keep one pool per payload
+    field, and their ``shape`` is the logical ``(n_pages, G, PAGE_TOKENS,
+    KVH, d)``).  ``heads`` is ``KVH``.  ``bt`` is the step's dense block
+    table, ``lengths`` the valid context per row, and ``group`` selects
+    which stacked layer this view addresses.
     """
     k: object
     v: Optional[object]
@@ -83,12 +91,13 @@ class PagedKVCache:
     fmt: str = "mx8"
     v_width: Optional[int] = None    # MLA only
     lead_shape: Tuple[int, ...] = ()  # original group-axis shape (commit)
+    heads: int = 1                   # KV heads a stored row holds
 
     def tree_flatten_with_keys(self):
         GK = jax.tree_util.GetAttrKey
         return ([(GK("k"), self.k), (GK("v"), self.v), (GK("bt"), self.bt),
                  (GK("lengths"), self.lengths), (GK("group"), self.group)],
-                (self.fmt, self.v_width, self.lead_shape))
+                (self.fmt, self.v_width, self.lead_shape, self.heads))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -112,18 +121,18 @@ class PagedKVCache:
 
     @property
     def kv_heads(self) -> int:
-        return _payload_dims(self.k)[3]
+        return self.heads
 
     @property
     def dk(self) -> int:
-        return _payload_dims(self.k)[4]
+        return _row_width(self.k) // self.heads
 
     @property
     def dv(self) -> int:
         if self.v is None:
             assert self.v_width is not None
             return self.v_width
-        return _payload_dims(self.v)[4]
+        return _row_width(self.v) // self.heads
 
     def with_step(self, group, lengths: jnp.ndarray) -> "PagedKVCache":
         """Re-bind the view to one scan iteration: stacked-layer index plus

@@ -14,11 +14,13 @@ the paper's two-phase GPU⇄PIM handoff (score -> host softmax -> attend):
 GQA is handled by processing all G = H / KV_heads query heads of a KV head
 together against each KV tile (operand reuse across the chunk group, the
 analogue of Pimba broadcasting shared operands once per chunk group).  One
-grid step reads the tile of *every* KV head of a row: the cache is stored
-``(..., t, KVH, d)``, and a TPU block must span its last two dims.
+grid step reads the tile of *every* KV head of a row: the kernel reads the
+cache as ``(..., KVH*d, t)``, tokens on the lanes, so that a tile is
+lane-dense at any head width (a 160-wide head's ten exponent bytes would
+pad to 128 lanes with the tokens on the rows).
 
 MLA mode (DeepSeek-V2): the cache is a single compressed latent stream; the
-same tiles serve as keys (full width) and values (first ``v_width`` lanes),
+same tiles serve as keys (full width) and values (first ``v_width`` rows),
 so no V operand is passed -- the kernel reads the K refs for both phases.
 
 :func:`flash_decode` is the one kernel body and ``pallas_call`` shared by
@@ -40,24 +42,49 @@ from repro.core import formats as F
 NEG_INF = -1e30
 
 
-def _deq(mant, exp, micro):
-    qt = F.QuantizedTensor("mx8", mant.shape,
-                           {"mantissa": mant, "exponent": exp, "micro": micro})
-    return F.mx8_dequantize(qt)
-
-
 def _payload(qt: F.QuantizedTensor) -> Tuple[jnp.ndarray, ...]:
     return qt.payload["mantissa"], qt.payload["exponent"], qt.payload["micro"]
 
 
+#: exponent rows dequantized together: 32 of them (512 value rows) keep
+#: every slice of a u8 block on its 32-row tile
+_EXP_CHUNK = 32
+
+
+def time_minor(qt: F.QuantizedTensor) -> F.QuantizedTensor:
+    """Dense ``(B, T, KVH, w)`` payloads -> ``(B, KVH*w, T)``: the layout
+    :func:`flash_decode` reads, tokens on the lanes (the paged pools are
+    stored so)."""
+    def tm(a):
+        return jnp.swapaxes(a.reshape(a.shape[:2] + (-1,)), 1, 2)
+    return F.QuantizedTensor(qt.fmt, qt.shape,
+                             {f: tm(a) for f, a in qt.payload.items()})
+
+
+def _dequant_tile(refs3, lead, scr):
+    """One tile's MX8 payload refs ``(R, t)``, ``(R/16, t)`` x 2 -> f32
+    ``scr`` ``(R, t)``, a chunk of exponent rows at a time."""
+    mant_ref, exp_ref, micro_ref = refs3
+    n_exp = exp_ref.shape[-2]
+    ch = _EXP_CHUNK if n_exp % _EXP_CHUNK == 0 else n_exp
+    rows = ch * F.MX8_GROUP
+    for c in range(n_exp // ch):
+        e = exp_ref[lead + (pl.ds(c * ch, ch), slice(None))]
+        m = micro_ref[lead + (pl.ds(c * ch, ch), slice(None))]
+        mant = mant_ref[lead + (pl.ds(c * rows, rows), slice(None))]
+        scr[pl.ds(c * rows, rows), :] = F.mx8_dequantize_rows(mant, e, m)
+
+
 def _flash_kernel(*refs, n_prefetch: int, paged: bool, mla: bool, t_blk: int,
-                  n_t: int, n_q: int, g: int, v_width: int):
+                  n_t: int, n_q: int, g: int, dk: int, dv: int):
     """One KV tile of streaming-softmax attention for every KV head of a row.
 
     The query block is ``(KVH, n_q*g, dk)``: query row ``r`` belongs to
     draft position ``r // g`` and sees positions ``< len - (n_q-1 - r//g)``
-    (``n_q == 1`` is plain decode).  The tile of all heads is dequantized at
-    once into VMEM scratch; a loop over heads then runs the flash update.
+    (``n_q == 1`` is plain decode).  The tile holds every head's rows,
+    tokens on the lanes (``(KVH*dk, t)``); it is dequantized at once into
+    VMEM scratch, and a loop over heads then runs the flash update on each
+    head's ``(dk, t)`` rows.
     """
     lens_ref = refs[n_prefetch - 1]
     q_ref, *rest = refs[n_prefetch:]
@@ -74,19 +101,19 @@ def _flash_kernel(*refs, n_prefetch: int, paged: bool, mla: bool, t_blk: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    lead = (0, 0) if paged else (0,)
     for refs3, scr in zip((kv_refs[:3], kv_refs[3:]), kv_scr):
-        # (t_blk, KVH, w) payloads -> one 2-D dequantize -> f32 scratch
-        parts = [(r[0, 0] if paged else r[0]) for r in refs3]
-        flat = [a.reshape(t_blk * n_heads, a.shape[-1]) for a in parts]
-        scr[...] = _deq(*flat).reshape(scr.shape)
+        _dequant_tile(refs3, lead, scr)
+
+    def rows(h, w):
+        return pl.ds(pl.multiple_of(h * w, 8) if w % 8 == 0 else h * w, w)
 
     def head(h, carry):
-        K = kv_scr[0][:, pl.ds(h, 1), :].reshape(t_blk, -1)   # (t_blk, dk)
-        V = (K[:, :v_width] if mla
-             else kv_scr[1][:, pl.ds(h, 1), :].reshape(t_blk, -1))
+        kt = kv_scr[0][rows(h, dk), :]                         # (dk, t_blk)
+        vt = kt[:dv] if mla else kv_scr[1][rows(h, dv), :]     # (dv, t_blk)
         qv = q_ref[0, h].astype(jnp.float32)                   # (n_q*g, dk)
         scores = jax.lax.dot_general(
-            qv, K, (((1,), (1,)), ((), ())),
+            qv, kt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (n_q*g, t_blk)
         pos = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + t * t_blk
         qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g
@@ -100,7 +127,7 @@ def _flash_kernel(*refs, n_prefetch: int, paged: bool, mla: bool, t_blk: int,
         p = jnp.exp(scores - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, V, (((1,), (0,)), ((), ())),
+            p, vt, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                # (n_q*g, dv)
         m_scr[h] = m_new
         l_scr[h] = l_new
@@ -116,7 +143,7 @@ def _flash_kernel(*refs, n_prefetch: int, paged: bool, mla: bool, t_blk: int,
 
 def flash_decode(
     qg: jnp.ndarray,                     # (B, KVH, n_q*g, dk) scaled queries
-    k: F.QuantizedTensor,                # dense (B, T, KVH, dk) | pool (P, G, 128, KVH, dk)
+    k: F.QuantizedTensor,                # dense (B, KVH*dk, T) | pool (P, G, KVH*dk, 128)
     v: Optional[F.QuantizedTensor],      # like k; None => MLA
     lengths: jnp.ndarray,                # (B,) valid length incl. the n_q rows
     *, n_q: int, v_width: int, interpret: bool, name: str,
@@ -124,47 +151,48 @@ def flash_decode(
 ) -> jnp.ndarray:
     """Shared flash-decoding ``pallas_call``; returns (B, KVH, n_q*g, dv).
 
-    Dense caches tile the time axis in ``t_block`` steps.  With ``pages =
-    (bt, group)`` the caches are page pools and the grid's time axis walks
-    the scalar-prefetched block table ``bt[B, npg]`` instead, one 128-token
-    page per step, so no dense copy of the context exists.  ``name`` is the
-    kernel's name in the compiled program (its SPU op kind).
+    The payloads hold tokens on the last axis (:func:`time_minor`), every
+    head's rows stacked before it, so a tile is lane-dense at any head
+    width.  Dense caches tile the time axis in ``t_block`` steps.  With
+    ``pages = (bt, group)`` the caches are page pools and the grid's time
+    axis walks the scalar-prefetched block table ``bt[B, npg]`` instead,
+    one 128-token page per step, so no dense copy of the context exists.
+    ``name`` is the kernel's name in the compiled program (its SPU op kind).
     """
     B, KVH, QG, dk = qg.shape
     mla = v is None
     lens = lengths.astype(jnp.int32).reshape(B)
     parts: Sequence[jnp.ndarray] = _payload(k) + (() if mla else _payload(v))
     if pages is None:
-        T = k.shape[1]
+        T = k.payload["mantissa"].shape[-1]
         assert T % t_block == 0
         n_t = T // t_block
         prefetch = (lens,)
-        kv_map = lambda b, t, *_: (b, t, 0, 0)
-        kv_block = lambda w: (1, t_block, KVH, w)
+        kv_map = lambda b, t, *_: (b, 0, t)
+        kv_block = lambda r: (1, r, t_block)
     else:
         bt, group = pages
-        t_block, n_t = k.payload["mantissa"].shape[2], int(bt.shape[1])
+        t_block, n_t = k.payload["mantissa"].shape[-1], int(bt.shape[1])
         prefetch = (bt, jnp.asarray(group, jnp.int32).reshape(1), lens)
-        kv_map = lambda b, t, bt_ref, g_ref, _: (bt_ref[b, t], g_ref[0],
-                                                 0, 0, 0)
-        kv_block = lambda w: (1, 1, t_block, KVH, w)
-    dv = v_width if mla else v.payload["mantissa"].shape[-1]
+        kv_map = lambda b, t, bt_ref, g_ref, _: (bt_ref[b, t], g_ref[0], 0, 0)
+        kv_block = lambda r: (1, 1, r, t_block)
+    dv = v_width if mla else v.payload["mantissa"].shape[-2] // KVH
 
     kernel = functools.partial(
         _flash_kernel, n_prefetch=len(prefetch), paged=pages is not None,
-        mla=mla, t_blk=t_block, n_t=n_t, n_q=n_q, g=QG // n_q, v_width=dv)
+        mla=mla, t_blk=t_block, n_t=n_t, n_q=n_q, g=QG // n_q, dk=dk, dv=dv)
     row_map = lambda b, t, *_: (b, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B, n_t),
         in_specs=[pl.BlockSpec((1, KVH, QG, dk), row_map)]
-        + [pl.BlockSpec(kv_block(a.shape[-1]), kv_map) for a in parts],
+        + [pl.BlockSpec(kv_block(a.shape[-2]), kv_map) for a in parts],
         out_specs=pl.BlockSpec((1, KVH, QG, dv), row_map),
         scratch_shapes=[
             pltpu.VMEM((KVH, QG, 1), jnp.float32),
             pltpu.VMEM((KVH, QG, 1), jnp.float32),
             pltpu.VMEM((KVH, QG, dv), jnp.float32),
-        ] + [pltpu.VMEM((t_block, KVH, parts[i].shape[-1]), jnp.float32)
+        ] + [pltpu.VMEM((parts[i].shape[-2], t_block), jnp.float32)
              for i in ((0,) if mla else (0, 3))],
     )
     return pl.pallas_call(
@@ -195,7 +223,8 @@ def mx_attention_decode(
     assert qV is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
     qg = (q.astype(jnp.float32) * scale).reshape(B, KVH, H // KVH, dk)
-    y = flash_decode(qg, qK, qV, lengths, n_q=1, v_width=v_width,
-                     t_block=t_block, interpret=interpret,
+    y = flash_decode(qg, time_minor(qK),
+                     None if qV is None else time_minor(qV), lengths, n_q=1,
+                     v_width=v_width, t_block=t_block, interpret=interpret,
                      name="spu_attn_decode")
     return y.reshape(B, H, -1)

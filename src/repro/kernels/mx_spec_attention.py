@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import formats as F
 from repro.core.paged import PAGE_TOKENS
-from repro.kernels.mx_attention import flash_decode
+from repro.kernels.mx_attention import flash_decode, time_minor
 
 
 def _fold_queries(q: jnp.ndarray, KVH: int, scale: float) -> jnp.ndarray:
@@ -64,7 +64,8 @@ def mx_spec_attention_decode(
     assert dk == qK.shape[3] and H % KVH == 0
     assert qV is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5
-    y = flash_decode(_fold_queries(q, KVH, scale), qK, qV, lengths, n_q=Kq,
+    y = flash_decode(_fold_queries(q, KVH, scale), time_minor(qK),
+                     None if qV is None else time_minor(qV), lengths, n_q=Kq,
                      v_width=v_width, t_block=t_block, interpret=interpret,
                      name="spu_spec_verify")
     return _unfold_outputs(y, Kq)
@@ -74,7 +75,7 @@ def mx_spec_attention_decode(
     jax.jit, static_argnames=("interpret", "v_width", "scale"))
 def mx_paged_spec_attention_decode(
     q: jnp.ndarray,                 # (B, Kq, H, dk)
-    k_pool: F.QuantizedTensor,      # pools (P, G, 128, KVH, dk)
+    k_pool: F.QuantizedTensor,      # pools of logical shape (P, G, 128, KVH, dk)
     v_pool: Optional[F.QuantizedTensor],  # like k_pool; None => MLA
     bt: jnp.ndarray,                # (B, npg) int32 physical page ids
     group,                          # () int32 stacked-layer index
@@ -89,7 +90,7 @@ def mx_paged_spec_attention_decode(
     query block and the VMEM accumulators widen by ``Kq``.
     """
     B, Kq, H, dk = q.shape
-    P, G, TB, KVH, dkc = k_pool.payload["mantissa"].shape
+    P, G, TB, KVH, dkc = k_pool.shape
     assert dk == dkc and H % KVH == 0 and TB == PAGE_TOKENS
     assert v_pool is not None or v_width is not None
     scale = scale if scale is not None else dk ** -0.5

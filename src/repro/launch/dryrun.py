@@ -71,8 +71,8 @@ def _mem_dict(mem) -> Dict[str, float]:
 
 
 # production tuning choices per cell (recorded in EXPERIMENTS.md):
-# zamba2 train microbatches 2x -- its 6-mamba+shared-attn group holds the
-# largest per-group working set of the fleet.
+# zamba2 train microbatches 2x -- its shared blocks' 2 d_model-wide
+# attention holds the largest working set of the fleet.
 CELL_TUNING = {
     ("zamba2-2.7b", "train_4k"): {"grad_accum": 2},
     # 236B on 256 chips: ZeRO moments alone are 7.4 GiB/chip; microbatch 4x
@@ -249,17 +249,23 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
         # O(S*c*dk) vs the O(S*dk*dv) state term) shift by <2% of the total
         probe_ssm = (dataclasses.replace(cfg.ssm, chunk=512)
                      if cfg.ssm is not None else None)
-        for k in ks:
+        def probe(k, apps=()):
+            # ``apps``: the probe's shared-block schedule (hybrids); the
+            # depth probes run without one
             cfg_k = cfg.with_(cost_probe=True, scan_layers=False,
                               n_layers=pre + k * pat, ssm=probe_ssm,
-                              attn_q_chunk=4096, attn_kv_chunk=4096)
+                              attn_q_chunk=4096, attn_kv_chunk=4096,
+                              hybrid_layer_ids=apps)
             pk_shapes = SP.params_struct(cfg_k)
             pk_shard = SH.param_shardings(pk_shapes, cfg_k, par)
             with par.mesh:
                 # grad_accum=1: the microbatch loop is a while body that
                 # cost_analysis counts once; accumulation doesn't change FLOPs
                 ck = _compile_step(cfg_k, sc_probe, par, pk_shapes, pk_shard, 1)
-            probes[k] = _probe_costs(ck, par)
+            return _probe_costs(ck, par)
+
+        for k in ks:
+            probes[k] = probe(k)
         corr = _slstm_correction(cfg, sc_probe, par)
         k1, k2 = ks
         delta = (probes[k2]["flops"] - probes[k1]["flops"]) / (k2 - k1)
@@ -269,6 +275,12 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
             # GSPMD partitioned the two probe depths differently; fall back
             # to scaling the deeper probe by group count
             flops = probes[k2]["flops"] * cfg.n_groups / k2
+        if cfg.n_shared_apps:
+            # a shared-block application costs the same wherever it runs:
+            # the deeper probe once more with one before each of its layers
+            per_app = (probe(k2, tuple(range(k2)))["flops"]
+                       - probes[k2]["flops"]) / k2
+            flops += cfg.n_shared_apps * per_app
         flops_per_chip = (flops + corr["flops"]) * s_scale
         probe_diag = {f"probe{k1}_flops": probes[k1]["flops"],
                       f"probe{k2}_flops": probes[k2]["flops"],

@@ -185,16 +185,20 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def init_attention(key, cfg: ModelConfig, n_heads: Optional[int] = None,
-                   n_kv: Optional[int] = None) -> L.Params:
+                   n_kv: Optional[int] = None,
+                   d_in: Optional[int] = None) -> L.Params:
+    """Projections from a ``d_in``-wide input (``d_model`` by default) back
+    to ``d_model``."""
     H = n_heads or cfg.n_heads
     KVH = n_kv or cfg.n_kv_heads
     d, dh = cfg.d_model, cfg.head_dim
+    d_in = d_in or d
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 4)
     return {
-        "wq": L.dense_init(ks[0], d, H * dh, dt),
-        "wk": L.dense_init(ks[1], d, KVH * dh, dt),
-        "wv": L.dense_init(ks[2], d, KVH * dh, dt),
+        "wq": L.dense_init(ks[0], d_in, H * dh, dt),
+        "wk": L.dense_init(ks[1], d_in, KVH * dh, dt),
+        "wv": L.dense_init(ks[2], d_in, KVH * dh, dt),
         "wo": L.dense_init(ks[3], H * dh, d, dt, 1.0 / np.sqrt(2 * cfg.n_layers)),
     }
 
@@ -203,8 +207,10 @@ def attention_forward(p: L.Params, x: jnp.ndarray, cfg: ModelConfig,
                       positions: jnp.ndarray,
                       n_heads: Optional[int] = None,
                       n_kv: Optional[int] = None,
-                      prefix_len: int = 0) -> jnp.ndarray:
-    """Full-sequence attention (train / prefill math)."""
+                      prefix_len: int = 0,
+                      scale: Optional[float] = None) -> jnp.ndarray:
+    """Full-sequence attention (train / prefill math); ``scale`` multiplies
+    the scores (``head_dim ** -0.5`` by default)."""
     B, S, d = x.shape
     H = n_heads or cfg.n_heads
     KVH = n_kv or cfg.n_kv_heads
@@ -216,7 +222,7 @@ def attention_forward(p: L.Params, x: jnp.ndarray, cfg: ModelConfig,
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
     o = blockwise_attention(q, k, v, causal=cfg.causal and not cfg.encoder_only,
-                            prefix_len=prefix_len,
+                            prefix_len=prefix_len, scale=scale,
                             q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
                             unroll=cfg.cost_probe)
     return o.reshape(B, S, H * dh) @ p["wo"]
@@ -241,7 +247,8 @@ def attention_prefill_kv(p: L.Params, x: jnp.ndarray, cfg: ModelConfig,
 def attention_decode(p: L.Params, x: jnp.ndarray, cache: AC.KVCache,
                      cfg: ModelConfig, positions: jnp.ndarray, seed,
                      n_heads: Optional[int] = None,
-                     n_kv: Optional[int] = None
+                     n_kv: Optional[int] = None,
+                     scale: Optional[float] = None
                      ) -> Tuple[jnp.ndarray, AC.KVCache]:
     """One-token decode: x (B, 1, d) -> (out (B,1,d), updated cache)."""
     B, _, d = x.shape
@@ -256,14 +263,16 @@ def attention_decode(p: L.Params, x: jnp.ndarray, cache: AC.KVCache,
         k = L.apply_rope(k, positions, cfg.rope_theta)
     # one registered SPU op step: kv_append + attn_decode via the registry
     o, cache = OPS.attention_decode_step(cache, k, v, q.reshape(B, H, dh),
-                                         cfg.state_quant, seed=seed)
+                                         cfg.state_quant, scale=scale,
+                                         seed=seed)
     return (o.reshape(B, 1, H * dh).astype(x.dtype) @ p["wo"]), cache
 
 
 def attention_spec_decode(p: L.Params, x: jnp.ndarray, cache: AC.KVCache,
                           cfg: ModelConfig, positions: jnp.ndarray, seed,
                           n_heads: Optional[int] = None,
-                          n_kv: Optional[int] = None
+                          n_kv: Optional[int] = None,
+                          scale: Optional[float] = None
                           ) -> Tuple[jnp.ndarray, AC.KVCache]:
     """Speculative decode: x (B, n, d) -> (out (B, n, d), updated cache).
 
@@ -282,7 +291,7 @@ def attention_spec_decode(p: L.Params, x: jnp.ndarray, cache: AC.KVCache,
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
     o, cache = OPS.attention_spec_step(cache, k, v, q, cfg.state_quant,
-                                       seed=seed)
+                                       scale=scale, seed=seed)
     return (o.reshape(B, n, H * dh).astype(x.dtype) @ p["wo"]), cache
 
 

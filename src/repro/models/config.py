@@ -72,9 +72,15 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (Zamba2): one shared attention+MLP block applied after every
-    # pattern group (weights shared across applications)
-    shared_attn: bool = False
+    # hybrid (Zamba2): the layers listed in ``hybrid_layer_ids`` each take
+    # one application of a shared attention + MLP block on their input,
+    # added before the layer's norm (``models/model.py``, "hybrid").  The
+    # ``n_mem_blocks`` shared blocks alternate over the applications; each
+    # application has its own output linear and a rank-``adapter_rank``
+    # LoRA on the block MLP's fused gate/up projection.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_mem_blocks: int = 1
+    adapter_rank: int = 0
     # modality frontends are STUBS: input_specs() supplies precomputed
     # patch/frame embeddings of width frontend_dim
     frontend: Optional[str] = None  # patch|audio_frames
@@ -101,7 +107,32 @@ class ModelConfig:
     attn_q_chunk: int = 512
     attn_kv_chunk: int = 512
 
+    def __post_init__(self):
+        # list-valued fields (a configuration read from JSON) become tuples,
+        # so the config stays hashable as a static jit argument
+        for name in ("pattern", "prelude", "hybrid_layer_ids"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        ids = self.hybrid_layer_ids
+        if ids:
+            assert list(ids) == sorted(set(ids)) and 0 <= ids[0] \
+                and ids[-1] < self.n_layers, (
+                    f"{self.name}: hybrid_layer_ids {ids} must be increasing "
+                    f"layer indices below n_layers={self.n_layers}")
+            assert len(self.pattern) == 1 and not self.prelude, (
+                f"{self.name}: shared blocks need a one-kind layer stack")
+            assert self.n_mem_blocks >= 1 and self.adapter_rank >= 1
+
     # ---- derived ----
+    @property
+    def shared_attn(self) -> bool:
+        """Whether shared attention blocks run (the hybrid schedule)."""
+        return bool(self.hybrid_layer_ids)
+
+    @property
+    def n_shared_apps(self) -> int:
+        """Applications of the shared blocks in one forward pass."""
+        return len(self.hybrid_layer_ids)
+
     @property
     def n_groups(self) -> int:
         n = self.n_layers - len(self.prelude)

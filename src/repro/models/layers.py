@@ -126,6 +126,15 @@ def apply_ffn(p: Params, x: jnp.ndarray, kind: str) -> jnp.ndarray:
     return h @ p["wo"]
 
 
+def shared_mlp(p: Params, adapter: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """Zamba2's shared-block MLP: a fused gate/up projection plus the
+    application's own low-rank adapter, exact (erf) GELU on the gate half
+    times the up half, then the down projection."""
+    gu = x @ p["wi"] + (x @ adapter["lora_a"]) @ adapter["lora_b"]
+    gate, up = jnp.split(gu, 2, axis=-1)
+    return (jax.nn.gelu(gate, approximate=False) * up) @ p["wo"]
+
+
 # ---------------------------------------------------------------------------
 # Mixture-of-Experts (expert-parallel over the 'model' mesh axis)
 # ---------------------------------------------------------------------------

@@ -1,8 +1,9 @@
 """Model assembly: block patterns, scan-over-layers, train/prefill/decode.
 
 A model is a repeating ``pattern`` of mixer blocks (attn | mla | mamba2 | gla
-| retnet | hgrn2 | mlstm | slstm), optionally followed by a weight-shared
-attention block per group (Zamba2).  Parameters of the repeating groups are
+| retnet | hgrn2 | mlstm | slstm); a hybrid (Zamba2) also applies shared
+attention + MLP blocks on the input of the layers its config lists (see
+"hybrid schedule" below).  Parameters of the repeating groups are
 stacked along a leading axis and executed with ``jax.lax.scan`` so the HLO
 is O(1) in depth (MaxText-style), with per-group remat.
 
@@ -82,15 +83,31 @@ def _init_element(key, cfg: ModelConfig, kind: str, layer_idx: int,
 
 
 def _init_shared_block(key, cfg: ModelConfig) -> Params:
-    """Zamba2-style shared attention + MLP block."""
-    k1, k2 = jax.random.split(key)
+    """One Zamba2 shared block: attention over the stream concatenated with
+    the embedding (2 d_model wide) back to d_model, then an MLP with a fused
+    gate/up projection."""
+    k1, k2, k3 = jax.random.split(key, 3)
     dt = jnp.dtype(cfg.param_dtype)
+    d = cfg.d_model
     return {
-        "norm": L.init_norm(cfg.d_model, cfg.norm_kind, dt),
-        "attn": ATT.init_attention(k1, cfg),
-        "ffn_norm": L.init_norm(cfg.d_model, cfg.norm_kind, dt),
-        "ffn": L.init_ffn(k2, cfg),
+        "norm": L.init_norm(2 * d, cfg.norm_kind, dt),
+        "attn": ATT.init_attention(k1, cfg, d_in=2 * d),
+        "ffn_norm": L.init_norm(d, cfg.norm_kind, dt),
+        "ffn": {"wi": L.dense_init(k2, d, 2 * cfg.d_ff, dt),
+                "wo": L.dense_init(k3, cfg.d_ff, d, dt,
+                                   1.0 / np.sqrt(2 * cfg.n_layers))},
     }
+
+
+def _init_application(key, cfg: ModelConfig) -> Params:
+    """One shared-block application's own weights: the linear on the
+    block's output and the LoRA on the MLP's gate/up projection."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    dt = jnp.dtype(cfg.param_dtype)
+    d, r = cfg.d_model, cfg.adapter_rank
+    return {"linear": L.dense_init(k1, d, d, dt),
+            "lora_a": L.dense_init(k2, d, r, dt),
+            "lora_b": L.dense_init(k3, r, 2 * cfg.d_ff, dt)}
 
 
 def init_model(key, cfg: ModelConfig) -> Params:
@@ -126,7 +143,11 @@ def init_model(key, cfg: ModelConfig) -> Params:
                                            jnp.arange(cfg.n_groups))
 
     if cfg.shared_attn:
-        params["shared"] = _init_shared_block(keys[-4], cfg)
+        nb = cfg.n_mem_blocks
+        sks = jax.random.split(keys[-4], nb + cfg.n_shared_apps)
+        params["shared"] = tuple(_init_shared_block(k, cfg) for k in sks[:nb])
+        params["hybrid"] = tuple(_init_application(k, cfg)
+                                 for k in sks[nb:])
     params["final_norm"] = L.init_norm(cfg.d_model, cfg.norm_kind, dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = L.dense_init(keys[-5], cfg.d_model, cfg.vocab_size, dt)
@@ -145,8 +166,11 @@ def _lm_head(params: Params, cfg: ModelConfig) -> jnp.ndarray:
 
 def _element_forward(p: Params, x, cfg: ModelConfig, kind: str,
                      positions, prefix_len: int, want_cache: bool,
-                     mesh_axes) -> Tuple[jnp.ndarray, Any]:
-    h = L.apply_norm(p["norm"], x, cfg.norm_kind, cfg.norm_eps)
+                     mesh_axes, extra=None) -> Tuple[jnp.ndarray, Any]:
+    """One layer; ``extra`` (a shared block's output) is added to its input
+    before the norm and not to the residual stream."""
+    h = L.apply_norm(p["norm"], x if extra is None else x + extra,
+                     cfg.norm_kind, cfg.norm_eps)
     cache = None
     if kind == "attn":
         y = ATT.attention_forward(p["mixer"], h, cfg, positions,
@@ -209,18 +233,184 @@ def _build_kv_cache(k: jnp.ndarray, v: Optional[jnp.ndarray],
                       lengths, sq.fmt, v_width)
 
 
-def _shared_block_forward(p: Params, x, cfg: ModelConfig, positions,
-                          prefix_len: int, want_cache: bool):
-    h = L.apply_norm(p["norm"], x, cfg.norm_kind, cfg.norm_eps)
-    y = ATT.attention_forward(p["attn"], h, cfg, positions,
-                              prefix_len=prefix_len)
-    cache = None
-    if want_cache:
-        kv = ATT.attention_prefill_kv(p["attn"], h, cfg, positions)
-        cache = _build_kv_cache(kv[0], kv[1], cfg)
-    x = x + y
-    h = L.apply_norm(p["ffn_norm"], x, cfg.norm_kind, cfg.norm_eps)
-    return x + L.apply_ffn(p["ffn"], h, cfg.ffn_kind), cache
+# ---------------------------------------------------------------------------
+# hybrid schedule (Zamba2): shared blocks on the input of chosen layers
+# ---------------------------------------------------------------------------
+#
+# Application j of the shared blocks runs before layer
+# ``hybrid_layer_ids[j]``: block ``j % n_mem_blocks`` reads the stream x
+# concatenated with the embedding x0 (RMSNorm over 2 d_model), attends with
+# scores scaled by (head_dim / 2) ** -0.5, and its MLP output goes through
+# the application's own linear.  That result is added to the input of the
+# layer before its norm -- the stream itself never receives it -- and the
+# block has no residual inside.  The layers run in segments, each a scan
+# over its layers by index that starts at a hybrid layer; the block output
+# rides the carry and is zero after the segment's first layer.
+
+def _block_of(cfg: ModelConfig, app: int) -> int:
+    """The shared block that application ``app`` runs: they alternate."""
+    return app % cfg.n_mem_blocks
+
+
+def _segments(cfg: ModelConfig):
+    """[(lo, hi, app)]: layers lo..hi-1 run as one scan, the first of them
+    after shared-block application ``app`` (None before the first)."""
+    bounds = (0,) + cfg.hybrid_layer_ids + (cfg.n_layers,)
+    return [(lo, hi, j - 1 if j else None)
+            for j, (lo, hi) in enumerate(zip(bounds, bounds[1:])) if hi > lo]
+
+
+def _shared_scale(cfg: ModelConfig) -> float:
+    return (cfg.head_dim / 2) ** -0.5
+
+
+def _shared_in(p: Params, cfg: ModelConfig, x, x0):
+    return L.apply_norm(p["norm"], jnp.concatenate([x, x0], axis=-1),
+                        cfg.norm_kind, cfg.norm_eps)
+
+
+def _shared_out(p: Params, a: Params, cfg: ModelConfig, y):
+    h = L.apply_norm(p["ffn_norm"], y, cfg.norm_kind, cfg.norm_eps)
+    return L.shared_mlp(p["ffn"], a, h) @ a["linear"]
+
+
+def _take(tree, i):
+    """Row ``i`` of every leaf of a layer-stacked tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def _put(tree, rows, i):
+    return jax.tree.map(
+        lambda a, r: jax.lax.dynamic_update_index_in_dim(
+            a, r.astype(a.dtype), i, 0), tree, rows)
+
+
+def _stack(trees):
+    return jax.tree.map(lambda *a: jnp.stack(a), *trees)
+
+
+def _scan_segment(body, carry, lo: int, hi: int, cfg: ModelConfig):
+    """``body(carry, i)`` over layers lo..hi-1: a scan, or unrolled with
+    static indices when ``cfg.scan_layers`` is off (the cost probe)."""
+    if cfg.scan_layers:
+        return jax.lax.scan(body, carry, jnp.arange(lo, hi))
+    ys = []
+    for i in range(lo, hi):
+        carry, y = body(carry, i)
+        ys.append(y)
+    return carry, _stack(ys)
+
+
+def _run_hybrid(params: Params, x, cfg: ModelConfig, positions,
+                prefix_len: int, want_cache: bool, mesh_axes, ckpt):
+    """Full-sequence forward of the hybrid schedule; caches as
+    :func:`init_decode_caches` lays them out (layer stack, then the K/V of
+    every application stacked)."""
+    kind = cfg.pattern[0]
+    x0 = x
+
+    def layer(carry, i):
+        x, t = carry
+        if cfg.seq_parallel:
+            x = _seq_shard(x, mesh_axes)
+        fn = ckpt(lambda p, xx, tt: _element_forward(
+            p[0], xx, cfg, kind, positions, prefix_len, want_cache,
+            mesh_axes, extra=tt))
+        x, c = fn(_take(params["groups"], i), x, t)
+        return (x, jnp.zeros_like(t)), (c,)
+
+    def application(p, a, x):
+        h = _shared_in(p, cfg, x, x0)
+        y = ATT.attention_forward(p["attn"], h, cfg, positions,
+                                  prefix_len=prefix_len,
+                                  scale=_shared_scale(cfg))
+        cache = None
+        if want_cache:
+            k, v = ATT.attention_prefill_kv(p["attn"], h, cfg, positions)
+            cache = _build_kv_cache(k, v, cfg)
+        return _shared_out(p, a, cfg, y), cache
+
+    ys, kv = [], []
+    for lo, hi, app in _segments(cfg):
+        t = jnp.zeros_like(x)
+        if app is not None:
+            t, c = ckpt(application)(params["shared"][_block_of(cfg, app)],
+                                     params["hybrid"][app], x)
+            kv.append(c)
+        (x, _), y = _scan_segment(layer, (x, t), lo, hi, cfg)
+        ys.append(y)
+    if not want_cache:
+        return x, None
+    layers = jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+    return x, layers + (_stack(kv),)
+
+
+def _decode_hybrid(params: Params, cfg: ModelConfig, x, caches, positions,
+                   lengths, seed, spec: bool):
+    """Decode through the hybrid schedule over dense or paged caches.
+
+    ``x`` (B, n, d) holds one token a row (``spec`` off) or the verify
+    positions.  Layer i runs with the seed of the scanned path's group i;
+    application j's K/V append with that of its layer plus 99.  Returns
+    (x, new caches, per-position state snapshots or None)."""
+    from repro.core import paged as PG
+    kind = cfg.pattern[0]
+    x0 = x
+    carried, scanned = PG.split_paged(caches[0])
+    kv = caches[1]
+    paged = isinstance(kv, PG.PagedKVCache)
+    dense_kv = []
+
+    def layer(carry, i):
+        x, t, carried, scanned = carry
+        seed_i = (jnp.uint32(seed) + jnp.asarray(i, jnp.uint32)
+                  * jnp.uint32(_SEED_STRIDE) + jnp.uint32(1))
+        c = PG.merge_paged(PG.with_group(carried, i, lengths),
+                           _take(scanned, i))
+        p = _take(params["groups"], i)[0]
+        snap = None
+        if spec:
+            x, c, snap = _element_spec_decode(p, x, c, cfg, kind, positions,
+                                              seed_i, extra=t)
+        else:
+            x, c = _element_decode(p, x, c, cfg, kind, positions, seed_i,
+                                   extra=t)
+        ca, sc = PG.split_paged(c)
+        return (x, jnp.zeros_like(t), ca, _put(scanned, sc, i)), snap
+
+    snaps = []
+    for lo, hi, app in _segments(cfg):
+        t = jnp.zeros_like(x)
+        if app is not None:
+            p = params["shared"][_block_of(cfg, app)]
+            h = _shared_in(p, cfg, x, x0)
+            c = (PG.with_group(kv, app, lengths) if paged
+                 else jax.tree.map(lambda a: a[app], kv))
+            aseed = jnp.uint32(seed) + jnp.uint32(_SEED_STRIDE * lo + 99)
+            if spec:
+                y, c = ATT.attention_spec_decode(p["attn"], h, c, cfg,
+                                                 positions, aseed,
+                                                 scale=_shared_scale(cfg))
+            else:
+                y, c = ATT.attention_decode(p["attn"], h, c, cfg,
+                                            positions[:, None], aseed,
+                                            scale=_shared_scale(cfg))
+            t = _shared_out(p, params["hybrid"][app], cfg, y)
+            if paged:
+                kv = c
+            else:
+                dense_kv.append(c)
+        (x, _, carried, scanned), snap = _scan_segment(
+            layer, (x, t, carried, scanned), lo, hi, cfg)
+        snaps.append(snap)
+    new = (PG.merge_paged(carried, scanned), kv if paged else _stack(dense_kv))
+    if not spec:
+        return x, new, None
+    # (layers, n, B, ...) -> position-major (n, B, layers, ...)
+    snap = jax.tree.map(lambda *a: jnp.moveaxis(jnp.concatenate(a), 0, 2),
+                        *snaps)
+    return x, new, (snap, None)
 
 
 def _seq_shard(x: jnp.ndarray, par) -> jnp.ndarray:
@@ -244,7 +434,6 @@ def _seq_shard(x: jnp.ndarray, par) -> jnp.ndarray:
 def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 positions, prefix_len: int, want_cache: bool,
                 mesh_axes) -> Tuple[jnp.ndarray, Any]:
-    shared = params.get("shared")
     if cfg.seq_parallel:
         x = _seq_shard(x, mesh_axes)
 
@@ -256,9 +445,13 @@ def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 
     def _maybe_ckpt(fn):
         # nested remat: one element's backward lives at a time, so a group
-        # of many elements (zamba2: 6 mamba + shared attn) does not hold
-        # every sublayer's cotangents simultaneously
+        # of many elements does not hold every sublayer's cotangents
+        # simultaneously
         return jax.checkpoint(fn, prevent_cse=False) if cfg.remat else fn
+
+    if cfg.shared_attn:
+        return _run_hybrid(params, x, cfg, positions, prefix_len, want_cache,
+                           mesh_axes, _maybe_ckpt)
 
     def group_body(x, ginp):
         gparams, gidx = ginp
@@ -271,12 +464,6 @@ def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                     p, xx, cfg, kind, positions, prefix_len, want_cache,
                     mesh_axes))
             x, c = fn(gparams[pos], x)
-            caches.append(c)
-        if shared is not None:
-            fn = _maybe_ckpt(
-                lambda p, xx: _shared_block_forward(
-                    p, xx, cfg, positions, prefix_len, want_cache))
-            x, c = fn(shared, x)
             caches.append(c)
         return x, tuple(caches)
 
@@ -401,14 +588,16 @@ def init_decode_caches(cfg: ModelConfig, B: int, cache_capacity: int) -> Any:
             return SSM.slstm_init_state(B, cfg)
         raise ValueError(kind)
 
-    per_group = [one_element(k) for k in cfg.pattern]
+    def stack(tree, n):
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
+
+    stacked = stack(tuple(one_element(k) for k in cfg.pattern), cfg.n_groups)
     if cfg.shared_attn:
-        per_group.append(AC.init_kv_cache(B, cache_capacity, cfg.n_kv_heads,
-                                          cfg.head_dim, cfg.state_quant))
-    # lengths: how many positions already in the caches
-    stacked = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (cfg.n_groups,) + x.shape),
-        tuple(per_group))
+        # the K/V of every shared-block application, stacked
+        stacked += (stack(AC.init_kv_cache(B, cache_capacity, cfg.n_kv_heads,
+                                           cfg.head_dim, cfg.state_quant),
+                          cfg.n_shared_apps),)
     if cfg.prelude:
         return {"prelude": tuple(one_element(k) for k in cfg.prelude),
                 "groups": stacked}
@@ -436,8 +625,9 @@ def set_cache_lengths(caches: Any, lengths: jnp.ndarray) -> Any:
 
 
 def _element_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
-                    positions, seed) -> Tuple[jnp.ndarray, Any]:
-    h = L.apply_norm(p["norm"], x, cfg.norm_kind, cfg.norm_eps)
+                    positions, seed, extra=None) -> Tuple[jnp.ndarray, Any]:
+    h = L.apply_norm(p["norm"], x if extra is None else x + extra,
+                     cfg.norm_kind, cfg.norm_eps)
     if kind == "attn":
         y, cache = ATT.attention_decode(p["mixer"], h, cache, cfg,
                                         positions[:, None], seed)
@@ -480,7 +670,10 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     positions = lengths
     if cfg.pos_emb == "learned":
         x = x + params["pos"][positions][:, None]
-    shared = params.get("shared")
+    if cfg.shared_attn:
+        x, new_caches, _ = _decode_hybrid(params, cfg, x, caches, positions,
+                                          lengths, seed, spec=False)
+        return _head(params, cfg, x[:, 0]), new_caches
 
     if cfg.prelude:
         prelude_caches, caches = caches["prelude"], caches["groups"]
@@ -499,15 +692,6 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             x, c = _element_decode(gparams[pos], x, gcaches[pos], cfg, kind,
                                    positions, seed_g + jnp.uint32(pos + 1))
             new_caches.append(c)
-        if shared is not None:
-            h = L.apply_norm(shared["norm"], x, cfg.norm_kind, cfg.norm_eps)
-            y, c = ATT.attention_decode(shared["attn"], h, gcaches[-1], cfg,
-                                        positions[:, None],
-                                        seed_g + jnp.uint32(99))
-            x = x + y
-            h = L.apply_norm(shared["ffn_norm"], x, cfg.norm_kind, cfg.norm_eps)
-            x = x + L.apply_ffn(shared["ffn"], h, cfg.ffn_kind)
-            new_caches.append(c)
         return x, tuple(new_caches)
 
     if cfg.scan_layers:
@@ -525,9 +709,12 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
 
     if cfg.prelude:
         new_caches = {"prelude": tuple(new_prelude), "groups": new_caches}
-    x = L.apply_norm(params["final_norm"], x[:, 0], cfg.norm_kind, cfg.norm_eps)
-    logits = x @ _lm_head(params, cfg)
-    return logits, new_caches
+    return _head(params, cfg, x[:, 0]), new_caches
+
+
+def _head(params: Params, cfg: ModelConfig, x):
+    x = L.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
+    return x @ _lm_head(params, cfg)
 
 
 @_f32_matmuls
@@ -557,7 +744,10 @@ def paged_decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     positions = lengths
     if cfg.pos_emb == "learned":
         x = x + params["pos"][positions][:, None]
-    shared = params.get("shared")
+    if cfg.shared_attn:
+        x, new_caches, _ = _decode_hybrid(params, cfg, x, caches, positions,
+                                          lengths, seed, spec=False)
+        return _head(params, cfg, x[:, 0]), new_caches
 
     if cfg.prelude:
         prelude_caches, caches = caches["prelude"], caches["groups"]
@@ -569,7 +759,7 @@ def paged_decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                                    jnp.uint32(seed) + jnp.uint32(7919 * (i + 1)))
             new_prelude.append(c)
 
-    n_elems = len(cfg.pattern) + (1 if shared is not None else 0)
+    n_elems = len(cfg.pattern)
     carried, scanned = [], []
     for pos in range(n_elems):
         ca, sc = PG.split_paged(caches[pos])
@@ -590,16 +780,6 @@ def paged_decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             ca, sc = PG.split_paged(c)
             new_kv.append(ca)
             new_states.append(sc)
-        if shared is not None:
-            h = L.apply_norm(shared["norm"], x, cfg.norm_kind, cfg.norm_eps)
-            y, c = ATT.attention_decode(
-                shared["attn"], h, PG.with_group(kv[-1], gidx, lengths), cfg,
-                positions[:, None], seed_g + jnp.uint32(99))
-            x = x + y
-            h = L.apply_norm(shared["ffn_norm"], x, cfg.norm_kind, cfg.norm_eps)
-            x = x + L.apply_ffn(shared["ffn"], h, cfg.ffn_kind)
-            new_kv.append(c)
-            new_states.append(None)
         return (x, tuple(new_kv)), tuple(new_states)
 
     if cfg.scan_layers:
@@ -621,9 +801,7 @@ def paged_decode_step(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                        for pos in range(n_elems))
     if cfg.prelude:
         new_caches = {"prelude": tuple(new_prelude), "groups": new_caches}
-    x = L.apply_norm(params["final_norm"], x[:, 0], cfg.norm_kind, cfg.norm_eps)
-    logits = x @ _lm_head(params, cfg)
-    return logits, new_caches
+    return _head(params, cfg, x[:, 0]), new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -656,7 +834,8 @@ def _state_snapshot(cache: Any) -> Any:
 
 
 def _element_spec_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
-                         positions, seed) -> Tuple[jnp.ndarray, Any, Any]:
+                         positions, seed, extra=None
+                         ) -> Tuple[jnp.ndarray, Any, Any]:
     """Multi-position twin of :func:`_element_decode`.
 
     ``x`` is (B, n, d) -- the current token plus the drafted ones --
@@ -671,7 +850,8 @@ def _element_spec_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
     snapshots to (n, B, ...) leaves (None for attention elements).
     """
     n = x.shape[1]
-    h = L.apply_norm(p["norm"], x, cfg.norm_kind, cfg.norm_eps)
+    h = L.apply_norm(p["norm"], x if extra is None else x + extra,
+                     cfg.norm_kind, cfg.norm_eps)
     if kind == "attn":
         y, cache = ATT.attention_spec_decode(p["mixer"], h, cache, cfg,
                                              positions, seed)
@@ -739,7 +919,11 @@ def paged_spec_decode_step(params: Params, cfg: ModelConfig,
     positions = lengths[:, None] + jnp.arange(n, dtype=lengths.dtype)[None]
     if cfg.pos_emb == "learned":
         x = x + params["pos"][positions]
-    shared = params.get("shared")
+    if cfg.shared_attn:
+        x, new_caches, snaps = _decode_hybrid(params, cfg, x, caches,
+                                              positions, lengths, seed,
+                                              spec=True)
+        return _head(params, cfg, x), new_caches, snaps
 
     if cfg.prelude:
         prelude_caches, caches = caches["prelude"], caches["groups"]
@@ -752,7 +936,7 @@ def paged_spec_decode_step(params: Params, cfg: ModelConfig,
             new_prelude.append(c)
             prelude_snaps.append(sn)
 
-    n_elems = len(cfg.pattern) + (1 if shared is not None else 0)
+    n_elems = len(cfg.pattern)
     carried, scanned = [], []
     for pos in range(n_elems):
         ca, sc = PG.split_paged(caches[pos])
@@ -775,17 +959,6 @@ def paged_spec_decode_step(params: Params, cfg: ModelConfig,
             new_kv.append(ca)
             new_states.append(sc)
             gsnaps.append(sn)
-        if shared is not None:
-            h = L.apply_norm(shared["norm"], x, cfg.norm_kind, cfg.norm_eps)
-            y, c = ATT.attention_spec_decode(
-                shared["attn"], h, PG.with_group(kv[-1], gidx, lengths), cfg,
-                positions, seed_g + jnp.uint32(99))
-            x = x + y
-            h = L.apply_norm(shared["ffn_norm"], x, cfg.norm_kind, cfg.norm_eps)
-            x = x + L.apply_ffn(shared["ffn"], h, cfg.ffn_kind)
-            new_kv.append(c)
-            new_states.append(None)
-            gsnaps.append(None)
         return (x, tuple(new_kv)), (tuple(new_states), tuple(gsnaps))
 
     if cfg.scan_layers:
@@ -813,6 +986,4 @@ def paged_spec_decode_step(params: Params, cfg: ModelConfig,
     if cfg.prelude:
         new_caches = {"prelude": tuple(new_prelude), "groups": new_caches}
         snaps = {"prelude": tuple(prelude_snaps), "groups": snaps}
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
-    logits = x @ _lm_head(params, cfg)
-    return logits, new_caches, snaps
+    return _head(params, cfg, x), new_caches, snaps

@@ -94,7 +94,7 @@ def decode_op_plans(cfg, batch: int, seq_len: int,
 
     # -- attention decode + the token append that feeds it -------------
     from repro.ops.attention import plan_attn_decode_dims
-    n_attn = layer_count("attn") + (cfg.n_groups if cfg.shared_attn else 0)
+    n_attn = layer_count("attn") + cfg.n_shared_apps
     if n_attn:
         dims = dict(B=batch, T=seq_len, KVH=cfg.n_kv_heads,
                     dk=cfg.head_dim, dv=cfg.head_dim, n=1,

@@ -19,8 +19,9 @@ a gathered dense cache tree:
 ``kv_append`` (pallas mx8 / jnp every format)
     Quantizes the new token's K/V rows with the *same* bits as the dense
     op (identical shapes and seed -> identical stochastic rounding) and
-    writes them into their page slot in place -- ``input_output_aliases``
-    on the pallas path, a one-slot ``.at[].set`` scatter on jnp.
+    writes them into their page slot (a column: the pools keep a page's
+    tokens on the lanes) in place -- ``input_output_aliases`` on the pallas
+    path, a one-slot ``.at[].set`` scatter on jnp.
 
 ``state_update`` (pallas mx8 / jnp every format)
     State slabs are per-request already, so the paged op reads exactly the
@@ -53,25 +54,26 @@ from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
 from repro.ops.platform import interpret_pallas
 
 
-def _gather_stream(pool, bt: jnp.ndarray, group) -> Any:
-    """Pool (P, G, 128, KVH, w) -> dense (B, npg*128, KVH, w) for one group."""
+def _gather_stream(pool, bt: jnp.ndarray, group, heads: int) -> Any:
+    """Pool (P, G, KVH*w, 128) -> dense (B, npg*128, KVH, w) for one group."""
     def one(arr):
-        g = arr[bt, jnp.asarray(group, jnp.int32)]     # (B, npg, 128, KVH, w)
-        B, npg = g.shape[:2]
-        return g.reshape((B, npg * PAGE_TOKENS) + g.shape[3:])
+        g = arr[bt, jnp.asarray(group, jnp.int32)]     # (B, npg, R, 128)
+        B, npg, R = g.shape[:3]
+        g = jnp.swapaxes(g, 2, 3)                      # (B, npg, 128, R)
+        return g.reshape(B, npg * PAGE_TOKENS, heads, R // heads)
     if isinstance(pool, F.QuantizedTensor):
         payload = {f: one(a) for f, a in pool.payload.items()}
         B, T = payload["mantissa"].shape[:2]
-        shape = (B, T) + pool.payload["mantissa"].shape[3:]
+        shape = (B, T) + payload["mantissa"].shape[2:]
         return F.QuantizedTensor(pool.fmt, shape, payload)
     return one(pool)
 
 
 def _dense_view(cache: PagedKVCache) -> AC.KVCache:
     """Materialize the block table's dense KVCache (jnp reference path)."""
-    k = _gather_stream(cache.k, cache.bt, cache.group)
+    k = _gather_stream(cache.k, cache.bt, cache.group, cache.heads)
     v = (None if cache.v is None
-         else _gather_stream(cache.v, cache.bt, cache.group))
+         else _gather_stream(cache.v, cache.bt, cache.group, cache.heads))
     return AC.KVCache(k, v, cache.lengths, cache.fmt, cache.v_width)
 
 
@@ -197,7 +199,8 @@ class PagedKVAppendJnp(_PagedKVAppendBase):
         phys = bt[jnp.arange(B), lengths // PAGE_TOKENS]
         off = lengths % PAGE_TOKENS
         grp = jnp.asarray(group, jnp.int32)
-        return tuple(p.at[phys, grp, off].set(r.astype(p.dtype))
+        return tuple(p.at[phys, grp, :, off].set(
+                         r.reshape(B, -1).astype(p.dtype))
                      for p, r in zip(pools, rows))
 
     def execute(self, cache: PagedKVCache, inputs: Dict[str, Any],

@@ -759,12 +759,14 @@ class PagedServingEngine(_EngineCore):
         part (admit, headroom, prefetch, then the decode step's prepare,
         dispatch, sync, account and commit) and, as args, the step number,
         the rows decoded, how many of them fed a prompt token
-        (``tail_rows``), the tokens prefilled, and whether anything
-        compiled."""
+        (``tail_rows``), the tokens prefilled, the K/V positions one
+        attention layer read (``kv_tokens``) and the K/V pages in use
+        (``kv_pages``), and whether anything compiled."""
         span = self.obs.span
         with span("serve.step", cat="step") as st:
             c0 = self.obs.recompiles.n_events
             self._step_prefilled = rows = tail_rows = 0
+            self._step_kv = (0, 0)
             if self.faults is not None:
                 self.faults.set_step(self.step_count)
             with span("serve.admit"):
@@ -795,6 +797,7 @@ class PagedServingEngine(_EngineCore):
                            "not fit the page budget)")
             st.set(step=self.step_count, rows=rows, tail_rows=tail_rows,
                    prefill_tokens=self._step_prefilled,
+                   kv_tokens=self._step_kv[0], kv_pages=self._step_kv[1],
                    compiled=self.obs.recompiles.n_events > c0)
         return self.has_work()
 
@@ -1342,20 +1345,31 @@ class PagedServingEngine(_EngineCore):
         row attended (``length + n`` positions), pool occupancy and
         fragmentation.  Copy-on-write shared pages are deduplicated across
         rows -- a physical page streamed for several forks of one prefix is
-        attributed once."""
+        attributed once.  It also sets the step's ``kv_tokens`` (positions
+        one attention layer reads, a shared page counted once at the most
+        any row reads of it) and ``kv_pages`` (pages in use); both are 0 for
+        a model without K/V."""
         with self.obs.span("serve.account"):
             seen_pages = set()
             units = []
             rids = []
+            read = {}                   # page id -> tokens read from it
             for row, rid in enumerate(self.rows):
                 if rid is None:
                     continue
                 rids.append(rid)
                 table = self.pool.page_table[rid]
-                npg = min(pages_for(int(lengths[row]) + n), len(table))
+                total = int(lengths[row]) + n
+                npg = min(pages_for(total), len(table))
                 fresh = [p for p in table[:npg] if p not in seen_pages]
                 seen_pages.update(fresh)
                 units.append(max(len(fresh), 1))
+                for j, p in enumerate(table[:npg]):
+                    read[p] = max(read.get(p, 0),
+                                  min(PAGE_TOKENS, total - j * PAGE_TOKENS))
+            if self.pool.page_nbytes > 0:
+                self._step_kv = (sum(read.values()),
+                                 self.pool.usable_pages - self.pool.free_pages)
             self._traffic.account_units(units)
             self._occ.append(self.pool.occupancy())
             self._frag.append(self.pool.fragmentation(

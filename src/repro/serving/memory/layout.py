@@ -18,6 +18,14 @@ building abstract cache skeletons at (B=1,T=128), (B=2,T=128) and
 axis, the one that moves with T is the time axis.  Group-stacked leaves
 ((G, B, T, ...) from scan-over-layers) fall out of the same probe.
 
+A page pool stores its tokens on the last axis: a page whose content is
+``(*lead, 128, *rest)`` is kept as ``(*lead, prod(rest), 128)``
+(:attr:`LeafSpec.stored_shape`).  The last two axes are then lane-dense
+whatever the head width, the TPU keeps the pool in the row-major layout the
+paged kernels read, and no decode step copies a pool into another layout.
+The host-side page blobs (spill, prefix-store demotion) keep the logical
+``(n, 128, *lead, *rest)`` order.
+
 All gather/scatter functions are pure jnp and run inside jit.
 """
 from __future__ import annotations
@@ -51,6 +59,14 @@ class LeafSpec:
         s = list(self.shape)
         s.pop(self.batch_axis)
         return tuple(s)
+
+    @property
+    def stored_shape(self) -> Tuple[int, ...]:
+        """One page or slab as its pool stores it: a page's tokens last."""
+        if self.kind == "slab":
+            return self.content_shape
+        c, ct = self.content_shape, self.content_time_axis
+        return c[:ct] + (int(np.prod(c[ct + 1:])), c[ct])
 
     @property
     def content_time_axis(self) -> int:
@@ -142,7 +158,7 @@ class CachePaging:
         for spec in self.specs:
             leaf = next(it)
             if spec.kind == "page":
-                pools.append(jnp.zeros((n_pages,) + spec.content_shape,
+                pools.append(jnp.zeros((n_pages,) + spec.stored_shape,
                                        spec.dtype))
             else:
                 content = jnp.squeeze(jnp.asarray(leaf), axis=spec.batch_axis)
@@ -187,10 +203,25 @@ class CachePaging:
     # ------------------------------------------------------------------
 
     @staticmethod
-    def _gather_page_leaf(pool, bt, spec: LeafSpec):
-        """pool (P, *content), bt (B, npg) -> dense leaf (.., B, T, ..)."""
+    def _store(pages, spec: LeafSpec):
+        """Pages ``(..., *lead, 128, *rest)`` -> stored ``(..., *lead, R,
+        128)``."""
+        n_rest = len(spec.content_shape) - spec.content_time_axis - 1
+        flat = pages.reshape(pages.shape[:pages.ndim - n_rest] + (-1,))
+        return jnp.swapaxes(flat, -1, -2)
+
+    @staticmethod
+    def _unstore(stored, spec: LeafSpec):
+        """Inverse of :meth:`_store`."""
+        rest = spec.content_shape[spec.content_time_axis + 1:]
+        x = jnp.swapaxes(stored, -1, -2)
+        return x.reshape(x.shape[:-1] + rest)
+
+    @classmethod
+    def _gather_page_leaf(cls, pool, bt, spec: LeafSpec):
+        """pool (P, *stored), bt (B, npg) -> dense leaf (.., B, T, ..)."""
         ct = spec.content_time_axis
-        g = pool[bt]                                   # (B, npg, *content)
+        g = cls._unstore(pool[bt], spec)               # (B, npg, *content)
         g = jnp.moveaxis(g, 1, 1 + ct)                 # (B, c.., npg, 128, ..)
         shape = (g.shape[:1 + ct]
                  + (g.shape[1 + ct] * g.shape[2 + ct],)
@@ -210,10 +241,10 @@ class CachePaging:
         phys = bt[jnp.arange(B), pos // PAGE_TOKENS]
         off = pos % PAGE_TOKENS
         d = jnp.moveaxis(dense, (spec.batch_axis, spec.time_axis), (0, 1))
-        vals = d[jnp.arange(B), pos]                   # (B, *rest)
-        pm = jnp.moveaxis(pool, 1 + ct, 1)             # (P, 128, *rest)
-        pm = pm.at[phys, off].set(vals)
-        return jnp.moveaxis(pm, 1, 1 + ct)
+        vals = d[jnp.arange(B), pos]                   # (B, *lead, *rest)
+        vals = vals.reshape(vals.shape[:1 + ct] + (-1,))
+        # pool (P, *lead, R, 128): the slot is a column of the page
+        return pool.at[(phys,) + (slice(None),) * (ct + 1) + (off,)].set(vals)
 
     @staticmethod
     def _scatter_slab_leaf(pool, dense, slabs, spec: LeafSpec):
@@ -227,18 +258,18 @@ class CachePaging:
         npg = d.shape[0] // PAGE_TOKENS
         return d.reshape((npg, PAGE_TOKENS) + d.shape[1:])
 
-    @staticmethod
-    def _insert_pages_leaf(pool, pages_vals, page_ids, spec: LeafSpec):
+    @classmethod
+    def _insert_pages_leaf(cls, pool, pages_vals, page_ids, spec: LeafSpec):
+        """Pages (npg, 128, *lead, *rest) into their stored slots."""
         ct = spec.content_time_axis
-        pm = jnp.moveaxis(pool, 1 + ct, 1)             # (P, 128, *rest)
-        pm = pm.at[page_ids].set(pages_vals)
-        return jnp.moveaxis(pm, 1, 1 + ct)
+        vals = cls._store(jnp.moveaxis(pages_vals, 1, 1 + ct), spec)
+        return pool.at[page_ids].set(vals.astype(pool.dtype))
 
-    @staticmethod
-    def _extract_pages_leaf(pool, page_ids, spec: LeafSpec):
+    @classmethod
+    def _extract_pages_leaf(cls, pool, page_ids, spec: LeafSpec):
         ct = spec.content_time_axis
-        pm = jnp.moveaxis(pool, 1 + ct, 1)
-        return pm[page_ids]                            # (npg, 128, *rest)
+        pages = cls._unstore(pool[page_ids], spec)     # (npg, *content)
+        return jnp.moveaxis(pages, 1 + ct, 1)          # (npg, 128, ...)
 
     # ------------------------------------------------------------------
     # tree-level operations
@@ -436,7 +467,8 @@ class CachePaging:
     #
     # paged_view / commit replace gather / scatter_step in the decode loop:
     # KV pools become PagedKVCache views (zero-copy -- the group-axis
-    # normalization is a reshape) that the layout="paged" SPU ops walk via
+    # normalization is a reshape, and the pools are stored as the kernels
+    # read them) that the layout="paged" SPU ops walk via
     # the block table; recurrent "S" leaves become PagedState slab views the
     # paged state_update op updates in place; only the small residual slab
     # leaves (conv tails, sLSTM carries) are gathered/scattered as B rows --
@@ -453,22 +485,27 @@ class CachePaging:
         return pool.reshape((pool.shape[0], g) + pool.shape[1 + n_lead:]), lead
 
     def _view_stream(self, t, take):
-        """Template KV/state stream -> pool-backed stream + lead shape."""
+        """Template KV/state stream -> (pool-backed stream, lead shape,
+        logical rest shape).  A page stream's logical shape is (P, G, 128,
+        *rest); a slab stream's is its pool's."""
         if t is None:
-            return None, ()
-        if isinstance(t, F.QuantizedTensor):
-            payload, lead = {}, ()
-            for f in sorted(t.payload):
-                pool, spec = take()
-                n_lead = (spec.content_time_axis if spec.kind == "page"
-                          else len(spec.content_shape) - 3)
-                payload[f], lead = self._norm_groups(pool, n_lead)
-            return F.QuantizedTensor(t.fmt, tuple(payload["mantissa"].shape),
-                                     payload), lead
-        pool, spec = take()
-        n_lead = (spec.content_time_axis if spec.kind == "page"
-                  else len(spec.content_shape) - 3)
-        return self._norm_groups(pool, n_lead)
+            return None, (), ()
+        quantized = isinstance(t, F.QuantizedTensor)
+        payload, specs = {}, {}
+        for f in (sorted(t.payload) if quantized else [None]):
+            pool, specs[f] = take()
+            n_lead = (specs[f].content_time_axis if specs[f].kind == "page"
+                      else len(specs[f].content_shape) - 3)
+            payload[f], lead = self._norm_groups(pool, n_lead)
+        main = "mantissa" if quantized else None
+        spec, arr = specs[main], payload[main]
+        rest = (spec.content_shape[spec.content_time_axis + 1:]
+                if spec.kind == "page" else ())
+        if not quantized:
+            return arr, lead, rest
+        shape = (arr.shape[:2] + (PAGE_TOKENS,) + rest
+                 if spec.kind == "page" else arr.shape)
+        return F.QuantizedTensor(t.fmt, shape, payload), lead, rest
 
     def paged_view(self, pools: Sequence[jnp.ndarray], bt: jnp.ndarray,
                    slabs: jnp.ndarray, lengths: jnp.ndarray):
@@ -483,15 +520,16 @@ class CachePaging:
             if t is None:
                 return None
             if isinstance(t, AC.KVCache):
-                k, lead = self._view_stream(t.k, take)
-                v, _ = self._view_stream(t.v, take)
+                k, lead, rest = self._view_stream(t.k, take)
+                v, _, _ = self._view_stream(t.v, take)
                 return PG.PagedKVCache(k, v, bt, lengths, group0,
-                                       t.fmt, t.v_width, tuple(lead))
+                                       t.fmt, t.v_width, tuple(lead),
+                                       rest[0] if len(rest) > 1 else 1)
             if isinstance(t, dict):
                 out = {}
                 for key in sorted(t):
                     if key == "S":
-                        s, lead = self._view_stream(t[key], take)
+                        s, lead, _ = self._view_stream(t[key], take)
                         fmt = (t[key].fmt
                                if isinstance(t[key], F.QuantizedTensor)
                                else fmt_of_state(t[key]))
@@ -521,7 +559,7 @@ class CachePaging:
                   if isinstance(stream, F.QuantizedTensor) else [stream])
         for arr in arrays:
             _, spec = take()
-            out.append(arr.reshape((arr.shape[0],) + spec.content_shape))
+            out.append(arr.reshape((arr.shape[0],) + spec.stored_shape))
         return out
 
     def commit(self, pools: Sequence[jnp.ndarray], new_caches,

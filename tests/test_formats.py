@@ -117,3 +117,18 @@ def test_strict_mx_arith_close_to_fused():
     fused = F.dequantize(F.mx8_quantize(a + b))
     denom = jnp.maximum(jnp.abs(a + b), 1e-3)
     assert float(jnp.median(jnp.abs(strict - fused) / denom)) < 0.05
+
+
+@pytest.mark.parametrize("heads,width", [(2, 16), (32, 160)])
+def test_mx8_dequantize_rows_matches_transposed_payload(heads, width):
+    """Rows-down dequantization (the paged K/V pools' layout: every head's
+    values of a token in one column) gives the values of the lane-wise
+    dequantization, bit for bit."""
+    x = jax.random.normal(jax.random.PRNGKey(heads), (128, heads, width))
+    qt = F.quantize(x * 10.0 ** jnp.arange(-2, 2, 4 / heads)[:, None],
+                    "mx8")
+    cols = {f: a.reshape(128, -1).T for f, a in qt.payload.items()}
+    got = F.mx8_dequantize_rows(cols["mantissa"], cols["exponent"],
+                                cols["micro"])
+    want = F.dequantize(qt).reshape(128, -1).T
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -537,3 +537,25 @@ def test_spans_reach_the_profiler_trace(tiny_fp32, tmp_path):
     eng.step()
     rep = eng.engine.bank_report()
     assert rep["t_real_s"] > 0 and "imbalance" in rep
+
+
+@pytest.mark.parametrize("arch", ["zamba2-2.7b", "mamba2-2.7b"])
+def test_step_spans_count_kv_tokens_and_pages(arch):
+    """``serve.step`` carries the K/V positions one attention layer read
+    (each row's length with the appended token) and the pages in use; a
+    model without K/V reads 0 for both."""
+    cfg = get_smoke_config(arch).with_(
+        state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
+                                     backend="jnp"))
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    eng = Engine(params, cfg, ServeConfig(backend="paged", batch=2,
+                                          n_pages=9, n_slabs=5))
+    eng.submit(_prompts(cfg, [20])[0], max_new_tokens=4)
+    eng.run()
+    steps = [e["args"] for e in eng.obs.tracer.events()
+             if e["ph"] == "X" and e["name"] == "serve.step"
+             and e["args"]["rows"]]
+    kv = arch == "zamba2-2.7b"
+    assert [s["kv_tokens"] for s in steps] == \
+        ([21, 22, 23] if kv else [0, 0, 0])
+    assert [s["kv_pages"] for s in steps] == [int(kv)] * 3

@@ -7,10 +7,15 @@ the 8 x 128 tiling, unsupported casts or reshapes, fast-memory overflow)
 would fail the same way on the chip.  Nothing runs, so nothing about
 results or times is checked.
 
-Widths: zamba2-2.7b (80 Mamba-2 heads with dk = dv = 64; shared attention
-with 32 KV heads of width 80, stacked over 9 layer groups) at decode batch
-8, and mamba2-2.7b's state update (dk = 128) at 8 and at 20 rows.
+Widths: zamba2-2.7b's state update (80 Mamba-2 heads with dk = dv = 64)
+and paged attention at its published 32 KV heads of 160 stacked over its 9
+shared-block applications, and its whole paged decode step at the chat
+cell's 20 rows; mamba2-2.7b's state update (dk = 128) at 8 and at 20 rows.
+The attention kernels also compile at 32 heads of 80, a width whose
+exponent rows pad to more lanes.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,7 +30,8 @@ from repro.kernels.mx_spec_attention import (mx_paged_spec_attention_decode,
 from repro.kernels.mx_state_update import mx_state_update
 
 B = 8                      # decode batch
-KVH, D, GROUPS = 32, 80, 9  # zamba2-2.7b shared attention
+KVH, D, GROUPS = 32, 80, 9  # 32 KV heads of 80 over 9 stacked layers
+ZAMBA2_D = 160              # zamba2-2.7b's published head width
 PAGES, NPG, KQ = 16, 3, 4   # pool pages, block-table width, verify queries
 
 
@@ -61,6 +67,17 @@ def _mx8(shape, sharding):
         "micro": _sds(groups, jnp.uint8, sharding)})
 
 
+def _mx8_pool(pages, groups, heads, width, sharding):
+    """An MX8 page pool as the serving pool stores it: logical shape
+    (pages, groups, 128, heads, width), tokens on the last axis."""
+    def stored(w, dtype):
+        return _sds((pages, groups, heads * w, 128), dtype, sharding)
+    return F.QuantizedTensor("mx8", (pages, groups, 128, heads, width), {
+        "mantissa": stored(width, jnp.int8),
+        "exponent": stored(width // F.MX8_GROUP, jnp.uint8),
+        "micro": stored(width // F.MX8_GROUP, jnp.uint8)})
+
+
 def _compile(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
@@ -87,17 +104,76 @@ def test_state_update_compiles(one_chip, arch, heads, dk, dv, batch):
 
 def test_paged_attn_decode_compiles(one_chip):
     i32 = lambda *s: _sds(s, jnp.int32, one_chip)
-    pool = lambda: _mx8((PAGES, GROUPS, 128, KVH, D), one_chip)
+    pool = lambda: _mx8_pool(PAGES, GROUPS, KVH, D, one_chip)
     _compile(lambda q, k, v, bt, g, n: mx_paged_attention_decode(
                  q, k, v, bt, g, n, interpret=False),
              _sds((B, 32, D), jnp.float32, one_chip), pool(), pool(),
              i32(B, NPG), i32(), i32(B))
 
 
+def test_paged_attn_decode_compiles_at_zamba2_width(one_chip):
+    """32 KV heads of 160: every head's rows of a page, dequantized in
+    VMEM at once (5,120 rows of 128 tokens for K and for V)."""
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)
+    pool = lambda: _mx8_pool(PAGES, GROUPS, KVH, ZAMBA2_D, one_chip)
+    _compile(lambda q, k, v, bt, g, n: mx_paged_attention_decode(
+                 q, k, v, bt, g, n, scale=(ZAMBA2_D / 2) ** -0.5,
+                 interpret=False),
+             _sds((20, 32, ZAMBA2_D), jnp.float32, one_chip), pool(), pool(),
+             i32(20, 8), i32(), i32(20))
+
+
+def test_zamba2_paged_decode_step_compiles(one_chip, monkeypatch):
+    """The whole paged decode step of zamba2-2.7b at its published widths
+    (2.66 B parameters, 54 layers, 9 shared-block applications) over the
+    chat cell's pool: 20 rows, 141 pages, 21 slabs, 8-page block tables.
+    It fits v5e's 15.75 GB, and no K/V page pool is copied into another
+    layout around the kernels.  Shapes only: nothing is allocated."""
+    from repro.configs import get_config
+    from repro.core.paged import PAGE_TOKENS
+    from repro.models import model as M
+    from repro.ops import attention, paged_ops, spec_verify, state_update
+    from repro.ops.base import StateQuantConfig
+    from repro.serving.memory.layout import CachePaging
+    for mod in (attention, paged_ops, spec_verify, state_update):
+        monkeypatch.setattr(mod, "interpret_pallas", lambda: False)
+    cfg = get_config("zamba2-2.7b").with_(state_quant=StateQuantConfig(
+        fmt="mx8", rounding="stochastic", backend="pallas"))
+    rows, n_pages, n_slabs = 20, 141, 21
+    paging = CachePaging(M.init_decode_caches(cfg, 1, PAGE_TOKENS),
+                         M.abstract_decode_caches(cfg, 2, PAGE_TOKENS),
+                         M.abstract_decode_caches(cfg, 1, 2 * PAGE_TOKENS))
+    pools = [_sds(((n_pages if s.kind == "page" else n_slabs),)
+                  + s.stored_shape, s.dtype, one_chip)
+             for s in paging.specs]
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg)))
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)
+
+    def step(params, pools, bt, slabs, lengths, tokens, seed):
+        views = paging.paged_view(pools, bt, slabs, lengths)
+        logits, new = M.paged_decode_step(params, cfg=cfg, tokens=tokens,
+                                          caches=views, lengths=lengths,
+                                          seed=seed)
+        return logits, paging.commit(pools, new, slabs)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, i32(rows, 8), i32(rows), i32(rows), i32(rows),
+        i32()).compile()
+    text = compiled.as_text()
+    for kind in ("state_update", "attn_decode", "kv_append"):
+        assert f"spu_{kind}" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    page_copies = re.findall(rf"= \w+\[{n_pages},\S* copy\(", text)
+    assert not page_copies, page_copies
+
+
 def test_paged_kv_append_compiles(one_chip):
     i32 = lambda *s: _sds(s, jnp.int32, one_chip)
     widths = ((D, jnp.int8), (D // 16, jnp.uint8), (D // 16, jnp.uint8))
-    pools = [_sds((PAGES, GROUPS, 128, KVH, w), dt, one_chip)
+    pools = [_sds((PAGES, GROUPS, KVH * w, 128), dt, one_chip)
              for w, dt in widths]
     rows = [_sds((B, KVH, w), dt, one_chip) for w, dt in widths]
     _compile(lambda p, r, bt, g, n: mx_paged_kv_append(
@@ -115,7 +191,7 @@ def test_spec_verify_compiles(one_chip):
 
 def test_paged_spec_verify_compiles(one_chip):
     i32 = lambda *s: _sds(s, jnp.int32, one_chip)
-    pool = lambda: _mx8((PAGES, GROUPS, 128, KVH, D), one_chip)
+    pool = lambda: _mx8_pool(PAGES, GROUPS, KVH, D, one_chip)
     _compile(lambda q, k, v, bt, g, n: mx_paged_spec_attention_decode(
                  q, k, v, bt, g, n, interpret=False),
              _sds((B, KQ, 32, D), jnp.float32, one_chip), pool(), pool(),
