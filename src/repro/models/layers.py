@@ -27,6 +27,15 @@ def embed_init(key, vocab: int, d: int, dtype) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# padding
+# ---------------------------------------------------------------------------
+
+def valid_mask(lengths: jnp.ndarray, S: int) -> jnp.ndarray:
+    """(B, S) True at each row's first ``lengths[b]`` positions."""
+    return jnp.arange(S)[None] < lengths[:, None]
+
+
+# ---------------------------------------------------------------------------
 # norms
 # ---------------------------------------------------------------------------
 

@@ -166,9 +166,11 @@ def _lm_head(params: Params, cfg: ModelConfig) -> jnp.ndarray:
 
 def _element_forward(p: Params, x, cfg: ModelConfig, kind: str,
                      positions, prefix_len: int, want_cache: bool,
-                     mesh_axes, extra=None) -> Tuple[jnp.ndarray, Any]:
+                     mesh_axes, extra=None, lengths=None
+                     ) -> Tuple[jnp.ndarray, Any]:
     """One layer; ``extra`` (a shared block's output) is added to its input
-    before the norm and not to the residual stream."""
+    before the norm and not to the residual stream.  ``lengths`` (B,) marks
+    each row's positions from there on as padding (see :func:`prefill`)."""
     h = L.apply_norm(p["norm"], x if extra is None else x + extra,
                      cfg.norm_kind, cfg.norm_eps)
     cache = None
@@ -177,7 +179,7 @@ def _element_forward(p: Params, x, cfg: ModelConfig, kind: str,
                                   prefix_len=prefix_len)
         if want_cache:
             kv = ATT.attention_prefill_kv(p["mixer"], h, cfg, positions)
-            cache = _build_kv_cache(kv[0], kv[1], cfg)
+            cache = _build_kv_cache(kv[0], kv[1], cfg, lengths=lengths)
     elif kind == "mla":
         y = ATT.mla_forward(p["mixer"], h, cfg, positions)
         if want_cache:
@@ -185,7 +187,8 @@ def _element_forward(p: Params, x, cfg: ModelConfig, kind: str,
             cache = _build_kv_cache(ckv[:, :, None, :], None, cfg,
                                     v_width=cfg.mla.kv_lora)
     elif kind == "mamba2":
-        y, st = SSM.mamba2_forward(p["mixer"], h, cfg, par=mesh_axes)
+        y, st = SSM.mamba2_forward(p["mixer"], h, cfg, par=mesh_axes,
+                                   lengths=lengths)
         cache = st if want_cache else None
     elif kind in ("gla", "retnet", "hgrn2"):
         y, st = SSM.gla_family_forward(p["mixer"], h, cfg, kind, par=mesh_axes)
@@ -212,10 +215,17 @@ def _element_forward(p: Params, x, cfg: ModelConfig, kind: str,
 
 
 def _build_kv_cache(k: jnp.ndarray, v: Optional[jnp.ndarray],
-                    cfg: ModelConfig, v_width: Optional[int] = None
-                    ) -> AC.KVCache:
-    """Quantize full-sequence K/V into a cache with tile-aligned capacity."""
+                    cfg: ModelConfig, v_width: Optional[int] = None,
+                    lengths=None) -> AC.KVCache:
+    """Quantize full-sequence K/V into a cache with tile-aligned capacity;
+    with ``lengths`` (B,), the rows from each row's length on are padding:
+    zeroed, and the cache holds ``lengths`` positions."""
     B, S = k.shape[:2]
+    if lengths is not None:
+        real = L.valid_mask(lengths, S)
+        k = jnp.where(real.reshape((B, S) + (1,) * (k.ndim - 2)), k, 0)
+        if v is not None:
+            v = jnp.where(real.reshape((B, S) + (1,) * (v.ndim - 2)), v, 0)
     cap = -(-S // 128) * 128
     pad = cap - S
     if pad:
@@ -223,7 +233,8 @@ def _build_kv_cache(k: jnp.ndarray, v: Optional[jnp.ndarray],
         if v is not None:
             v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
     sq = cfg.state_quant
-    lengths = jnp.full((B,), S, jnp.int32)
+    lengths = (jnp.full((B,), S, jnp.int32) if lengths is None
+               else lengths.astype(jnp.int32))
     if sq.quantized:
         qk = F.quantize(k, sq.fmt)
         qv = None if v is None else F.quantize(v, sq.fmt)
@@ -303,7 +314,8 @@ def _scan_segment(body, carry, lo: int, hi: int, cfg: ModelConfig):
 
 
 def _run_hybrid(params: Params, x, cfg: ModelConfig, positions,
-                prefix_len: int, want_cache: bool, mesh_axes, ckpt):
+                prefix_len: int, want_cache: bool, mesh_axes, ckpt,
+                lengths=None):
     """Full-sequence forward of the hybrid schedule; caches as
     :func:`init_decode_caches` lays them out (layer stack, then the K/V of
     every application stacked)."""
@@ -316,7 +328,7 @@ def _run_hybrid(params: Params, x, cfg: ModelConfig, positions,
             x = _seq_shard(x, mesh_axes)
         fn = ckpt(lambda p, xx, tt: _element_forward(
             p[0], xx, cfg, kind, positions, prefix_len, want_cache,
-            mesh_axes, extra=tt))
+            mesh_axes, extra=tt, lengths=lengths))
         x, c = fn(_take(params["groups"], i), x, t)
         return (x, jnp.zeros_like(t)), (c,)
 
@@ -328,7 +340,7 @@ def _run_hybrid(params: Params, x, cfg: ModelConfig, positions,
         cache = None
         if want_cache:
             k, v = ATT.attention_prefill_kv(p["attn"], h, cfg, positions)
-            cache = _build_kv_cache(k, v, cfg)
+            cache = _build_kv_cache(k, v, cfg, lengths=lengths)
         return _shared_out(p, a, cfg, y), cache
 
     ys, kv = [], []
@@ -433,14 +445,15 @@ def _seq_shard(x: jnp.ndarray, par) -> jnp.ndarray:
 
 def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 positions, prefix_len: int, want_cache: bool,
-                mesh_axes) -> Tuple[jnp.ndarray, Any]:
+                mesh_axes, lengths=None) -> Tuple[jnp.ndarray, Any]:
     if cfg.seq_parallel:
         x = _seq_shard(x, mesh_axes)
 
     prelude_caches = []
     for i, kind in enumerate(cfg.prelude):
         x, c = _element_forward(params["prelude"][i], x, cfg, kind, positions,
-                                prefix_len, want_cache, mesh_axes)
+                                prefix_len, want_cache, mesh_axes,
+                                lengths=lengths)
         prelude_caches.append(c)
 
     def _maybe_ckpt(fn):
@@ -451,7 +464,7 @@ def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 
     if cfg.shared_attn:
         return _run_hybrid(params, x, cfg, positions, prefix_len, want_cache,
-                           mesh_axes, _maybe_ckpt)
+                           mesh_axes, _maybe_ckpt, lengths=lengths)
 
     def group_body(x, ginp):
         gparams, gidx = ginp
@@ -462,7 +475,7 @@ def _run_blocks(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             fn = _maybe_ckpt(
                 lambda p, xx, kind=kind: _element_forward(
                     p, xx, cfg, kind, positions, prefix_len, want_cache,
-                    mesh_axes))
+                    mesh_axes, lengths=lengths))
             x, c = fn(gparams[pos], x)
             caches.append(c)
         return x, tuple(caches)
@@ -551,20 +564,48 @@ def train_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
                                   unroll=cfg.cost_probe)
 
 
+#: the layer kinds whose full-sequence forward masks padded positions
+_MASKED_KINDS = frozenset({"attn", "mamba2"})
+
+
+def masked_prefill_ok(cfg: ModelConfig) -> bool:
+    """Whether :func:`prefill` with ``lengths`` gives what the unpadded
+    prefill gives: every layer masks the padding (causal attention; Mamba-2
+    with ``dt`` 0), and nothing lets a padded position reach a real one --
+    no bidirectional prefix, frontend or capacity-bound expert routing."""
+    return (set(cfg.pattern) | set(cfg.prelude) <= _MASKED_KINDS
+            and cfg.ffn_kind != "moe" and cfg.frontend is None
+            and cfg.prefix_len == 0 and not cfg.encoder_only)
+
+
 @_f32_matmuls
 def prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
-            mesh_axes=None) -> Tuple[jnp.ndarray, Any]:
-    """Full-sequence forward; returns (last-position logits, caches)."""
+            mesh_axes=None, lengths=None) -> Tuple[jnp.ndarray, Any]:
+    """Full-sequence forward; returns (last-position logits, caches).
+
+    ``lengths`` (B,), traced: row b holds ``lengths[b]`` real tokens and
+    padding after them (one program a padded length, whatever the real
+    ones).  The padding leaves the caches untouched -- the recurrent state
+    and conv windows end at the last real token, K/V rows past it are zero
+    and the K/V lengths are ``lengths`` -- and the logits are those of the
+    last real position.  Needs :func:`masked_prefill_ok`."""
+    if lengths is not None and not masked_prefill_ok(cfg):
+        raise ValueError(f"{cfg.name}: prefill cannot mask padding")
     x, positions, prefix_len = embed_inputs(params, cfg, batch)
     x, caches = _run_blocks(params, x, cfg, positions, prefix_len,
                             want_cache=not cfg.encoder_only,
-                            mesh_axes=mesh_axes)
+                            mesh_axes=mesh_axes, lengths=lengths)
     x = L.apply_norm(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
     if cfg.encoder_only:
         # encoder models: per-position classification logits
         logits = x @ _lm_head(params, cfg)
         return logits, None
-    logits = x[:, -1] @ _lm_head(params, cfg)
+    if lengths is None:
+        last = x[:, -1]
+    else:
+        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
+                                   axis=1)[:, 0]
+    logits = last @ _lm_head(params, cfg)
     return logits, caches
 
 

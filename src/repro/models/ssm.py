@@ -280,8 +280,25 @@ def _m2_project(p, x, cfg):
     return z, xin, bc[..., :N], bc[..., N:], dt_
 
 
+def _conv_window(x: jnp.ndarray, n: int, lengths=None) -> jnp.ndarray:
+    """The ``n`` rows of ``x`` (B,S,d) that end at each row's last real
+    position (``lengths - 1``, or ``S - 1``), zeros before position 0."""
+    B, S = x.shape[:2]
+    if lengths is None:
+        return x[:, -n:] if S >= n else jnp.pad(
+            x, ((0, 0), (n - S, 0), (0, 0)))
+    idx = lengths[:, None] - n + jnp.arange(n)[None]              # (B, n)
+    rows = jnp.take_along_axis(x, jnp.clip(idx, 0, S - 1)[..., None],
+                               axis=1)
+    return jnp.where((idx >= 0)[..., None], rows, 0)
+
+
 def mamba2_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
-                   par=None) -> Tuple[jnp.ndarray, MixerState]:
+                   par=None, lengths=None) -> Tuple[jnp.ndarray, MixerState]:
+    """Full-sequence Mamba-2 mixer.  With ``lengths`` (B,), each row's
+    positions from its length on are padding: ``dt`` is 0 there (decay 1,
+    no input), so the final state and the conv windows are those of the
+    last real token."""
     B, S, d = x.shape
     d_inner, H, N, P = _m2_dims(cfg)
     z, xin, Bv, Cv, dt_ = _m2_project(p, x, cfg)
@@ -291,6 +308,8 @@ def mamba2_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     Bv, Cv = bc[..., :N], bc[..., N:]
 
     dt_f = jax.nn.softplus(dt_.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
+    if lengths is not None:
+        dt_f = jnp.where(L.valid_mask(lengths, S)[..., None], dt_f, 0.0)
     a = -jnp.exp(p["A_log"])                                        # (H,)
     log_decay = (dt_f * a).transpose(0, 2, 1)                       # (B,H,S)
 
@@ -310,7 +329,8 @@ def mamba2_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     out = y @ p["out_proj"]
 
     # NOTE: conv caches hold pre-activation inputs of the last d_conv-1 steps
-    z2, xin2, Bv2, Cv2, _ = _m2_project(p, x[:, -(cfg.ssm.d_conv - 1):], cfg)
+    z2, xin2, Bv2, Cv2, _ = _m2_project(
+        p, _conv_window(x, cfg.ssm.d_conv - 1, lengths), cfg)
     state = {"S": _store_state(S_fin, cfg),
              "conv_x": xin2,
              "conv_bc": jnp.concatenate([Bv2, Cv2], -1)}

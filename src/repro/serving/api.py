@@ -72,9 +72,11 @@ class ServeConfig:
     byte_budget: Optional[int] = None  # paged: alternative to n_pages
     prefill_chunk: int = 128           # paged: longest full-seq prefill
     prefill_buckets: Optional[Tuple[int, ...]] = None
-                                       # paged: snap prefill lengths down to
-                                       # this bucket set (bounded compile
-                                       # count); tail streams through decode
+                                       # paged: prefill lengths (bounded
+                                       # compile count): a covered prompt is
+                                       # padded up to its bucket, the padding
+                                       # masked; a longer one streams its
+                                       # tail through decode
     sampling: SamplingConfig = SamplingConfig()
     scheduler: SchedulerConfig = SchedulerConfig()
     seed: int = 0

@@ -161,9 +161,11 @@ def _sample_tokens(key, logits, sampling: SamplingConfig):
 
 def _prefill_program(cfg: ModelConfig, mesh_axes):
     """The jitted full-sequence prefill, under a name of its own: the
-    profiler shows it as ``jit_prefill``."""
-    def prefill(params, batch):
-        return M.prefill(params, cfg=cfg, batch=batch, mesh_axes=mesh_axes)
+    profiler shows it as ``jit_prefill``.  ``lengths`` (B,), when given,
+    masks the padding after each row's real tokens (``M.prefill``)."""
+    def prefill(params, batch, lengths=None):
+        return M.prefill(params, cfg=cfg, batch=batch, mesh_axes=mesh_axes,
+                         lengths=lengths)
     return jax.jit(prefill)
 
 
@@ -608,13 +610,15 @@ class PagedEngineConfig:
     byte_budget: Optional[int] = None  # alternative to n_pages
     prefill_chunk: int = 128          # longest full-sequence prefill; the
                                       # prompt tail streams through decode
-    # opt-in prefill length bucketing (JH103): when set, the full-sequence
-    # prefill length snaps down to the largest bucket <= the prompt length
-    # and the remainder streams through the decode batch, so the prefill
-    # jit compiles one executable per *bucket* instead of one per distinct
-    # prompt length.  Off by default: moving tokens from prefill to decode
-    # changes which op consumes which stochastic-rounding draw, so mx8
-    # token streams differ from the unbucketed engine (both are valid).
+    # opt-in prefill length bucketing (JH103): when set, the prefill jit
+    # compiles one executable per *bucket* instead of one per distinct
+    # prompt length.  A prompt that a bucket up to prefill_chunk covers is
+    # prefilled whole at the smallest such bucket, the padding masked
+    # (where the model's layers can mask it: M.masked_prefill_ok); a longer
+    # one prefills the largest bucket that fits and streams the rest
+    # through the decode batch.  Off by default: streamed tokens take
+    # other stochastic-rounding draws than prefilled ones, so mx8 token
+    # streams differ from the unbucketed engine (both are valid).
     prefill_buckets: Optional[Tuple[int, ...]] = None
     sampling: SamplingConfig = SamplingConfig()
     scheduler: SchedulerConfig = SchedulerConfig()
@@ -702,8 +706,10 @@ class PagedServingEngine(_EngineCore):
         self._reprefills: Dict[int, int] = {}
         #: rid -> consecutive failed admission attempts (degradation rung)
         self._admit_fails: Dict[int, int] = {}
-        self._prefill = self.obs.wrap_jit(_prefill_program(cfg, mesh_axes),
-                                          "engine.prefill")
+        self._prefill_jit = self.obs.wrap_jit(
+            _prefill_program(cfg, mesh_axes), "engine.prefill")
+        #: bucketed prompts are prefilled padded, the padding masked
+        self._masked = bool(pcfg.prefill_buckets) and M.masked_prefill_ok(cfg)
         max_chunk_pages = pages_for(pcfg.prefill_chunk)
         assert max_chunk_pages <= self.pool.usable_pages, \
             "prefill_chunk does not fit the page pool"
@@ -877,7 +883,7 @@ class PagedServingEngine(_EngineCore):
             # corruption recovery re-prefills from the replay stream; the
             # prefix store is bypassed entirely
             return pages_for(
-                self._bucket_prefill_len(len(self._replay[req.rid])))
+                self._prefill_lens(len(self._replay[req.rid]))[1])
         if req.rid in self.spilled:
             if self.pool.prefetch_ready(req.rid):
                 return 0            # staged: commit is O(1) bookkeeping
@@ -891,8 +897,9 @@ class PagedServingEngine(_EngineCore):
             # prefix hit: promote any demoted nodes + one page of headroom
             # for the first streamed tail token
             return sum(1 for n in nodes if not n.resident) + 1
-        s0 = min(len(req.prompt), self.pcfg.prefill_chunk)
-        return pages_for(s0)
+        n = len(req.prompt)
+        return pages_for(max(min(n, self.pcfg.prefill_chunk),
+                             self._prefill_lens(n)[1]))
 
     def _admit(self) -> bool:
         admitted = False
@@ -1040,21 +1047,43 @@ class PagedServingEngine(_EngineCore):
         if self.kctl is not None:
             self.kctl.forget(rid)
 
-    def _bucket_prefill_len(self, n: int) -> int:
-        """Full-sequence prefill length for an ``n``-token prompt.
+    def _prefill_lens(self, n: int) -> Tuple[int, int]:
+        """(real, padded): the tokens of an ``n``-token prompt that the
+        full-sequence prefill takes, and the length it runs at.
 
-        Unbucketed: ``min(n, prefill_chunk)`` -- one compiled prefill per
-        distinct prompt length.  With ``prefill_buckets``, snap down to the
-        largest bucket that fits (prompts shorter than every bucket keep
-        their exact length); the tail streams through the decode batch via
-        the existing pending mechanism."""
+        Unbucketed: ``min(n, prefill_chunk)``, unpadded -- one compiled
+        prefill per distinct prompt length.  With ``prefill_buckets``, the
+        smallest bucket up to ``prefill_chunk`` that covers them, the
+        padding masked; where no bucket covers them, or the model cannot
+        mask padding, the largest bucket that fits, unpadded (prompts
+        shorter than every bucket keep their exact length).  The rest of
+        the prompt streams through the decode batch."""
         s0 = min(n, self.pcfg.prefill_chunk)
-        buckets = self.pcfg.prefill_buckets
-        if buckets:
-            fits = [b for b in buckets if 0 < b <= s0]
-            if fits:
-                s0 = max(fits)
-        return s0
+        buckets = self.pcfg.prefill_buckets or ()
+        if self._masked:
+            cover = [b for b in buckets if s0 <= b <= self.pcfg.prefill_chunk]
+            if cover:
+                return s0, min(cover)
+        fits = [b for b in buckets if 0 < b <= s0]
+        if fits:
+            s0 = max(fits)
+        return s0, s0
+
+    def _prefill(self, params, batch):
+        """The full-sequence prefill of ``batch["tokens"]`` (1, n), the
+        exact tokens on the host: padded here to ``_prefill_lens(n)``'s
+        length and run with ``lengths`` n when the engine masks padding.
+        Returns the logits of the last real position and caches of the
+        padded length.  The padding is numpy: a device op on the (1, n)
+        tokens would compile once per distinct prompt length."""
+        if not self._masked:
+            return self._prefill_jit(params, batch=batch)
+        tokens = np.asarray(batch["tokens"], np.int32)
+        B, n = tokens.shape
+        pad = self._prefill_lens(n)[1] - n
+        return self._prefill_jit(
+            params, batch={"tokens": np.pad(tokens, ((0, 0), (0, pad)))},
+            lengths=np.full((B,), n, np.int32))
 
     def _prefill_into(self, req: Request) -> bool:
         replay = self._replay.get(req.rid)
@@ -1072,27 +1101,31 @@ class PagedServingEngine(_EngineCore):
         src = np.asarray(replay, np.int32) if replay is not None \
             else req.prompt
         self.obs.lifecycle.phase(req.rid, "prefill")
-        s0 = self._bucket_prefill_len(len(src))
-        if not self._retry("alloc",
-                           lambda: self.pool.register(req.rid, pages_for(s0))):
+        s0, padded = self._prefill_lens(len(src))
+        if not self._retry("alloc", lambda: self.pool.register(
+                req.rid, pages_for(padded))):
             return False                # replay ctx (if any) stays for retry
         self._replay.pop(req.rid, None)
         # the whole prompt is fresh context: s0 through full-sequence
-        # prefill, the tail streamed through the decode batch.  With
-        # prefill_buckets set, s0 comes from a fixed bucket set, so the
-        # slice below feeds a bounded family of compiled shapes.
+        # prefill (run at ``padded``), the tail streamed through the decode
+        # batch.  With prefill_buckets set, ``padded`` comes from a fixed
+        # bucket set, so the prefill runs a bounded family of shapes.
         self._count_prefill(len(src))
         self._step_prefilled += s0
         a = _Active(req, length=s0, pending=list(map(int, src[s0:])),
                     cur_token=-1, replayed=replay is not None)
+        m = self.obs.metrics
+        m.counter("prefill_pad_tokens_total").inc(padded - s0)
+        m.counter("prefill_tail_tokens_total").inc(len(a.pending))
         with self.obs.span("serve.prefill", cat="prefill", rid=req.rid,
-                           tokens=s0, tail=len(a.pending),
+                           tokens=s0, padded=padded, tail=len(a.pending),
                            replay=replay is not None):
-            prompt = jnp.asarray(src[:s0], jnp.int32)[None]  # lint: disable=JH103
+            # host tokens, any length: _prefill pads them to a bucket
+            prompt = np.asarray(src[:s0], np.int32)[None]  # lint: disable=JH103
             logits, row_caches = self._prefill(
                 self.params, batch={"tokens": prompt, "targets": prompt})
             self.pool.insert_prefill(req.rid, row_caches)
-            if replay is None and s0 % PAGE_TOKENS == 0:
+            if replay is None and s0 == padded and s0 % PAGE_TOKENS == 0:
                 # the prefilled pages are full and immutable: remember them
                 # in the prefix store for future requests sharing this
                 # prompt (replay streams contain generated tokens -- never
@@ -1643,6 +1676,11 @@ class PagedServingEngine(_EngineCore):
             # release in end-of-run stats (the live value is also exposed)
             "shared_page_savings": float(self.pool.shared_savings_peak),
             "shared_page_savings_live": float(self.pool.shared_page_savings),
+            # --- bucketed prefill: padding run, prompt left to decode ---
+            "prefill_pad_tokens": self.obs.metrics.value(
+                "prefill_pad_tokens_total"),
+            "prefill_tail_tokens": self.obs.metrics.value(
+                "prefill_tail_tokens_total"),
             # --- tiered memory hierarchy ---
             "prefix_hits": float(self.pool.prefix_hits),
             "prefix_hit_pages": float(self.pool.prefix_hit_pages),

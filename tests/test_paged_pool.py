@@ -217,11 +217,10 @@ def test_paged_engine_mixed_workload_matches_greedy(tiny_fp32):
 
 
 def test_paged_engine_prefill_buckets(tiny_fp32):
-    """Opt-in prefill bucketing (the JH103 lint-finding fix): snapping the
-    full-sequence prefill length down to a fixed bucket set must not change
-    greedy outputs -- the prompt tail streams through the bit-exact decode
-    pending path -- while collapsing one-prefill-compile-per-prompt-length
-    to one per bucket."""
+    """Opt-in prefill bucketing (the JH103 lint-finding fix): prefilling
+    each prompt whole at the smallest bucket that covers it, the padding
+    masked, must not change greedy outputs, while collapsing
+    one-prefill-compile-per-prompt-length to one per bucket."""
     params, cfg = tiny_fp32
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
@@ -238,8 +237,116 @@ def test_paged_engine_prefill_buckets(tiny_fp32):
     for r in done:
         assert r.output == refs[r.rid], (r.rid, r.output, refs[r.rid])
     # six distinct prompt lengths, but only two buckets actually prefill
-    # (9-13 -> 8, 42-46 -> 32): the compile count follows the bucket set
-    assert eng.obs.recompiles.counts().get("engine.prefill", 0) <= 2
+    # (9-13 -> 32, 42-46 -> 128): the compile count follows the bucket set
+    assert eng.obs.recompiles.counts().get("engine.prefill", 0) == 2
+
+
+#: the model families of the chip cells, and attention alone
+MASKED_ARCHS = ("llama3.2-1b", "mamba2-2.7b", "zamba2-2.7b")
+
+
+@pytest.fixture(scope="module", params=MASKED_ARCHS)
+def masked_fp32(request):
+    cfg = get_smoke_config(request.param).with_(
+        state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
+                                     backend="jnp"))
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    return params, cfg
+
+
+def _bucketed_engine(params, cfg):
+    from repro.serving.api import Engine, ServeConfig
+    return Engine(params, cfg, ServeConfig(
+        backend="paged", batch=2, n_pages=9, n_slabs=5, prefill_chunk=128,
+        prefill_buckets=(16, 32, 64)))
+
+
+def _serve_steps(eng):
+    return [e for e in eng.obs.tracer.events()
+            if e["ph"] == "X" and e["name"] == "serve.step"]
+
+
+def test_paged_engine_pads_covered_prompts(masked_fp32):
+    """Every prompt a bucket covers is prefilled whole at its bucket, the
+    padding masked: the greedy tokens are the fixed-slot engine's, no
+    request ingests a tail, no decode row feeds a prompt token, the pad
+    counter reads the padding, and each bucket compiles once."""
+    params, cfg = masked_fp32
+    lengths = (5, 16, 20, 32, 50, 64)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in lengths]
+    refs = _reference_outputs(params, cfg, prompts, 4)
+
+    eng = _bucketed_engine(params, cfg)
+    hs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    eng.run()
+    for i, h in enumerate(hs):
+        assert h.status == "done"
+        assert h.request.output == refs[i], (i, h.request.output, refs[i])
+        assert "ingest" not in eng.lifecycle(h).phase_sequence()
+    assert all(e["args"]["tail_rows"] == 0 for e in _serve_steps(eng))
+    st = eng.stats()
+    assert st["prefill_pad_tokens"] == (16 - 5) + (32 - 20) + (64 - 50)
+    assert st["prefill_tail_tokens"] == 0
+    assert eng.obs.recompiles.counts()["engine.prefill"] == 3
+    spans = [e["args"] for e in eng.obs.tracer.events()
+             if e["ph"] == "X" and e["name"] == "serve.prefill"]
+    assert sorted((a["tokens"], a["padded"], a["tail"]) for a in spans) == [
+        (5, 16, 0), (16, 16, 0), (20, 32, 0), (32, 32, 0), (50, 64, 0),
+        (64, 64, 0)]
+
+
+def test_paged_engine_new_prompt_lengths_compile_nothing(masked_fp32):
+    """Once each bucket has run, prompts of lengths not seen before pad
+    into them without a single compile: no device op sees the (1, n)
+    tokens of an unpadded prompt."""
+    params, cfg = masked_fp32
+    eng = _bucketed_engine(params, cfg)
+    rng = np.random.default_rng(9)
+    for n in (16, 32, 64):
+        eng.submit(rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                   max_new_tokens=2)
+        eng.run()
+    compiles = []
+
+    def listen(event, duration, **_):
+        if "backend_compile" in event:
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for n in (5, 23, 41, 63):
+            eng.submit(rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                       max_new_tokens=2)
+            eng.run()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert eng.stats()["prefill_tail_tokens"] == 0
+
+
+def test_paged_engine_streams_past_largest_bucket(masked_fp32):
+    """A prompt longer than the largest bucket prefills that bucket and
+    streams the rest through the decode batch, and still gives the
+    fixed-slot engine's greedy tokens beside a padded one."""
+    params, cfg = masked_fp32
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (80, 40)]
+    refs = _reference_outputs(params, cfg, prompts, 4)
+
+    eng = _bucketed_engine(params, cfg)
+    hs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    eng.run()
+    for i, h in enumerate(hs):
+        assert h.request.output == refs[i], (i, h.request.output, refs[i])
+    assert "ingest" in eng.lifecycle(hs[0]).phase_sequence()
+    assert "ingest" not in eng.lifecycle(hs[1]).phase_sequence()
+    assert sum(e["args"]["tail_rows"] for e in _serve_steps(eng)) == 80 - 64
+    st = eng.stats()
+    assert st["prefill_tail_tokens"] == 80 - 64
+    assert st["prefill_pad_tokens"] == 64 - 40
 
 
 def test_paged_engine_growth_preemption_e2e(tiny_fp32):
