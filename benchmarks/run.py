@@ -14,7 +14,8 @@ reproduced (a ratio, error, or tokens/s).
   fig15_latency_memory      latency + cache memory vs output length
   kernel_state_update       fused kernel vs unfused jnp on CPU (interpret)
   kernel_attention          decode attention kernel vs ref
-  serving_throughput        engine tokens/s vs batch (tiny model, real compute)
+  serving_throughput        paged engine tokens/s, unbucketed and bucketed
+                            prefill (tiny model, real compute)
   serving_open_loop         Poisson arrivals driving Engine.step(): goodput
   serving_shared_prefix     CoW fork vs N independent submissions: prefill
                             tokens + allocated pages saved
@@ -228,49 +229,19 @@ def kernel_attention():
 def serving_throughput():
     from repro.configs import get_smoke_config
     from repro.models import model as M
-    from repro.serving.engine import (EngineConfig, PagedEngineConfig,
-                                      PagedServingEngine, Request,
-                                      ServingEngine)
+    from repro.serving.api import ServeConfig
+    from repro.serving.engine import PagedServingEngine, Request
     cfg = get_smoke_config("mamba2-2.7b")
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     artifact = SERVING_ARTIFACT
-    # one mixed prompt set shared by the slots4 and paged rows, so the
-    # paged_vs_slots ratio compares pools, not workloads (prefill compiles
-    # per distinct prompt length and would otherwise skew the wall clock)
+    # one mixed prompt set shared by the unbucketed and bucketed rows
     mixed = [rng.integers(0, cfg.vocab_size,
                           8 + i % 8 if i % 2 else 40 + i).astype(np.int32)
              for i in range(8)]
-    for slots in (1, 4):
-        eng = ServingEngine(params, cfg,
-                            EngineConfig(slots=slots, cache_capacity=128))
-        for i in range(slots * 2):
-            prompt = (mixed[i] if slots == 4
-                      else rng.integers(0, cfg.vocab_size, 8
-                                        ).astype(np.int32))
-            eng.submit(Request(rid=i, prompt=prompt, max_new_tokens=8))
-        t0 = time.perf_counter()
-        done = eng.run()
-        dt = time.perf_counter() - t0
-        toks = sum(len(r.output) for r in done)
-        stats = eng.stats()
-        # the registry view: histogram summaries (ttft/step/tok-latency
-        # percentiles, step_s split by compile tag) + which jitted fns
-        # compiled how often -- the p99_step_s vs p99_step_nocompile_s gap
-        # is compile stalls, not steady-state decode
-        stats["histograms"] = eng.obs.metrics.summaries()
-        stats["recompile_counts"] = eng.obs.recompiles.counts()
-        artifact[f"slots{slots}"] = stats
-        emit(f"serving/slots{slots}", dt / max(toks, 1) * 1e6,
-             f"tokens_per_s={toks/dt:.2f};requests={len(done)};"
-             f"p99_ttft_ms={stats.get('p99_ttft_s', 0)*1e3:.1f};"
-             f"p99_step_nocompile_ms="
-             f"{stats['p99_step_nocompile_s']*1e3:.1f};"
-             f"recompiles={stats['recompiles']:.0f}")
-    # paged pool: same decode batch and the same mixed prompts; decode runs
-    # the block-table-native ops (no per-step gather/scatter)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=4, n_pages=9, n_slabs=9, prefill_chunk=128))
+    # decode runs the block-table-native ops (no per-step gather/scatter)
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=4, n_pages=9, n_slabs=9, prefill_chunk=128))
     for i, prompt in enumerate(mixed):
         eng.submit(Request(rid=i, prompt=prompt, max_new_tokens=8))
     t0 = time.perf_counter()
@@ -279,18 +250,15 @@ def serving_throughput():
     toks = sum(len(r.output) for r in done)
     stats = eng.stats()
     stats["bank_report"] = eng.bank_report()
+    # the registry view: histogram summaries (ttft/step/tok-latency
+    # percentiles, step_s split by compile tag) + which jitted fns compiled
+    # how often -- the p99_step_s vs p99_step_nocompile_s gap is compile
+    # stalls, not steady-state decode
     stats["histograms"] = eng.obs.metrics.summaries()
     stats["recompile_counts"] = eng.obs.recompiles.counts()
     artifact["paged"] = stats
-    # the headline of the block-table-native rewire: paged tokens/s vs the
-    # fixed-slot pool on the identical workload (was ~0.28x with the
-    # gather/scatter decode path), plus the residual gather ledger
-    ratio = (stats["tokens_per_s"]
-             / max(artifact["slots4"]["tokens_per_s"], 1e-9))
-    artifact["paged_vs_slots"] = ratio
     emit("serving/paged", dt / max(toks, 1) * 1e6,
          f"tokens_per_s={toks/dt:.2f};requests={len(done)};"
-         f"paged_vs_slots={ratio:.2f};"
          f"gather_bytes={stats['gather_bytes']:.0f};"
          f"occupancy={stats['occupancy']:.2f};"
          f"fragmentation={stats['fragmentation']:.2f};"
@@ -303,8 +271,8 @@ def serving_throughput():
     # distinct prompt length (8 in this mix).  "after" snaps the prefill to
     # a fixed bucket set and streams the tail through the decode batch, so
     # the prefill jit sees one shape per *bucket*.
-    eng_b = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=4, n_pages=9, n_slabs=9, prefill_chunk=128,
+    eng_b = PagedServingEngine(params, cfg, ServeConfig(
+        batch=4, n_pages=9, n_slabs=9, prefill_chunk=128,
         prefill_buckets=(8, 16, 32, 64, 128)))
     for i, prompt in enumerate(mixed):
         eng_b.submit(Request(rid=100 + i, prompt=prompt, max_new_tokens=8))
@@ -317,7 +285,7 @@ def serving_throughput():
     artifact["paged_bucketed"] = stats_b
     artifact["jit_hazard_fix"] = {
         "rule": "JH103 dynamic-shape-feeds-jit (prefill length churn)",
-        "fix": "PagedEngineConfig.prefill_buckets=(8, 16, 32, 64, 128)",
+        "fix": "ServeConfig.prefill_buckets=(8, 16, 32, 64, 128)",
         "before": {k: stats[k] for k in
                    ("recompiles", "recompile_counts",
                     "p99_step_nocompile_s", "tokens_per_s")},

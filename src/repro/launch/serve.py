@@ -1,20 +1,15 @@
 """Production serving launcher: the Pimba system loop.
 
-Paged, bank-aware pool (default) with the preempting scheduler:
+The paged, bank-aware pool with the preempting scheduler:
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
-        --smoke-size --paged --pages 33 --requests 16 --mixed \
+        --smoke-size --pages 33 --requests 16 --mixed \
         --policy priority --top-p 0.95 --seed 7
 
-Fixed-slot pool (legacy):
-
-    PYTHONPATH=src python -m repro.launch.serve --arch mamba2-2.7b \
-        --smoke-size --requests 12 --slots 4 --state-format mx8
-
-Both serve through the request-lifecycle facade (`repro.serving.api.Engine`):
+It serves through the request-lifecycle facade (`repro.serving.api.Engine`):
 `--stream` drives the engine open-loop and prints tokens as they are
 sampled; `--turns N` runs a multi-turn session on copy-on-write prefix
-sharing after the batch drains (paged only).
+sharing after the batch drains.
 
 Weights come from --ckpt-dir (a training checkpoint) or random init.
 """
@@ -29,34 +24,30 @@ def main(argv=None):
     ap.add_argument("--smoke-size", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--batch", type=int, default=4,
+                    help="rows in the jitted decode step")
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--cache-capacity", type=int, default=256)
     ap.add_argument("--state-format", default="mx8",
                     choices=["mx8", "int8", "fp16", "fp32"])
     ap.add_argument("--backend", default="auto",
                     choices=["auto", "pallas", "jnp"],
                     help="SPU op backend; 'auto' asks the op registry for "
                          "the preferred backend capable of --state-format "
-                         "in the served layout (dense, or paged under "
-                         "--paged). A concrete choice errors if any SPU "
-                         "compute op the model runs (state_update / "
+                         "in the paged layout. A concrete choice errors if "
+                         "any SPU compute op the model runs (state_update / "
                          "attn_decode / mla_decode) lacks that (op, format, "
                          "backend, layout) registration; kv_append always "
-                         "negotiates (dense kv_append is jnp-only; the "
-                         "paged one has an in-place pallas impl for mx8)")
+                         "negotiates")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-p", type=float, default=1.0,
                     help="nucleus sampling mass (1.0 disables)")
     ap.add_argument("--seed", type=int, default=0,
                     help="sampling PRNG seed for reproducible runs")
     # paged pool + scheduler
-    ap.add_argument("--paged", action="store_true",
-                    help="serve from the paged, bank-aware state/KV pool")
     ap.add_argument("--pages", type=int, default=33,
                     help="pool size in 128-token pages (incl. 1 scratch)")
     ap.add_argument("--slabs", type=int, default=None,
-                    help="state slabs (default: 2*slots + 1)")
+                    help="state slabs (default: 2*batch + 1)")
     ap.add_argument("--prefill-chunk", type=int, default=128,
                     help="longest full-sequence prefill; longer prompts "
                          "stream their tail through the decode batch")
@@ -70,7 +61,7 @@ def main(argv=None):
                          "each request's tokens as they are sampled")
     ap.add_argument("--turns", type=int, default=0,
                     help="after the batch: run a --turns-turn chat session "
-                         "on copy-on-write prefix sharing (paged only)")
+                         "on copy-on-write prefix sharing")
     # observability
     ap.add_argument("--trace", default=None, metavar="OUT",
                     help="write the structured trace at exit: Chrome-trace "
@@ -96,27 +87,21 @@ def main(argv=None):
            else get_config(args.arch))
     if cfg.encoder_only:
         raise SystemExit(f"{cfg.name} is encoder-only: nothing to serve")
-    if args.turns and not args.paged:
-        raise SystemExit("--turns needs --paged (sessions are built on "
-                         "copy-on-write prefix sharing in the paged pool)")
     # capability lookup in the SPU op registry (replaces the old inline
     # "pallas if mx8 else jnp" heuristic): every SPU *compute* op this model
     # dispatches must support a concrete requested triple, so a bad
     # --backend fails up front; kv_append (a scatter, jnp-only by design)
     # always negotiates, as does everything under --backend auto
     requested = None if args.backend == "auto" else args.backend
-    # --paged serves through the block-table-native ops, so the capability
-    # check runs against the layout actually dispatched
-    layout = "paged" if args.paged else "dense"
     compute_kinds = sorted({e.kind for e in OPS.decode_op_plans(cfg, 1, 128)}
                            - {"kv_append"})
     try:
         resolved = [OPS.resolve_backend(kind, args.state_format, requested,
-                                        layout=layout,
+                                        layout="paged",
                                         strict=requested is not None)
                     for kind in compute_kinds]
         backend = resolved[0] if resolved else OPS.resolve_backend(
-            "state_update", args.state_format, requested, layout=layout)
+            "state_update", args.state_format, requested, layout="paged")
     except ValueError as e:
         raise SystemExit(f"--backend {args.backend}: {e}")
     cfg = cfg.with_(state_quant=OPS.StateQuantConfig(
@@ -134,9 +119,7 @@ def main(argv=None):
                               top_k=40 if args.temperature > 0 else 0,
                               top_p=args.top_p)
     scfg = ServeConfig(
-        backend="paged" if args.paged else "slots",
-        batch=args.slots,
-        cache_capacity=args.cache_capacity,
+        batch=args.batch,
         n_pages=args.pages,
         n_slabs=args.slabs,
         prefill_chunk=args.prefill_chunk,
@@ -160,11 +143,10 @@ def main(argv=None):
             deadline=(time.time() + 1 + i % 5
                       if args.policy == "deadline" else None)))
     t0 = time.perf_counter()
-    if args.paged:
-        # score the page map of the first admitted batch with the PIM bank
-        # model (computed on demand: no decode step accounts bank traffic)
-        eng.step()
-        bank = eng.engine.bank_report()
+    # score the page map of the first admitted batch with the PIM bank
+    # model (computed on demand: no decode step accounts bank traffic)
+    eng.step()
+    bank = eng.engine.bank_report()
     if args.stream:
         # open-loop: one step at a time, tokens printed as they surface
         running = True
@@ -178,11 +160,10 @@ def main(argv=None):
     else:
         done = eng.run()
     stats = eng.stats()
-    pool = "paged" if args.paged else "slots"
     print(f"{len(done)} requests, {stats['tokens']:.0f} tokens, "
           f"{stats['tokens_per_s']:.1f} tok/s "
           f"(wall {time.perf_counter()-t0:.1f}s, state={args.state_format}, "
-          f"backend={backend}, pool={pool})")
+          f"backend={backend})")
     print(f"  steps: p99={stats['p99_step_s']*1e3:.1f}ms "
           f"p99_nocompile={stats['p99_step_nocompile_s']*1e3:.1f}ms "
           f"({int(stats['compile_steps'])} compile steps, "
@@ -198,15 +179,14 @@ def main(argv=None):
         if k in stats:
             print(f"  {k}={stats[k]*1e3:.1f}ms", end="")
     print()
-    if args.paged:
-        print(f"  occupancy={stats['occupancy']:.2f} "
-              f"fragmentation={stats['fragmentation']:.2f} "
-              f"preemptions={int(stats['preemptions'])}")
-        print(f"  pimsim page-map (first batch): "
-              f"step={bank['t_real_s']*1e6:.2f}us "
-              f"ideal={bank['t_ideal_s']*1e6:.2f}us "
-              f"conflict_factor={bank['conflict_factor']:.2f} "
-              f"bank_imbalance={bank['imbalance']:.2f}")
+    print(f"  occupancy={stats['occupancy']:.2f} "
+          f"fragmentation={stats['fragmentation']:.2f} "
+          f"preemptions={int(stats['preemptions'])}")
+    print(f"  pimsim page-map (first batch): "
+          f"step={bank['t_real_s']*1e6:.2f}us "
+          f"ideal={bank['t_ideal_s']*1e6:.2f}us "
+          f"conflict_factor={bank['conflict_factor']:.2f} "
+          f"bank_imbalance={bank['imbalance']:.2f}")
 
     if args.turns:
         print(f"-- {args.turns}-turn session (copy-on-write prefix "

@@ -4,22 +4,22 @@ The batch-offline engines (``submit()`` everything, ``run()`` to drain,
 read ``done`` at the end) become a request-lifecycle API shaped like a real
 serving front-end:
 
-  * one ``ServeConfig`` subsumes ``EngineConfig`` / ``PagedEngineConfig``
-    and selects the fixed-slot or paged backend;
+  * one ``ServeConfig`` configures the paged engine
+    (:class:`repro.serving.engine.PagedServingEngine`);
   * ``Engine.submit()`` returns a ``RequestHandle`` that streams tokens as
     they are sampled each ``step()``, exposes the terminal status
     (``done`` / ``aborted`` / ``truncated``) and can ``abort()`` mid-decode
-    (pages/slots free immediately, spilled victims included);
+    (pages free immediately, spilled victims included);
   * ``Engine.step()`` is the explicit event loop -- drive it open-loop,
     interleaving submits/aborts between steps; ``run()`` stays as the
     drain-to-empty wrapper;
-  * ``Engine.fork()`` (paged backend) starts a continuation of a retained
+  * ``Engine.fork()`` starts a continuation of a retained
     parent via copy-on-write prefix sharing: the child references the
     parent's full prefix pages and copies only the partial tail page, so N
     sampled continuations of one prompt or the next turn of a chat skip
     re-prefilling the shared context entirely.  ``Session`` wraps that into
     multi-turn chat;
-  * ``ServeConfig(prefix_cache=True)`` (paged backend) makes that sharing
+  * ``ServeConfig(prefix_cache=True)`` makes that sharing
     *automatic and cross-request*: a radix prefix store remembers every
     full prompt page served, and any later ``submit()`` whose prompt shares
     the prefix adopts the stored pages -- no explicit ``fork()``.  Stored
@@ -27,7 +27,7 @@ serving front-end:
     demoted to a ``host_tier_bytes``-budgeted host tier, and come back via
     scheduler-lookahead async prefetch (see ``serving/memory/tiered``).
 
-    eng = Engine(params, cfg, ServeConfig(backend="paged"))
+    eng = Engine(params, cfg, ServeConfig())
     h = eng.submit(prompt, max_new_tokens=32)
     for tok in h:                      # drives eng.step() under the hood
         print(tok)
@@ -46,8 +46,7 @@ import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.obs import Observability, RequestRecord
-from repro.serving.engine import (EngineConfig, PagedEngineConfig,
-                                  PagedServingEngine, Request, ServingEngine,
+from repro.serving.engine import (PagedServingEngine, Request,
                                   TERMINAL_STATUSES)
 from repro.serving.sampler import SamplingConfig
 from repro.serving.scheduler import SchedulerConfig
@@ -57,100 +56,60 @@ __all__ = ["ServeConfig", "Engine", "RequestHandle", "Session", "Request"]
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
-    """One config for both serving backends.
-
-    ``backend="slots"`` serves from the fixed ``batch x cache_capacity``
-    cache pool; ``backend="paged"`` serves from the paged, bank-aware
-    state/KV pool (preempting scheduler, chunked prefill, copy-on-write
-    prefix sharing / sessions).
-    """
-    backend: str = "paged"             # "paged" | "slots"
-    batch: int = 4                     # decode rows (slots / decode batch)
-    cache_capacity: int = 256          # slots backend: max context per slot
-    n_pages: Optional[int] = 33        # paged: pool pages (incl. 1 scratch)
-    n_slabs: Optional[int] = None      # paged: state slabs (default 2B+1)
-    byte_budget: Optional[int] = None  # paged: alternative to n_pages
-    prefill_chunk: int = 128           # paged: longest full-seq prefill
+    """The serving config, read by :class:`PagedServingEngine`: the paged,
+    bank-aware state/KV pool, the preempting scheduler, chunked prefill and
+    copy-on-write prefix sharing / sessions."""
+    backend: str = "paged"             # the only engine; any other value
+                                       # is refused
+    batch: int = 4                     # rows in the jitted decode step
+    n_pages: Optional[int] = 33        # pool pages (incl. 1 scratch)
+    n_slabs: Optional[int] = None      # state slabs (default 2*batch+1)
+    byte_budget: Optional[int] = None  # sizes the pool instead of n_pages
+    prefill_chunk: int = 128           # longest full-sequence prefill; the
+                                       # prompt tail streams through decode
+    # opt-in prefill length bucketing (JH103): when set, the prefill jit
+    # compiles one executable per *bucket* instead of one per distinct
+    # prompt length.  A prompt that a bucket up to prefill_chunk covers is
+    # prefilled whole at the smallest such bucket, the padding masked
+    # (where the model's layers can mask it: M.masked_prefill_ok); a longer
+    # one prefills the largest bucket that fits and streams the rest
+    # through the decode batch.  Off by default: streamed tokens take
+    # other stochastic-rounding draws than prefilled ones, so mx8 token
+    # streams differ from the unbucketed engine (both are valid).
     prefill_buckets: Optional[Tuple[int, ...]] = None
-                                       # paged: prefill lengths (bounded
-                                       # compile count): a covered prompt is
-                                       # padded up to its bucket, the padding
-                                       # masked; a longer one streams its
-                                       # tail through decode
     sampling: SamplingConfig = SamplingConfig()
     scheduler: SchedulerConfig = SchedulerConfig()
     seed: int = 0
-    # --- tiered memory hierarchy (paged backend only) ---
+    # --- tiered memory hierarchy (serving/memory/tiered) ---
     prefix_cache: bool = False         # radix prefix store: requests that
                                        # share a prompt prefix with earlier
                                        # requests adopt its pages, no fork()
     prefix_store_pages: int = 64       # store capacity in pages (LRU)
     host_tier_bytes: Optional[int] = None  # host DRAM budget (None = off)
-    prefetch_window: int = 2           # lookahead prefetch depth
-    # --- resilience / fault injection (paged backend only) ---
+    prefetch_window: int = 2           # scheduler lookahead for async
+                                       # spill-resume / prefix prefetch
+    # --- resilience / fault injection (serving/faults, resilience) ---
     fault_plan: Optional[str] = None   # fault spec string (see
                                        # serving/faults); REPRO_FAULTS env
                                        # applies when unset
     nan_guard: Optional[bool] = None   # post-step non-finite-logits guard
-                                       # (None = on iff faults active)
+                                       # (None = on iff faults active; the
+                                       # check costs one device sync)
     max_queued: Optional[int] = None   # admission control: queue-depth cap,
                                        # excess submits end ``rejected``
     request_timeout_s: Optional[float] = None  # max queue wait -> rejected
     step_budget_s: Optional[float] = None      # watchdog wall-clock budget
-    # --- speculative decoding (paged backend only) ---
+    # --- speculative decoding (serving/spec) ---
     spec: Optional[str] = None         # draft source: "ngram" (self-draft)
                                        # or "model:<arch>" (small model)
-    spec_k: int = 3                    # max drafts verified per step
+    spec_k: int = 3                    # max drafts per row; the verify step
+                                       # always compiles at spec_k+1 positions
     spec_window: int = 8               # k-controller acceptance window
 
     def __post_init__(self):
-        if self.backend not in ("paged", "slots"):
-            raise ValueError(f"backend must be 'paged' or 'slots', "
-                             f"got {self.backend!r}")
-        if self.backend == "slots" and self.prefix_cache:
-            raise ValueError("prefix_cache needs the paged backend "
-                             "(page refcounts / block tables)")
-        if self.backend == "slots" and self.spec is not None:
-            raise ValueError("speculative decoding needs the paged backend "
-                             "(the spec_verify step walks block tables and "
-                             "rolls state slabs back)")
-        if self.backend == "slots":
-            for f in ("fault_plan", "nan_guard", "max_queued",
-                      "request_timeout_s", "step_budget_s"):
-                if getattr(self, f) is not None:
-                    raise ValueError(
-                        f"{f} needs the paged backend (the resilience "
-                        "layer lives in the paged engine/pool)")
-
-    def engine_config(self):
-        """The backend-specific config this ServeConfig lowers to."""
-        if self.backend == "slots":
-            return EngineConfig(slots=self.batch,
-                                cache_capacity=self.cache_capacity,
-                                sampling=self.sampling, seed=self.seed)
-        return PagedEngineConfig(
-            max_decode_batch=self.batch,
-            n_pages=None if self.byte_budget is not None else self.n_pages,
-            n_slabs=(self.n_slabs if self.n_slabs is not None
-                     else 2 * self.batch + 1),
-            byte_budget=self.byte_budget,
-            prefill_chunk=self.prefill_chunk,
-            prefill_buckets=self.prefill_buckets,
-            sampling=self.sampling,
-            scheduler=self.scheduler,
-            seed=self.seed,
-            prefix_cache=self.prefix_cache,
-            prefix_store_pages=self.prefix_store_pages,
-            host_tier_bytes=self.host_tier_bytes,
-            prefetch_window=self.prefetch_window,
-            fault_plan=self.fault_plan,
-            nan_guard=self.nan_guard,
-            max_queued=self.max_queued,
-            request_timeout_s=self.request_timeout_s,
-            step_budget_s=self.step_budget_s,
-            spec=self.spec,
-            spec_k=self.spec_k,
-            spec_window=self.spec_window)
+        if self.backend != "paged":
+            raise ValueError(f"backend={self.backend!r}: the paged engine "
+                             "is the only serving engine")
 
 
 class RequestHandle:
@@ -227,33 +186,23 @@ class RequestHandle:
     # ------------- control -------------
 
     def abort(self) -> bool:
-        """Cancel now: frees pages/slots immediately (spilled state too);
+        """Cancel now: frees pages immediately (spilled state too);
         tokens already streamed stay available.  Status -> ``aborted``."""
         return self._engine.abort(self)
 
 
 class Engine:
-    """The one serving facade over both backends."""
+    """The serving facade over the paged engine."""
 
     def __init__(self, params, cfg: ModelConfig,
                  scfg: ServeConfig = ServeConfig(), mesh_axes=None,
                  obs: Optional[Observability] = None):
         self.scfg = scfg
-        obs = obs if obs is not None else Observability()
-        ecfg = scfg.engine_config()
-        if scfg.backend == "slots":
-            self._eng = ServingEngine(params, cfg, ecfg, mesh_axes=mesh_axes,
-                                      obs=obs)
-        else:
-            self._eng = PagedServingEngine(params, cfg, ecfg,
-                                           mesh_axes=mesh_axes, obs=obs)
+        self._eng = PagedServingEngine(params, cfg, scfg,
+                                       mesh_axes=mesh_axes, obs=obs)
         self._rids = itertools.count()
 
     # ------------- properties -------------
-
-    @property
-    def backend(self) -> str:
-        return self._eng.backend
 
     @property
     def engine(self):
@@ -290,7 +239,7 @@ class Engine:
                retain: bool = False) -> RequestHandle:
         """Queue a new request; returns its streaming handle.
 
-        ``retain=True`` (paged backend) keeps the finished request's pages
+        ``retain=True`` keeps the finished request's pages
         pinned so it can serve as a ``fork()`` parent; pair it with
         ``release()`` when the prefix is no longer needed.
         """
@@ -311,11 +260,7 @@ class Engine:
         feeds only ``tokens`` (the next user turn; may be empty for a pure
         sampled continuation) after the parent's final sampled token.  Its
         context is exactly ``parent.prompt + parent.output + tokens``.
-        Paged backend only.
         """
-        if self.backend != "paged":
-            raise ValueError("fork() needs the paged backend "
-                             "(copy-on-write prefix sharing)")
         if not parent.finished or parent.status != "done":
             raise ValueError(f"fork parent {parent.rid} is not done "
                              f"(status={parent.status}); drive it with "
@@ -349,7 +294,7 @@ class Engine:
         return self._eng.has_work()
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
-        """Drain-to-empty wrapper around ``step()`` (see the engines' docs
+        """Drain-to-empty wrapper around ``step()`` (see the engine's docs
         for the ``max_steps`` still-active surfacing contract)."""
         return self._eng.run(max_steps=max_steps)
 
@@ -359,9 +304,6 @@ class Engine:
     # ------------- sessions -------------
 
     def session(self) -> "Session":
-        if self.backend != "paged":
-            raise ValueError("sessions need the paged backend "
-                             "(copy-on-write prefix sharing)")
         return Session(self)
 
 
@@ -375,7 +317,6 @@ class Session:
     """
 
     def __init__(self, engine: Engine):
-        assert engine.backend == "paged"
         self._engine = engine
         self._prev: Optional[RequestHandle] = None
 

@@ -1,52 +1,50 @@
-"""Batched serving engines: the Pimba system loop (paper Fig. 7).
+"""The batched serving engine: the Pimba system loop (paper Fig. 7).
 
-Two engines share the request-lifecycle machinery (``_EngineCore``): an
-explicit ``step()`` event loop (admit + one batched decode step) that
-callers can drive open-loop, ``submit`` / ``abort`` with terminal statuses
-(``done`` / ``aborted`` / ``truncated``), and a ``run()`` drain wrapper.
-The streaming facade over them lives in :mod:`repro.serving.api`.
-
-``ServingEngine`` -- the original fixed-slot pool: continuous batching over
-``slots x cache_capacity`` preallocated caches.  One long request dictates
-everyone's memory footprint and admission is FCFS.
-
-``PagedServingEngine`` -- the paged pool (``serving/memory``): state/KV
-memory is block/page granular with a block table per request, so short and
-long prompts coexist in the same byte budget, admission follows a
+``PagedServingEngine`` serves from the paged pool (``serving/memory``):
+state/KV memory is block/page granular with a block table per request, so
+short and long prompts coexist in the same byte budget, admission follows a
 priority/deadline scheduler (``serving/scheduler``), prefill is chunked
 (the tail of a long prompt streams through the shared decode step instead
 of blocking the batch), and the pool preempts by page eviction -- victim
-pages spill to host bit-exactly and resume re-pins them.  It additionally
-supports **retained** requests (finished but still pinning their pages) and
+pages spill to host bit-exactly and resume re-pins them.  It supports
+**retained** requests (finished but still pinning their pages) and
 copy-on-write ``fork`` of a retained parent: the child shares the parent's
 full prefix pages by reference and skips re-prefill entirely (multi-turn
 sessions, N parallel continuations of one prompt).
 
-The cache pool is MX8 by default -- the 8-bit state is what makes slot
+Its request lifecycle is an explicit ``step()`` event loop (admit + one
+batched decode step) that callers can drive open-loop, ``submit`` /
+``abort`` with terminal statuses (``done`` / ``aborted`` / ``truncated``
+...), and a ``run()`` drain wrapper.  The streaming facade over it, and the
+``ServeConfig`` it reads, live in :mod:`repro.serving.api`.
+
+The cache pool is MX8 by default -- the 8-bit state is what makes state
 memory ~2x smaller than the fp16 baseline (paper Fig. 1a, 15b).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import ops as OPS
-from repro.core import attention_cache as AC
 from repro.core.paged import PAGE_TOKENS, pages_for
 from repro.models import model as M
 from repro.obs import Observability
 from repro.models.config import ModelConfig
 from repro.serving.faults import FaultPlan
+from repro.serving.memory import SpilledRequest, TieredStatePool
 from repro.serving.resilience import (REPREFILL_CAP, BlobCorruption,
                                       StepWatchdog, retry_transient)
 from repro.serving.sampler import SamplingConfig, filtered_probs, sample
-from repro.serving.scheduler import Scheduler, SchedulerConfig
+from repro.serving.scheduler import Scheduler
+
+if TYPE_CHECKING:
+    from repro.serving.api import ServeConfig
 
 #: terminal request statuses -- a request in one of these will never
 #: produce another token.  ``failed`` = the engine quarantined it after an
@@ -61,10 +59,10 @@ class Request:
     prompt: np.ndarray                 # (S,) int32
     max_new_tokens: int = 16
     eos_id: Optional[int] = None
-    priority: int = 0                  # lower = more urgent (paged engine)
-    deadline: Optional[float] = None   # absolute time (paged engine, EDF)
+    priority: int = 0                  # lower = more urgent
+    deadline: Optional[float] = None   # absolute time (EDF)
     retain: bool = False               # keep pages pinned after finish
-                                       # (paged engine: enables fork())
+                                       # (enables fork())
     parent_rid: Optional[int] = None   # copy-on-write fork parent
     # filled by the engine
     output: List[int] = dataclasses.field(default_factory=list)
@@ -81,58 +79,33 @@ class Request:
         return self.status in TERMINAL_STATUSES
 
 
-@dataclasses.dataclass(frozen=True)
-class EngineConfig:
-    slots: int = 4                    # decode batch size
-    cache_capacity: int = 256         # max context per slot (tile-aligned)
-    sampling: SamplingConfig = SamplingConfig()
-    seed: int = 0                     # sampling PRNG seed
-
-
 class _OpTrafficMeter:
     """Accumulates per-op-kind SPU traffic over decode steps.
 
-    Bytes come from the registered ops' own ``traffic(plan)`` descriptors
-    (``repro.ops.decode_traffic_by_kind``) at each active row's real context
-    length, so the serving stats attribute bandwidth between attention and
-    state-update ops with the same numbers the cost models use.
-
-    ``layout="dense"`` traffic is affine in the context length; the
-    ``layout="paged"`` ops are affine in the *page count* (whole 128-token
-    pages stream, appends write one slot).  Either way the descriptors are
-    probed once at two operating points and each step costs O(kinds), not
-    O(rows) registry walks -- no per-slot Python work in the decode loop.
-    The paged engine passes pre-deduplicated units so a copy-on-write
-    shared page is attributed once per step, not once per reader.
+    Bytes come from the registered paged ops' own ``traffic(plan)``
+    descriptors (``repro.ops.decode_traffic_by_kind``), which are affine in
+    a row's *page count* (whole 128-token pages stream, appends write one
+    slot).  They are probed once at one and two pages, and each step costs
+    O(kinds), not O(rows) registry walks.  The engine passes
+    pre-deduplicated page counts, so a copy-on-write shared page is
+    attributed once per step, not once per reader.
     """
 
-    def __init__(self, cfg: ModelConfig, layout: str = "dense",
-                 metrics=None):
+    def __init__(self, cfg: ModelConfig, metrics=None):
         self.cfg = cfg
-        self.layout = layout
         self.metrics = metrics        # mirror into the obs registry
         self.by_kind: Dict[str, float] = {}
-        self._affine = None   # kind -> (bytes at 1 unit, bytes per +1 unit)
+        self._affine = None   # kind -> (bytes at 1 page, bytes per +1 page)
 
     def _coeffs(self) -> Dict[str, tuple]:
         if self._affine is None:
-            if self.layout == "paged":
-                u1, u2 = PAGE_TOKENS, 2 * PAGE_TOKENS   # 1 page, 2 pages
-            else:
-                u1, u2 = 1, 2                            # 1 token, 2 tokens
-            t1 = OPS.decode_traffic_by_kind(self.cfg, 1, u1, self.layout)
-            t2 = OPS.decode_traffic_by_kind(self.cfg, 1, u2, self.layout)
+            t1, t2 = (OPS.decode_traffic_by_kind(self.cfg, 1, u, "paged")
+                      for u in (PAGE_TOKENS, 2 * PAGE_TOKENS))
             self._affine = {k: (t1[k].total, t2[k].total - t1[k].total)
                             for k in t1}
         return self._affine
 
-    def _units(self, length: int) -> int:
-        """Traffic units of one row: tokens (dense) or pages (paged)."""
-        if self.layout == "paged":
-            return pages_for(max(int(length), 1))
-        return max(int(length), 1)
-
-    def account_units(self, units: Sequence[int]) -> None:
+    def account_units(self, units: List[int]) -> None:
         if not units:
             return
         n, total = len(units), sum(units)
@@ -143,16 +116,13 @@ class _OpTrafficMeter:
                 self.metrics.counter("op_traffic_bytes_total",
                                      kind=kind).inc(add)
 
-    def account_step(self, lengths) -> None:
-        self.account_units([self._units(L) for L in lengths])
-
     def stats(self) -> Dict[str, float]:
         return {f"op_traffic_bytes/{k}": v
                 for k, v in sorted(self.by_kind.items())}
 
 
 def _sample_tokens(key, logits, sampling: SamplingConfig):
-    """The one sampling helper both engines route through (prefill's first
+    """The one sampling helper the engine routes through (prefill's first
     token and every decode step): split the engine key once, sample a whole
     batch of logits.  Returns (new_key, tokens (B,) on device)."""
     key, sub = jax.random.split(key)
@@ -169,48 +139,35 @@ def _prefill_program(cfg: ModelConfig, mesh_axes):
     return jax.jit(prefill)
 
 
-def _row_insert(pool_leaf, row_leaf, slot):
-    """Write one batch row into a pooled cache leaf (leading dims may include
-    the n_groups stack: (G, B, ...) vs row (G, 1, ...))."""
-    if pool_leaf.ndim == 0:
-        return pool_leaf
-    # find the batch axis: row has size 1 there, pool has size slots
-    for ax in range(row_leaf.ndim):
-        if row_leaf.shape[ax] == 1 and pool_leaf.shape[ax] != row_leaf.shape[ax]:
-            idx = [slice(None)] * pool_leaf.ndim
-            idx[ax] = slot
-            return pool_leaf.at[tuple(idx)].set(
-                jnp.squeeze(row_leaf, ax).astype(pool_leaf.dtype))
-    # lengths-style (B,) leaves: row (1,), pool (slots,)
-    return pool_leaf.at[slot].set(row_leaf.reshape(-1)[0].astype(pool_leaf.dtype))
+@dataclasses.dataclass
+class _Active:
+    req: Request
+    length: int                       # cached positions so far
+    pending: List[int]                # prompt tokens not yet consumed
+    cur_token: int                    # next token to feed once prompt is done
+    replayed: bool = False            # corruption-recovery re-prefill: the
+                                      # "prompt" includes generated tokens,
+                                      # so prefix-store inserts are skipped
 
 
-# ===========================================================================
-# Shared stepper core
-# ===========================================================================
+class PagedServingEngine:
+    """Continuous batching over the paged, bank-aware state/KV pool.
 
-
-class _EngineCore:
-    """Request-lifecycle machinery both engines are rebased onto.
-
-    Subclasses implement the mechanics (``_admit``, ``_decode_step``,
-    ``_abort_impl``, ``has_work``, ``pending_requests``); the core owns the
-    public lifecycle: ``submit`` -> ``step``/``run`` -> terminal status,
-    plus ``abort`` and the stats schema.
-
-    Every engine carries an :class:`repro.obs.Observability` bundle:
-    ``stats()`` is a schema-stable view over its metrics registry, request
-    phase transitions land in its lifecycle tracker, each step and the
-    boundaries inside it are spans in its trace ring buffer (and in a
-    running ``jax.profiler`` session), and the jitted steppers are wrapped
-    by its recompile watcher.
+    The engine owns the public lifecycle: ``submit`` -> ``step``/``run``
+    -> terminal status, plus ``abort`` and the stats schema.  It carries an
+    :class:`repro.obs.Observability` bundle: ``stats()`` is a
+    schema-stable view over its metrics registry, request phase transitions
+    land in its lifecycle tracker, each step and the boundaries inside it
+    are spans in its trace ring buffer (and in a running ``jax.profiler``
+    session), and the jitted steppers are wrapped by its recompile watcher.
     """
 
-    backend: str = "?"
-
-    def __init__(self, cfg: ModelConfig, seed: int = 0,
-                 obs: Optional[Observability] = None):
+    def __init__(self, params, cfg: ModelConfig, scfg: "ServeConfig",
+                 mesh_axes=None, obs: Optional[Observability] = None):
+        assert not cfg.encoder_only
+        self.params = params
         self.cfg = cfg
+        self.scfg = scfg
         self.obs = obs if obs is not None else Observability()
         self.done: List[Request] = []
         self.step_count = 0
@@ -224,27 +181,149 @@ class _EngineCore:
         #: copy-on-write forks skip the shared prefix, so this is the
         #: number the prefix-sharing benches compare
         self.prefill_tokens = 0
-        self._key = jax.random.PRNGKey(seed)
-        #: wall-clock step budget monitor (paged engine wires one up when
-        #: ``step_budget_s`` is configured; None = zero cost)
-        self.watchdog: Optional[StepWatchdog] = None
+        self._key = jax.random.PRNGKey(scfg.seed)
+        # a byte budget sizes the page pool itself; n_pages is then unused
+        self.pool = TieredStatePool(
+            cfg, n_pages=None if scfg.byte_budget is not None else scfg.n_pages,
+            n_slabs=(scfg.n_slabs if scfg.n_slabs is not None
+                     else 2 * scfg.batch + 1),
+            byte_budget=scfg.byte_budget,
+            mesh_axes=mesh_axes, host_tier_bytes=scfg.host_tier_bytes,
+            prefix_cache=scfg.prefix_cache,
+            prefix_store_pages=scfg.prefix_store_pages)
+        self.pool.attach_obs(self.obs)
+        self.sched = Scheduler(scfg.scheduler)
+        self.sched.obs = self.obs
+        self.active: Dict[int, _Active] = {}
+        self.rows: List[Optional[int]] = [None] * scfg.batch
+        self.spilled: Dict[int, Tuple[SpilledRequest, List[int], int]] = {}
+        #: finished-but-pinned requests: fork parents for sessions /
+        #: N-way continuations; release_retained() frees them
+        self.retained: Dict[int, _Active] = {}
+        # account the block-table-native ops this engine actually dispatches
+        self._traffic = _OpTrafficMeter(cfg, metrics=self.obs.metrics)
+        self.preemptions = 0
+        self._occ: List[float] = []
+        self._frag: List[float] = []
+        self._step_prefilled = 0          # full-sequence prefill tokens
+                                          # of the step under way
+        # --- resilience wiring (all None/empty => zero overhead) ---
+        self.faults = FaultPlan.maybe(scfg.fault_plan, seed=scfg.seed)
+        self.pool.faults = self.faults
+        #: wall-clock step budget monitor (zero cost when
+        #: ``step_budget_s`` is unset)
+        self.watchdog = StepWatchdog(scfg.step_budget_s, obs=self.obs)
+        self._nan_guard = (scfg.nan_guard if scfg.nan_guard is not None
+                           else self.faults is not None)
+        #: rid -> full replay token stream (prompt + generated) for the
+        #: bounded re-prefill after a detected spill-blob corruption
+        self._replay: Dict[int, List[int]] = {}
+        self._reprefills: Dict[int, int] = {}
+        #: rid -> consecutive failed admission attempts (degradation rung)
+        self._admit_fails: Dict[int, int] = {}
+        self._prefill_jit = self.obs.wrap_jit(
+            _prefill_program(cfg, mesh_axes), "engine.prefill")
+        #: bucketed prompts are prefilled padded, the padding masked
+        self._masked = bool(scfg.prefill_buckets) and M.masked_prefill_ok(cfg)
+        max_chunk_pages = pages_for(scfg.prefill_chunk)
+        assert max_chunk_pages <= self.pool.usable_pages, \
+            "prefill_chunk does not fit the page pool"
+        # --- speculative decoding (serving/spec) ---
+        self.draft = None
+        self.kctl = None
+        if scfg.spec is not None:
+            from repro.serving.spec import (KController, ModelDraft,
+                                            NGramDraft)
+            assert scfg.spec_k >= 1, "spec_k must be at least 1"
+            if scfg.spec == "ngram":
+                self.draft = NGramDraft()
+            elif scfg.spec.startswith("model:"):
+                from repro.configs import get_smoke_config
+                dcfg = get_smoke_config(scfg.spec.split(":", 1)[1]).with_(
+                    state_quant=cfg.state_quant)
+                # the draft pool is deliberately NOT obs-wrapped: its jits
+                # are warmup-only per draft request and must not count
+                # against the target engine's decode recompile budget
+                self.draft = ModelDraft(
+                    dcfg, max_requests=scfg.batch + 1, seed=scfg.seed)
+            else:
+                raise ValueError(
+                    f"unknown spec draft source {scfg.spec!r} "
+                    "(expected 'ngram' or 'model:<arch>')")
+            self.kctl = KController(scfg.spec_k, window=scfg.spec_window)
+            # per-position seeds inside the verify step are spec_seed + i,
+            # so advance by n per step to keep the streams non-overlapping
+            self._spec_seed = 0
 
-    # ------------- public lifecycle API -------------
+    # ------------- lifecycle -------------
 
     def submit(self, req: Request):
-        self._validate(req)
+        if req.parent_rid is not None and req.parent_rid not in self.retained:
+            raise ValueError(
+                f"fork parent {req.parent_rid} is not retained (submit the "
+                "parent with retain=True and let it finish first)")
         req.t_submit = time.perf_counter()
         req.status = "queued"
         self.obs.metrics.counter("requests_submitted_total").inc()
         self.obs.lifecycle.enqueued(req.rid, t=req.t_submit)
-        self._enqueue(req)
+        mq = self.scfg.max_queued
+        if mq is not None and len(self.sched) >= mq:
+            # overload shedding at the door: better an immediate, explicit
+            # rejection than an unbounded queue nobody drains in time
+            self.obs.metrics.counter("degradations_total", rung="shed").inc()
+            self._finalize(req, "rejected",
+                           detail=f"queue full (max_queued={mq})")
+            return
+        self.sched.push(req)
 
     def step(self) -> bool:
-        """One event-loop iteration: admit what fits, run one batched decode
-        step if anything is active.  Returns True while work remains, so
-        callers can drive the engine open-loop (`while eng.step(): ...`) and
-        interleave submits/aborts between steps."""
-        raise NotImplementedError
+        """One engine step.  Its ``serve.step`` span holds a span for each
+        part (admit, headroom, prefetch, then the decode step's prepare,
+        dispatch, sync, account and commit) and, as args, the step number,
+        the rows decoded, how many of them fed a prompt token
+        (``tail_rows``), the tokens prefilled, the K/V positions one
+        attention layer read (``kv_tokens``) and the K/V pages in use
+        (``kv_pages``), and whether anything compiled.  Returns True while
+        work remains, so callers can drive the engine open-loop
+        (``while eng.step(): ...``) and interleave submits/aborts."""
+        span = self.obs.span
+        with span("serve.step", cat="step") as st:
+            c0 = self.obs.recompiles.n_events
+            self._step_prefilled = rows = tail_rows = 0
+            self._step_kv = (0, 0)
+            if self.faults is not None:
+                self.faults.set_step(self.step_count)
+            with span("serve.admit"):
+                if self.scfg.request_timeout_s is not None:
+                    self._expire_queued()
+                admitted = self._admit()
+            if self.active:
+                with span("serve.headroom"):
+                    self._ensure_headroom()
+            if self.active:
+                # stage prefetches *before* dispatching decode: the
+                # host->device copies ride JAX's async dispatch behind the
+                # decode kernels, so the next admission window's data lands
+                # while this step runs
+                with span("serve.prefetch"):
+                    self._issue_prefetches()
+                rows = len(self.active)
+                tail_rows = sum(1 for a in self.active.values() if a.pending)
+                self._decode_step()
+            elif self.sched and not admitted:
+                # queue non-empty but nothing fits and nothing runs: shed
+                # the head loudly rather than spinning (a request whose
+                # admission can *never* be satisfied would otherwise wedge
+                # the engine)
+                self._drop_queued(
+                    self.sched.peek(), "rejected",
+                    detail="cannot admit with the pool idle (request does "
+                           "not fit the page budget)")
+            st.set(step=self.step_count, rows=rows, tail_rows=tail_rows,
+                   prefill_tokens=self._step_prefilled,
+                   kv_tokens=self._step_kv[0], kv_pages=self._step_kv[1],
+                   compiled=self.obs.recompiles.n_events > c0)
+        return self.has_work()
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drain: step until queue + batch are empty; returns terminal
@@ -277,539 +356,9 @@ class _EngineCore:
         self._sanitize_teardown()
         return self.done
 
-    def _sanitize_teardown(self) -> None:
-        """Shadow-ledger leak check after a full drain (REPRO_SANITIZE=1).
-        Paged engines override; the default engine has no page ledger."""
-
-    def _break_stall(self) -> None:
-        """Called by ``run()`` after consecutive no-progress steps.  The
-        fixed-slot engine cannot stall (a free slot always admits, an
-        occupied slot always decodes), so the default sheds every queued
-        request defensively; the paged engine overrides with a targeted
-        ``rejected`` drop of the unadmittable head."""
-        for r in list(self.pending_requests()):
-            if r.status == "queued":
-                self._abort_impl(r.rid)
-
-    def abort(self, rid: int) -> bool:
-        """Cancel a request at any lifecycle point: waiting, mid-decode, or
-        spilled.  Frees its slot/pages immediately; the request lands in
-        ``done`` with status ``aborted`` (tokens already streamed remain in
-        ``output``).  Returns False if ``rid`` is unknown or terminal."""
-        return self._abort_impl(rid)
-
-    def has_work(self) -> bool:
-        raise NotImplementedError
-
-    def pending_requests(self) -> List[Request]:
-        """Requests submitted but not yet terminal (running, waiting, or
-        spilled)."""
-        raise NotImplementedError
-
-    def stats(self) -> Dict[str, float]:
-        """Always the full key schema -- zeros before anything finishes.
-
-        The dict is a *view over the obs metrics registry*: counts read
-        the counters the lifecycle hooks incremented, percentiles read the
-        registry histograms (``ttft_s``, ``step_s`` split by compile tag,
-        ``tok_latency_s``).  Step latency is additionally reported with
-        compile steps excluded (``*_step_nocompile_s``) so steady-state
-        latency separates from compilation stalls, and ``recompiles``
-        counts every fresh XLA trace the watcher saw.
-        """
-        m = self.obs.metrics
-        pending = self.pending_requests()
-        n_active = sum(1 for r in pending if r.status == "running")
-        n_queued = sum(1 for r in pending if r.status == "queued")
-        m.gauge("active_requests").set(n_active)
-        m.gauge("queued_requests").set(n_queued)
-        out: Dict[str, float] = {
-            "tokens": m.value("tokens_total"),
-            "wall_s": 0.0, "tokens_per_s": 0.0,
-            "prefill_tokens": m.value("prefill_tokens_total"),
-            "requests_done": m.value("requests_total", status="done"),
-            "requests_aborted": m.value("requests_total", status="aborted"),
-            "requests_truncated": m.value("requests_total",
-                                          status="truncated"),
-            "requests_failed": m.value("requests_total", status="failed"),
-            "requests_rejected": m.value("requests_total",
-                                         status="rejected"),
-            "active_requests": float(n_active),
-            "queued_requests": float(n_queued),
-        }
-        timed = [r for r in self.done if r.t_done > 0]
-        if timed:
-            t0 = min(r.t_submit for r in timed)
-            t1 = max(r.t_done for r in timed)
-            out["wall_s"] = t1 - t0
-            out["tokens_per_s"] = out["tokens"] / max(t1 - t0, 1e-9)
-        ttft = m.histogram("ttft_s")
-        out["mean_ttft_s"] = ttft.mean
-        out["p50_ttft_s"] = ttft.percentile(50)
-        out["p99_ttft_s"] = ttft.percentile(99)
-        steps_all = m.family_samples("step_s")
-        out["p50_step_s"] = (float(np.percentile(steps_all, 50))
-                             if steps_all else 0.0)
-        out["p99_step_s"] = (float(np.percentile(steps_all, 99))
-                             if steps_all else 0.0)
-        steady = m.histogram("step_s", compile="false")
-        out["p50_step_nocompile_s"] = steady.percentile(50)
-        out["p99_step_nocompile_s"] = steady.percentile(99)
-        out["compile_steps"] = float(
-            m.histogram("step_s", compile="true").count)
-        tok = m.histogram("tok_latency_s")
-        out["p50_tok_latency_s"] = tok.percentile(50)
-        out["p99_tok_latency_s"] = tok.percentile(99)
-        out["recompiles"] = float(self.obs.recompiles.n_events)
-        # speculation accounting is schema-stable: zeros when speculation is
-        # off (or on engines without it) so downstream consumers never key-miss
-        proposed = m.value("spec_proposed_tokens_total")
-        accepted = m.value("spec_accepted_tokens_total")
-        steps = m.value("spec_verify_steps_total")
-        out["proposed_tokens"] = proposed
-        out["accepted_tokens"] = accepted
-        out["acceptance_rate"] = accepted / proposed if proposed else 0.0
-        # each verify row-step emits the accepted drafts plus one token the
-        # target model produced itself, so the floor is 1.0, not 0.0
-        out["accepted_tokens_per_step"] = ((accepted + steps) / steps
-                                           if steps else 0.0)
-        out.update(self._traffic.stats())
-        return out
-
-    # ------------- subclass hooks -------------
-
-    def _validate(self, req: Request):
-        if req.parent_rid is not None:
-            raise ValueError(
-                f"{type(self).__name__} does not support fork/sessions "
-                "(copy-on-write prefix sharing needs the paged pool)")
-        if req.retain:
-            raise ValueError(
-                f"{type(self).__name__} cannot retain finished requests "
-                "(page refcounts need the paged pool)")
-
-    def _enqueue(self, req: Request):
-        raise NotImplementedError
-
-    def _abort_impl(self, rid: int) -> bool:
-        raise NotImplementedError
-
-    def _finalize(self, req: Request, status: str,
-                  detail: Optional[str] = None):
-        req.status = status
-        if detail is not None:
-            req.detail = detail
-        req.truncated = status == "truncated"
-        req.t_done = time.perf_counter()
-        self.done.append(req)
-        m = self.obs.metrics
-        m.counter("requests_total", status=status).inc()
-        m.counter("tokens_total").inc(len(req.output))
-        self.obs.lifecycle.finish(req.rid, status,
-                                  n_tokens=len(req.output), t=req.t_done)
-
-    def _count_prefill(self, n: int):
-        """Fresh-context tokens ingested (prefill + streamed tails)."""
-        self.prefill_tokens += int(n)
-        self.obs.metrics.counter("prefill_tokens_total").inc(int(n))
-
-    def _record_step(self, dt: float, compiled: bool):
-        """Shared per-step bookkeeping: the step-time series with its
-        compile tag and the ``step_s`` histogram split by tag (the step's
-        trace event is its ``serve.step`` span)."""
-        self.step_times.append(dt)
-        self.step_compiled.append(compiled)
-        if self.watchdog is not None:
-            self.watchdog.observe(self.step_count, dt)
-        self.obs.metrics.histogram(
-            "step_s", compile="true" if compiled else "false").observe(dt)
-
-
-# ===========================================================================
-# Fixed-slot engine
-# ===========================================================================
-
-
-class ServingEngine(_EngineCore):
-    backend = "slots"
-
-    def __init__(self, params, cfg: ModelConfig, ecfg: EngineConfig,
-                 mesh_axes=None, obs: Optional[Observability] = None):
-        assert not cfg.encoder_only
-        super().__init__(cfg, seed=ecfg.seed, obs=obs)
-        self.params = params
-        self.ecfg = ecfg
-        self.mesh_axes = mesh_axes
-        B = ecfg.slots
-        self.caches = M.init_decode_caches(cfg, B, ecfg.cache_capacity)
-        # host-side mirror of per-slot lengths: the engine is the writer of
-        # record, so keeping it in numpy makes the step loop sync-free --
-        # it streams host->device with the decode call instead of being
-        # read back device->host every step (JH101)
-        self.lengths = np.zeros((B,), np.int32)
-        self.cur_tokens = jnp.zeros((B,), jnp.int32)
-        self.active = np.zeros((B,), bool)
-        self.slot_req: List[Optional[Request]] = [None] * B
-        self.queue: List[Request] = []
-        self._traffic = _OpTrafficMeter(cfg, metrics=self.obs.metrics)
-
-        # donate the cache tree: the engine drops its reference on return,
-        # so XLA appends the token in place instead of copying every cache
-        # leaf every step (same treatment as the paged pool's donated pools)
-        self._decode = self.obs.wrap_jit(
-            jax.jit(partial(M.decode_step, cfg=cfg, mesh_axes=mesh_axes),
-                    donate_argnames=("caches",)),
-            "engine.decode")
-        self._prefill = self.obs.wrap_jit(_prefill_program(cfg, mesh_axes),
-                                          "engine.prefill")
-
-    # ------------- lifecycle -------------
-
-    def _enqueue(self, req: Request):
-        self.queue.append(req)
-
-    def step(self) -> bool:
-        with self.obs.span("serve.step", cat="step") as st:
-            c0 = self.obs.recompiles.n_events
-            self._admit()
-            rows = int(self.active.sum())
-            if rows:
-                self._decode_step()
-            st.set(step=self.step_count, rows=rows,
-                   compiled=self.obs.recompiles.n_events > c0)
-        return self.has_work()
-
-    def has_work(self) -> bool:
-        return bool(self.queue) or bool(self.active.any())
-
-    def pending_requests(self) -> List[Request]:
-        return ([r for r in self.slot_req if r is not None]
-                + list(self.queue))
-
-    def _abort_impl(self, rid: int) -> bool:
-        for i, r in enumerate(self.queue):
-            if r.rid == rid:
-                self.queue.pop(i)
-                self._finalize(r, "aborted")
-                return True
-        for slot, r in enumerate(self.slot_req):
-            if r is not None and r.rid == rid:
-                # free the slot immediately; the stale cache row is simply
-                # overwritten by the next admission
-                self.slot_req[slot] = None
-                self.active[slot] = False
-                self._finalize(r, "aborted")
-                return True
-        return False
-
-    # ------------- internals -------------
-
-    def _admit(self):
-        while self.queue and not self.active.all():
-            slot = int(np.flatnonzero(~self.active)[0])
-            req = self.queue.pop(0)
-            with self.obs.span("serve.prefill", cat="prefill", rid=req.rid,
-                               tokens=len(req.prompt), tail=0):
-                self._prefill_into(slot, req)
-
-    def _prefill_into(self, slot: int, req: Request):
-        t_p0 = time.perf_counter()
-        self.obs.lifecycle.phase(req.rid, "prefill", t=t_p0)
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]       # (1, S)
-        S = prompt.shape[1]
-        self._count_prefill(S)
-        batch = {"tokens": prompt, "targets": prompt}
-        logits, row_caches = self._prefill(self.params, batch=batch)
-        # re-capacity the row cache to the pool capacity (explicit time axis)
-        row_caches = AC.recapacity(row_caches, self.ecfg.cache_capacity)
-        # NB: zip leaves rather than tree.map -- QuantizedTensor aux data
-        # embeds its logical shape, which differs between the B=1 prefill
-        # row and the B=slots pool (the structures are otherwise parallel)
-        pool_leaves, pool_def = jax.tree_util.tree_flatten(self.caches)
-        row_leaves = jax.tree_util.tree_leaves(row_caches)
-        assert len(pool_leaves) == len(row_leaves)
-        self.caches = jax.tree_util.tree_unflatten(
-            pool_def, [_row_insert(p, r, slot)
-                       for p, r in zip(pool_leaves, row_leaves)])
-        self._key, toks = _sample_tokens(self._key, logits, self.ecfg.sampling)
-        tok = int(toks[0])
-        req.t_first = time.perf_counter()
-        self.obs.lifecycle.first_token(req.rid, t=req.t_first)
-        req.output.append(tok)
-        hit_eos = req.eos_id is not None and tok == req.eos_id
-        if len(req.output) >= req.max_new_tokens or hit_eos:
-            self._finalize(req, "done")
-            return                      # never occupies a decode slot
-        self.cur_tokens = self.cur_tokens.at[slot].set(tok)
-        self.lengths[slot] = S
-        self.active[slot] = True
-        self.slot_req[slot] = req
-        req.status = "running"
-        self.obs.lifecycle.phase(req.rid, "decode")
-        # sync pool cache lengths for this row
-        self.caches = _set_row_lengths(self.caches, slot, S)
-
-    def _decode_step(self):
-        self.step_count += 1
-        c0 = self.obs.recompiles.n_events
-        t0 = time.perf_counter()
-        logits, self.caches = self._decode(
-            self.params, tokens=self.cur_tokens, caches=self.caches,
-            lengths=jnp.asarray(self.lengths), seed=jnp.int32(self.step_count))
-        self._key, toks = _sample_tokens(self._key, logits, self.ecfg.sampling)
-        self.lengths = self.lengths + self.active.astype(np.int32)
-        self.cur_tokens = toks
-        # the sampled tokens are the step's single device->host sync; the
-        # lengths ledger lives host-side (see __init__) and needs none
-        toks_np = np.asarray(toks)
-        lengths_np = self.lengths
-        self._record_step(time.perf_counter() - t0,
-                          compiled=self.obs.recompiles.n_events > c0)
-        self._traffic.account_step(lengths_np[self.active])
-        for slot in np.flatnonzero(self.active):
-            req = self.slot_req[slot]
-            req.output.append(int(toks_np[slot]))
-            hit_eos = req.eos_id is not None and req.output[-1] == req.eos_id
-            done = len(req.output) >= req.max_new_tokens or hit_eos
-            full = int(lengths_np[slot]) + 1 >= self.ecfg.cache_capacity
-            if done or full:
-                self.slot_req[slot] = None
-                self.active[slot] = False
-                # a request stopped only by slot capacity was clipped, not
-                # completed -- same contract as the paged pool's truncation
-                self._finalize(req, "done" if done else "truncated")
-
-
-def _set_row_lengths(caches, slot: int, length: int):
-    def fix(c):
-        if isinstance(c, AC.KVCache):
-            # lengths may be group-stacked (G, B) or flat (B,)
-            if c.lengths.ndim == 2:
-                nl = c.lengths.at[:, slot].set(length)
-            else:
-                nl = c.lengths.at[slot].set(length)
-            return AC.KVCache(c.k, c.v, nl, c.fmt, c.v_width, c.time_axis)
-        return c
-    return jax.tree.map(fix, caches,
-                        is_leaf=lambda x: isinstance(x, AC.KVCache))
-
-
-# ===========================================================================
-# Paged engine
-# ===========================================================================
-
-from repro.serving.memory import (PagedStatePool,  # noqa: E402,F401
-                                  SpilledRequest, TieredStatePool)
-
-
-@dataclasses.dataclass(frozen=True)
-class PagedEngineConfig:
-    max_decode_batch: int = 4         # rows in the jitted decode step
-    n_pages: Optional[int] = 33       # 128-token pages (incl. 1 scratch)
-    n_slabs: int = 9                  # state slabs (incl. 1 scratch)
-    byte_budget: Optional[int] = None  # alternative to n_pages
-    prefill_chunk: int = 128          # longest full-sequence prefill; the
-                                      # prompt tail streams through decode
-    # opt-in prefill length bucketing (JH103): when set, the prefill jit
-    # compiles one executable per *bucket* instead of one per distinct
-    # prompt length.  A prompt that a bucket up to prefill_chunk covers is
-    # prefilled whole at the smallest such bucket, the padding masked
-    # (where the model's layers can mask it: M.masked_prefill_ok); a longer
-    # one prefills the largest bucket that fits and streams the rest
-    # through the decode batch.  Off by default: streamed tokens take
-    # other stochastic-rounding draws than prefilled ones, so mx8 token
-    # streams differ from the unbucketed engine (both are valid).
-    prefill_buckets: Optional[Tuple[int, ...]] = None
-    sampling: SamplingConfig = SamplingConfig()
-    scheduler: SchedulerConfig = SchedulerConfig()
-    seed: int = 0
-    # --- tiered memory hierarchy (serving/memory/tiered) ---
-    prefix_cache: bool = False        # radix prefix store: automatic
-                                      # cross-request CoW prefix sharing
-    prefix_store_pages: int = 64      # store capacity (LRU-evicted)
-    host_tier_bytes: Optional[int] = None  # host tier budget (None = unmetered)
-    prefetch_window: int = 2          # scheduler lookahead for async
-                                      # spill-resume / prefix prefetch
-    # --- resilience / fault injection (serving/faults, serving/resilience) ---
-    fault_plan: Optional[str] = None  # fault spec string; the REPRO_FAULTS
-                                      # env var applies when unset
-    nan_guard: Optional[bool] = None  # post-step non-finite-logits guard;
-                                      # None = enabled iff faults are active
-                                      # (the check costs one device sync)
-    max_queued: Optional[int] = None  # admission control: submits beyond
-                                      # this queue depth are ``rejected``
-    request_timeout_s: Optional[float] = None  # queued longer -> ``rejected``
-    step_budget_s: Optional[float] = None      # watchdog wall-clock budget
-    # --- speculative decoding (serving/spec) ---
-    spec: Optional[str] = None        # draft source: None (off), "ngram"
-                                      # (self-drafting) or "model:<arch>"
-                                      # (small-model drafting)
-    spec_k: int = 3                   # max drafts per row; the verify step
-                                      # always compiles at spec_k+1 positions
-    spec_window: int = 8              # acceptance window of the k-controller
-
-
-@dataclasses.dataclass
-class _Active:
-    req: Request
-    length: int                       # cached positions so far
-    pending: List[int]                # prompt tokens not yet consumed
-    cur_token: int                    # next token to feed once prompt is done
-    replayed: bool = False            # corruption-recovery re-prefill: the
-                                      # "prompt" includes generated tokens,
-                                      # so prefix-store inserts are skipped
-
-
-class PagedServingEngine(_EngineCore):
-    """Continuous batching over the paged, bank-aware state/KV pool."""
-
-    backend = "paged"
-
-    def __init__(self, params, cfg: ModelConfig, pcfg: PagedEngineConfig,
-                 mesh_axes=None, obs: Optional[Observability] = None):
-        assert not cfg.encoder_only
-        super().__init__(cfg, seed=pcfg.seed, obs=obs)
-        self.params = params
-        self.pcfg = pcfg
-        self.pool = TieredStatePool(
-            cfg, n_pages=None if pcfg.byte_budget is not None else pcfg.n_pages,
-            n_slabs=pcfg.n_slabs, byte_budget=pcfg.byte_budget,
-            mesh_axes=mesh_axes, host_tier_bytes=pcfg.host_tier_bytes,
-            prefix_cache=pcfg.prefix_cache,
-            prefix_store_pages=pcfg.prefix_store_pages)
-        self.pool.attach_obs(self.obs)
-        self.sched = Scheduler(pcfg.scheduler)
-        self.sched.obs = self.obs
-        self.active: Dict[int, _Active] = {}
-        self.rows: List[Optional[int]] = [None] * pcfg.max_decode_batch
-        self.spilled: Dict[int, Tuple[SpilledRequest, List[int], int]] = {}
-        #: finished-but-pinned requests: fork parents for sessions /
-        #: N-way continuations; release_retained() frees them
-        self.retained: Dict[int, _Active] = {}
-        # account the block-table-native ops this engine actually dispatches
-        self._traffic = _OpTrafficMeter(cfg, layout="paged",
-                                        metrics=self.obs.metrics)
-        self.preemptions = 0
-        self._occ: List[float] = []
-        self._frag: List[float] = []
-        self._step_prefilled = 0          # full-sequence prefill tokens
-                                          # of the step under way
-        # --- resilience wiring (all None/empty => zero overhead) ---
-        self.faults = FaultPlan.maybe(pcfg.fault_plan, seed=pcfg.seed)
-        self.pool.faults = self.faults
-        self.watchdog = StepWatchdog(pcfg.step_budget_s, obs=self.obs)
-        self._nan_guard = (pcfg.nan_guard if pcfg.nan_guard is not None
-                           else self.faults is not None)
-        #: rid -> full replay token stream (prompt + generated) for the
-        #: bounded re-prefill after a detected spill-blob corruption
-        self._replay: Dict[int, List[int]] = {}
-        self._reprefills: Dict[int, int] = {}
-        #: rid -> consecutive failed admission attempts (degradation rung)
-        self._admit_fails: Dict[int, int] = {}
-        self._prefill_jit = self.obs.wrap_jit(
-            _prefill_program(cfg, mesh_axes), "engine.prefill")
-        #: bucketed prompts are prefilled padded, the padding masked
-        self._masked = bool(pcfg.prefill_buckets) and M.masked_prefill_ok(cfg)
-        max_chunk_pages = pages_for(pcfg.prefill_chunk)
-        assert max_chunk_pages <= self.pool.usable_pages, \
-            "prefill_chunk does not fit the page pool"
-        # --- speculative decoding (serving/spec) ---
-        self.draft = None
-        self.kctl = None
-        if pcfg.spec is not None:
-            from repro.serving.spec import (KController, ModelDraft,
-                                            NGramDraft)
-            assert pcfg.spec_k >= 1, "spec_k must be at least 1"
-            if pcfg.spec == "ngram":
-                self.draft = NGramDraft()
-            elif pcfg.spec.startswith("model:"):
-                from repro.configs import get_smoke_config
-                dcfg = get_smoke_config(pcfg.spec.split(":", 1)[1]).with_(
-                    state_quant=cfg.state_quant)
-                # the draft pool is deliberately NOT obs-wrapped: its jits
-                # are warmup-only per draft request and must not count
-                # against the target engine's decode recompile budget
-                self.draft = ModelDraft(
-                    dcfg, max_requests=pcfg.max_decode_batch + 1,
-                    seed=pcfg.seed)
-            else:
-                raise ValueError(
-                    f"unknown spec draft source {pcfg.spec!r} "
-                    "(expected 'ngram' or 'model:<arch>')")
-            self.kctl = KController(pcfg.spec_k, window=pcfg.spec_window)
-            # per-position seeds inside the verify step are spec_seed + i,
-            # so advance by n per step to keep the streams non-overlapping
-            self._spec_seed = 0
-
-    # ------------- lifecycle -------------
-
-    def _validate(self, req: Request):
-        if req.parent_rid is not None and req.parent_rid not in self.retained:
-            raise ValueError(
-                f"fork parent {req.parent_rid} is not retained (submit the "
-                "parent with retain=True and let it finish first)")
-
-    def _enqueue(self, req: Request):
-        mq = self.pcfg.max_queued
-        if mq is not None and len(self.sched) >= mq:
-            # overload shedding at the door: better an immediate, explicit
-            # rejection than an unbounded queue nobody drains in time
-            self.obs.metrics.counter("degradations_total", rung="shed").inc()
-            self._finalize(req, "rejected",
-                           detail=f"queue full (max_queued={mq})")
-            return
-        self.sched.push(req)
-
-    def step(self) -> bool:
-        """One engine step.  Its ``serve.step`` span holds a span for each
-        part (admit, headroom, prefetch, then the decode step's prepare,
-        dispatch, sync, account and commit) and, as args, the step number,
-        the rows decoded, how many of them fed a prompt token
-        (``tail_rows``), the tokens prefilled, the K/V positions one
-        attention layer read (``kv_tokens``) and the K/V pages in use
-        (``kv_pages``), and whether anything compiled."""
-        span = self.obs.span
-        with span("serve.step", cat="step") as st:
-            c0 = self.obs.recompiles.n_events
-            self._step_prefilled = rows = tail_rows = 0
-            self._step_kv = (0, 0)
-            if self.faults is not None:
-                self.faults.set_step(self.step_count)
-            with span("serve.admit"):
-                if self.pcfg.request_timeout_s is not None:
-                    self._expire_queued()
-                admitted = self._admit()
-            if self.active:
-                with span("serve.headroom"):
-                    self._ensure_headroom()
-            if self.active:
-                # stage prefetches *before* dispatching decode: the
-                # host->device copies ride JAX's async dispatch behind the
-                # decode kernels, so the next admission window's data lands
-                # while this step runs
-                with span("serve.prefetch"):
-                    self._issue_prefetches()
-                rows = len(self.active)
-                tail_rows = sum(1 for a in self.active.values() if a.pending)
-                self._decode_step()
-            elif self.sched and not admitted:
-                # queue non-empty but nothing fits and nothing runs: shed
-                # the head loudly rather than spinning (a request whose
-                # admission can *never* be satisfied would otherwise wedge
-                # the engine)
-                self._drop_queued(
-                    self.sched.peek(), "rejected",
-                    detail="cannot admit with the pool idle (request does "
-                           "not fit the page budget)")
-            st.set(step=self.step_count, rows=rows, tail_rows=tail_rows,
-                   prefill_tokens=self._step_prefilled,
-                   kv_tokens=self._step_kv[0], kv_pages=self._step_kv[1],
-                   compiled=self.obs.recompiles.n_events > c0)
-        return self.has_work()
-
     def _expire_queued(self) -> None:
         now = time.perf_counter()
-        budget = self.pcfg.request_timeout_s
+        budget = self.scfg.request_timeout_s
         for req in self.sched.requests():
             if req.t_submit and now - req.t_submit > budget:
                 self.obs.metrics.counter("request_timeouts_total").inc()
@@ -834,10 +383,16 @@ class PagedServingEngine(_EngineCore):
         return bool(self.sched) or bool(self.active)
 
     def pending_requests(self) -> List[Request]:
+        """Requests submitted but not yet terminal (running, waiting, or
+        spilled)."""
         return ([a.req for a in self.active.values()]
                 + self.sched.requests())
 
-    def _abort_impl(self, rid: int) -> bool:
+    def abort(self, rid: int) -> bool:
+        """Cancel a request at any lifecycle point: waiting, mid-decode, or
+        spilled.  Frees its pages immediately; the request lands in
+        ``done`` with status ``aborted`` (tokens already streamed remain in
+        ``output``).  Returns False if ``rid`` is unknown or terminal."""
         if rid in self.active:
             a = self.active.pop(rid)
             self._free_row(rid)
@@ -898,12 +453,12 @@ class PagedServingEngine(_EngineCore):
             # for the first streamed tail token
             return sum(1 for n in nodes if not n.resident) + 1
         n = len(req.prompt)
-        return pages_for(max(min(n, self.pcfg.prefill_chunk),
+        return pages_for(max(min(n, self.scfg.prefill_chunk),
                              self._prefill_lens(n)[1]))
 
     def _admit(self) -> bool:
         admitted = False
-        while len(self.active) < self.pcfg.max_decode_batch and self.sched:
+        while len(self.active) < self.scfg.batch and self.sched:
             head = self.sched.peek()
             need = self._admission_need(head)
             if not self.pool.can_admit(need):
@@ -1058,10 +613,10 @@ class PagedServingEngine(_EngineCore):
         mask padding, the largest bucket that fits, unpadded (prompts
         shorter than every bucket keep their exact length).  The rest of
         the prompt streams through the decode batch."""
-        s0 = min(n, self.pcfg.prefill_chunk)
-        buckets = self.pcfg.prefill_buckets or ()
+        s0 = min(n, self.scfg.prefill_chunk)
+        buckets = self.scfg.prefill_buckets or ()
         if self._masked:
-            cover = [b for b in buckets if s0 <= b <= self.pcfg.prefill_chunk]
+            cover = [b for b in buckets if s0 <= b <= self.scfg.prefill_chunk]
             if cover:
                 return s0, min(cover)
         fits = [b for b in buckets if 0 < b <= s0]
@@ -1133,7 +688,7 @@ class PagedServingEngine(_EngineCore):
                 self.pool.store_insert(req.rid, req.prompt[:s0])
             if not a.pending:
                 self._key, toks = _sample_tokens(self._key, logits,
-                                                 self.pcfg.sampling)
+                                                 self.scfg.sampling)
                 tok = int(toks[0])
                 if not req.t_first:
                     req.t_first = time.perf_counter()
@@ -1180,7 +735,7 @@ class PagedServingEngine(_EngineCore):
         window, dispatch spilled-blob copies into staging pages and promote
         demoted prefix-store nodes *now*, so the copies overlap the decode
         step dispatched right after and their eventual admission is O(1)."""
-        window = self.pcfg.prefetch_window
+        window = self.scfg.prefetch_window
         if window <= 0:
             return
         reserve = max(1, len(self.active))
@@ -1262,7 +817,7 @@ class PagedServingEngine(_EngineCore):
             a = self.active.get(rid)
             if a is None:
                 continue
-            span = (self.pcfg.spec_k
+            span = (self.scfg.spec_k
                     if self.draft is not None and not a.pending else 0)
             needed = (a.length + span) // PAGE_TOKENS + 1
             while needed > len(self.pool.page_table[rid]):
@@ -1286,7 +841,7 @@ class PagedServingEngine(_EngineCore):
         self.step_count += 1
         span = self.obs.span
         with span("serve.prepare"):
-            B = self.pcfg.max_decode_batch
+            B = self.scfg.batch
             tokens = np.zeros((B,), np.int32)
             lengths = np.zeros((B,), np.int32)
             for row, rid in enumerate(self.rows):
@@ -1306,7 +861,7 @@ class PagedServingEngine(_EngineCore):
             if self.faults is not None:
                 logits = self._inject_nan(logits)
             self._key, toks = _sample_tokens(self._key, logits,
-                                             self.pcfg.sampling)
+                                             self.scfg.sampling)
         with span("serve.sync"):
             toks_np = np.asarray(toks)
             bad_rows = self._scan_nonfinite(logits) if self._nan_guard \
@@ -1433,8 +988,8 @@ class PagedServingEngine(_EngineCore):
         """
         self.step_count += 1
         span = self.obs.span
-        B = self.pcfg.max_decode_batch
-        n = self.pcfg.spec_k + 1
+        B = self.scfg.batch
+        n = self.scfg.spec_k + 1
         with span("serve.prepare"):
             tokens = np.zeros((B, n), np.int32)
             lengths = np.zeros((B,), np.int32)
@@ -1451,7 +1006,7 @@ class PagedServingEngine(_EngineCore):
                 # request's remaining token allowance, so emitted tokens
                 # never need a post-hoc cut that would desync length from
                 # committed state
-                budget = min(self.kctl.k_for(rid), self.pcfg.spec_k,
+                budget = min(self.kctl.k_for(rid), self.scfg.spec_k,
                              a.req.max_new_tokens - len(a.req.output) - 1)
                 d = []
                 if budget > 0:
@@ -1480,13 +1035,13 @@ class PagedServingEngine(_EngineCore):
                 logits = self._inject_nan(logits)
             bad_rows = self._scan_nonfinite(logits) if self._nan_guard \
                 else ()
-            greedy = self.pcfg.sampling.temperature <= 0.0
+            greedy = self.scfg.sampling.temperature <= 0.0
             if greedy:
                 # same device op as the sampler's greedy branch, so ties
                 # break identically to non-speculative decoding
                 g = np.asarray(jnp.argmax(logits, axis=-1))
             else:
-                probs = np.asarray(filtered_probs(logits, self.pcfg.sampling))
+                probs = np.asarray(filtered_probs(logits, self.scfg.sampling))
         with span("serve.commit"):
             sel = np.zeros((B,), np.int32)
             emits: Dict[int, List[int]] = {}
@@ -1504,7 +1059,7 @@ class PagedServingEngine(_EngineCore):
                     emit = [int(g[row, j]) for j in range(m + 1)]
                 else:
                     rng = np.random.default_rng(
-                        (self.pcfg.seed, self.step_count, row))
+                        (self.scfg.seed, self.step_count, row))
                     emit = []
                     for j, t in enumerate(d):
                         pj = probs[row, j]
@@ -1557,7 +1112,7 @@ class PagedServingEngine(_EngineCore):
                         continue
                     tok = (int(g[row, 0]) if greedy else int(
                         np.random.default_rng(
-                            (self.pcfg.seed, self.step_count, row)
+                            (self.scfg.seed, self.step_count, row)
                         ).choice(probs.shape[-1],
                                  p=probs[row, 0] / probs[row, 0].sum())))
                     if not a.req.t_first:
@@ -1636,10 +1191,9 @@ class PagedServingEngine(_EngineCore):
     def _break_stall(self) -> None:
         """No-progress steps in ``run()``: shed the unadmittable queue head
         with a clear reason instead of spinning forever."""
-        head = self.sched.peek() if self.sched else None
-        if head is None:
-            super()._break_stall()
-            return
+        if not self.sched:
+            return            # every queued request is in the scheduler
+        head = self.sched.peek()
         self.obs.metrics.counter("stalls_broken_total").inc()
         self._drop_queued(
             head, "rejected",
@@ -1657,11 +1211,109 @@ class PagedServingEngine(_EngineCore):
                 self.draft.sanitizer_check_leaks(
                     what=f"drained draft pool (step {self.step_count})")
 
+    # ------------- bookkeeping -------------
+
+    def _finalize(self, req: Request, status: str,
+                  detail: Optional[str] = None):
+        req.status = status
+        if detail is not None:
+            req.detail = detail
+        req.truncated = status == "truncated"
+        req.t_done = time.perf_counter()
+        self.done.append(req)
+        m = self.obs.metrics
+        m.counter("requests_total", status=status).inc()
+        m.counter("tokens_total").inc(len(req.output))
+        self.obs.lifecycle.finish(req.rid, status,
+                                  n_tokens=len(req.output), t=req.t_done)
+
+    def _count_prefill(self, n: int):
+        """Fresh-context tokens ingested (prefill + streamed tails)."""
+        self.prefill_tokens += int(n)
+        self.obs.metrics.counter("prefill_tokens_total").inc(int(n))
+
+    def _record_step(self, dt: float, compiled: bool):
+        """Per-step bookkeeping: the step-time series with its compile tag
+        and the ``step_s`` histogram split by tag (the step's trace event
+        is its ``serve.step`` span)."""
+        self.step_times.append(dt)
+        self.step_compiled.append(compiled)
+        self.watchdog.observe(self.step_count, dt)
+        self.obs.metrics.histogram(
+            "step_s", compile="true" if compiled else "false").observe(dt)
+
     # ------------- stats -------------
 
     def stats(self) -> Dict[str, float]:
-        out = super().stats()
+        """Always the full key schema -- zeros before anything finishes.
+
+        The dict is a *view over the obs metrics registry*: counts read
+        the counters the lifecycle hooks incremented, percentiles read the
+        registry histograms (``ttft_s``, ``step_s`` split by compile tag,
+        ``tok_latency_s``).  Step latency is additionally reported with
+        compile steps excluded (``*_step_nocompile_s``) so steady-state
+        latency separates from compilation stalls, and ``recompiles``
+        counts every fresh XLA trace the watcher saw.
+        """
+        m = self.obs.metrics
+        pending = self.pending_requests()
+        n_active = sum(1 for r in pending if r.status == "running")
+        n_queued = sum(1 for r in pending if r.status == "queued")
+        m.gauge("active_requests").set(n_active)
+        m.gauge("queued_requests").set(n_queued)
+        out: Dict[str, float] = {
+            "tokens": m.value("tokens_total"),
+            "wall_s": 0.0, "tokens_per_s": 0.0,
+            "prefill_tokens": m.value("prefill_tokens_total"),
+            "requests_done": m.value("requests_total", status="done"),
+            "requests_aborted": m.value("requests_total", status="aborted"),
+            "requests_truncated": m.value("requests_total",
+                                          status="truncated"),
+            "requests_failed": m.value("requests_total", status="failed"),
+            "requests_rejected": m.value("requests_total",
+                                         status="rejected"),
+            "active_requests": float(n_active),
+            "queued_requests": float(n_queued),
+        }
+        timed = [r for r in self.done if r.t_done > 0]
+        if timed:
+            t0 = min(r.t_submit for r in timed)
+            t1 = max(r.t_done for r in timed)
+            out["wall_s"] = t1 - t0
+            out["tokens_per_s"] = out["tokens"] / max(t1 - t0, 1e-9)
+        ttft = m.histogram("ttft_s")
+        out["mean_ttft_s"] = ttft.mean
+        out["p50_ttft_s"] = ttft.percentile(50)
+        out["p99_ttft_s"] = ttft.percentile(99)
+        steps_all = m.family_samples("step_s")
+        out["p50_step_s"] = (float(np.percentile(steps_all, 50))
+                             if steps_all else 0.0)
+        out["p99_step_s"] = (float(np.percentile(steps_all, 99))
+                             if steps_all else 0.0)
+        steady = m.histogram("step_s", compile="false")
+        out["p50_step_nocompile_s"] = steady.percentile(50)
+        out["p99_step_nocompile_s"] = steady.percentile(99)
+        out["compile_steps"] = float(
+            m.histogram("step_s", compile="true").count)
+        tok = m.histogram("tok_latency_s")
+        out["p50_tok_latency_s"] = tok.percentile(50)
+        out["p99_tok_latency_s"] = tok.percentile(99)
+        out["recompiles"] = float(self.obs.recompiles.n_events)
+        # speculation accounting is schema-stable: zeros when speculation is
+        # off so downstream consumers never key-miss
+        proposed = m.value("spec_proposed_tokens_total")
+        accepted = m.value("spec_accepted_tokens_total")
+        steps = m.value("spec_verify_steps_total")
+        out["proposed_tokens"] = proposed
+        out["accepted_tokens"] = accepted
+        out["acceptance_rate"] = accepted / proposed if proposed else 0.0
+        # each verify row-step emits the accepted drafts plus one token the
+        # target model produced itself, so the floor is 1.0, not 0.0
+        out["accepted_tokens_per_step"] = ((accepted + steps) / steps
+                                           if steps else 0.0)
+        out.update(self._traffic.stats())
         out.update({
+
             "preemptions": float(self.preemptions),
             "occupancy": float(np.mean(self._occ)) if self._occ else 0.0,
             "fragmentation": (float(np.mean(self._frag))

@@ -32,3 +32,33 @@ def subprocess_env(**extra):
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+def greedy_reference(params, cfg, prompt, n_new):
+    """One request's greedy tokens with no engine: the dense ``M.prefill``
+    + ``M.decode_step`` loop, its caches sized for the whole continuation.
+    The serving engine must reproduce them under any batching (fp32 state,
+    where stochastic-rounding seeds cannot matter)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import attention_cache as AC
+    from repro.models import model as M
+
+    S = len(prompt)
+    tokens = jnp.asarray(prompt, jnp.int32)[None]
+    logits, caches = jax.jit(functools.partial(M.prefill, cfg=cfg))(
+        params, batch={"tokens": tokens, "targets": tokens})
+    caches = AC.recapacity(caches, -(-(S + n_new) // 128) * 128)
+    lengths = jnp.full((1,), S, jnp.int32)
+    caches = M.set_cache_lengths(caches, lengths)
+    decode = jax.jit(functools.partial(M.decode_step, cfg=cfg))
+    out = [int(jnp.argmax(logits[0]))]
+    for step in range(n_new - 1):
+        tok = jnp.asarray([out[-1]], jnp.int32)
+        logits, caches = decode(params, tokens=tok, caches=caches,
+                                lengths=lengths + step, seed=step + 1)
+        out.append(int(jnp.argmax(logits[0])))
+    return out

@@ -327,7 +327,7 @@ def test_watched_function_is_transparent():
 
 
 # ---------------------------------------------------------------------------
-# engine integration (both backends)
+# engine integration
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -339,16 +339,22 @@ def tiny_fp32():
     return params, cfg
 
 
-def _mk(params, cfg, backend):
-    return Engine(params, cfg, ServeConfig(backend=backend, batch=2,
-                                           cache_capacity=128, n_pages=9,
-                                           n_slabs=5))
+@pytest.fixture(scope="module", params=("llama3.2-1b", "mamba2-2.7b"))
+def arch_fp32(request):
+    cfg = get_smoke_config(request.param).with_(
+        state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
+                                     backend="jnp"))
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    return params, cfg
 
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_engine_trace_valid_and_chains_complete(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend)
+def _mk(params, cfg):
+    return Engine(params, cfg, ServeConfig(batch=2, n_pages=9, n_slabs=5))
+
+
+def test_engine_trace_valid_and_chains_complete(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(0)
     hs = [eng.submit(rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                      max_new_tokens=3) for _ in range(3)]
@@ -356,9 +362,7 @@ def test_engine_trace_valid_and_chains_complete(tiny_fp32, backend):
     obj = eng.obs.tracer.to_chrome()
     assert validate_chrome_trace(obj) == []
     feats = trace_features(obj)
-    assert {"steps", "spans", "recompile"} <= feats
-    if backend == "paged":
-        assert "phases" in feats
+    assert {"steps", "spans", "recompile", "phases"} <= feats
     # every terminal request has a complete queued->terminal chain
     recs = eng.obs.lifecycle.terminal_records()
     assert len(recs) == 3
@@ -373,7 +377,7 @@ def test_engine_trace_valid_and_chains_complete(tiny_fp32, backend):
 
 def test_stats_is_registry_view(tiny_fp32):
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "slots")
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(1)
     eng.submit(rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                max_new_tokens=4)
@@ -396,7 +400,7 @@ def test_run_max_steps_interrupts_spans(tiny_fp32):
     open span closed with an explicit interrupted marker -- the exported
     trace has no dangling async spans."""
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "paged")
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(2)
     hs = [eng.submit(rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                      max_new_tokens=64) for _ in range(2)]
@@ -421,7 +425,7 @@ def test_run_max_steps_interrupts_spans(tiny_fp32):
 
 def test_prometheus_endpoint_smoke(tiny_fp32):
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "paged")
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(3)
     eng.submit(rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
                max_new_tokens=2)

@@ -10,8 +10,8 @@ from repro import ops as OPS
 from repro.configs import get_smoke_config
 from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
-from repro.serving.engine import (EngineConfig, PagedEngineConfig,
-                                  PagedServingEngine, Request, ServingEngine)
+from repro.serving.api import ServeConfig
+from repro.serving.engine import PagedServingEngine, Request
 from repro.serving.memory import PAGE_TOKENS, PagedStatePool, pages_for
 
 
@@ -162,38 +162,18 @@ def test_paged_attention_traffic_is_page_granular():
 # engine-level: donation, retraces, residual gather accounting
 # ---------------------------------------------------------------------------
 
-def test_slotted_engine_donation_no_retrace():
-    """donate_argnames on the slotted engine's decode jit must not retrace:
-    one compiled executable serves every step."""
-    cfg = get_smoke_config("llama3.2-1b").with_(
-        state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
-                                     backend="jnp"))
-    params = M.init_model(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(params, cfg, EngineConfig(slots=2,
-                                                  cache_capacity=128))
-    rng = np.random.default_rng(0)
-    for i in range(3):
-        eng.submit(Request(rid=i,
-                           prompt=rng.integers(0, cfg.vocab_size, 8
-                                               ).astype(np.int32),
-                           max_new_tokens=4))
-    done = eng.run()
-    assert len(done) == 3 and all(len(r.output) == 4 for r in done)
-    assert eng._decode._cache_size() == 1, "decode retraced"
-
-
 def test_paged_engine_decode_no_retrace():
-    """The paged engine's retrace pin, mirroring the slotted one: a
-    constant-shape workload (equal-length prompts in one page bucket, the
-    full decode batch, same shape as benchmarks/paged_smoke.py) must be
-    served by exactly one compiled decode executable.  The recompile
+    """The paged engine's retrace pin: a constant-shape workload
+    (equal-length prompts in one page bucket, the full decode batch, same
+    shape as benchmarks/paged_smoke.py) must be served by exactly one
+    compiled decode executable.  The recompile
     watcher must agree with the jit cache, and tag only warmup compiles."""
     cfg = get_smoke_config("llama3.2-1b").with_(
         state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
                                      backend="jnp"))
     params = M.init_model(jax.random.PRNGKey(0), cfg)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=4, n_pages=9, n_slabs=9, prefill_chunk=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=4, n_pages=9, n_slabs=9, prefill_chunk=128))
     rng = np.random.default_rng(0)
     for i in range(4):
         eng.submit(Request(rid=i,
@@ -220,8 +200,8 @@ def test_paged_engine_gather_bytes_only_at_the_edges():
         state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
                                      backend="jnp"))
     params = M.init_model(jax.random.PRNGKey(0), cfg)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=7, n_slabs=5))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=7, n_slabs=5))
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (9, 17)]
@@ -247,8 +227,8 @@ def test_paged_engine_spill_resume_accounts_gather_bytes(tmp_path):
         state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
                                      backend="jnp"))
     params = M.init_model(jax.random.PRNGKey(0), cfg)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=4, n_slabs=5, prefill_chunk=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=4, n_slabs=5, prefill_chunk=128))
     rng = np.random.default_rng(3)
     for i in range(2):
         eng.submit(Request(rid=i,

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from repro.configs import get_smoke_config
 from repro.core import attention_cache as AC
@@ -11,8 +12,8 @@ from repro.core import formats as F
 from repro.core import pimsim
 from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
-from repro.serving.engine import (EngineConfig, PagedEngineConfig,
-                                  PagedServingEngine, Request, ServingEngine)
+from repro.serving.api import ServeConfig
+from repro.serving.engine import PagedServingEngine, Request
 from repro.serving.memory import (PAGE_TOKENS, BankAwarePlacement,
                                   BankTopology, PagedStatePool, pages_for)
 from repro.serving.sampler import SamplingConfig, sample
@@ -183,25 +184,22 @@ def test_preemption_roundtrip_bit_identical_logits(tiny):
 # ---------------------------------------------------------------------------
 
 def _reference_outputs(params, cfg, prompts, n_new):
-    eng = ServingEngine(params, cfg, EngineConfig(slots=2,
-                                                  cache_capacity=384))
-    for i, p in enumerate(prompts):
-        eng.submit(Request(rid=i, prompt=p, max_new_tokens=n_new))
-    return {r.rid: r.output for r in eng.run()}
+    return {i: greedy_reference(params, cfg, p, n_new)
+            for i, p in enumerate(prompts)}
 
 
 def test_paged_engine_mixed_workload_matches_greedy(tiny_fp32):
     """Short + long prompts (chunked prefill for the long one) through a
-    small pool; every request's greedy tokens must match the fixed-slot
-    engine."""
+    small pool; every request's greedy tokens must match the dense
+    single-request reference."""
     params, cfg = tiny_fp32
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (10, 150, 9, 40)]
     refs = _reference_outputs(params, cfg, prompts, 5)
 
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=3, n_pages=7, n_slabs=7, prefill_chunk=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=3, n_pages=7, n_slabs=7, prefill_chunk=128))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=5))
     done = eng.run()
@@ -227,8 +225,8 @@ def test_paged_engine_prefill_buckets(tiny_fp32):
                for n in (9, 11, 13, 42, 44, 46)]
     refs = _reference_outputs(params, cfg, prompts, 4)
 
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=3, n_pages=9, n_slabs=7, prefill_chunk=128,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=3, n_pages=9, n_slabs=7, prefill_chunk=128,
         prefill_buckets=(8, 32, 128)))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
@@ -268,7 +266,7 @@ def _serve_steps(eng):
 
 def test_paged_engine_pads_covered_prompts(masked_fp32):
     """Every prompt a bucket covers is prefilled whole at its bucket, the
-    padding masked: the greedy tokens are the fixed-slot engine's, no
+    padding masked: the greedy tokens are the dense reference's, no
     request ingests a tail, no decode row feeds a prompt token, the pad
     counter reads the padding, and each bucket compiles once."""
     params, cfg = masked_fp32
@@ -329,7 +327,7 @@ def test_paged_engine_new_prompt_lengths_compile_nothing(masked_fp32):
 def test_paged_engine_streams_past_largest_bucket(masked_fp32):
     """A prompt longer than the largest bucket prefills that bucket and
     streams the rest through the decode batch, and still gives the
-    fixed-slot engine's greedy tokens beside a padded one."""
+    dense reference's greedy tokens beside a padded one."""
     params, cfg = masked_fp32
     rng = np.random.default_rng(8)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
@@ -359,8 +357,8 @@ def test_paged_engine_growth_preemption_e2e(tiny_fp32):
                for _ in range(2)]
     refs = _reference_outputs(params, cfg, prompts, 12)
 
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=4, n_slabs=5, prefill_chunk=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=4, n_slabs=5, prefill_chunk=128))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=12))
     done = eng.run()
@@ -380,8 +378,8 @@ def test_paged_pool_doubles_inflight_in_same_bytes(tiny_fp32):
     budget = slots * ((cap // PAGE_TOKENS) * probe.page_nbytes
                       + probe.slab_nbytes)
 
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2 * slots, byte_budget=budget, n_pages=None,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2 * slots, byte_budget=budget, n_pages=None,
         n_slabs=2 * slots + 1, prefill_chunk=128))
     assert eng.pool.bytes_total() <= budget
     rng = np.random.default_rng(4)
@@ -400,8 +398,8 @@ def test_paged_pool_doubles_inflight_in_same_bytes(tiny_fp32):
 def test_paged_engine_priority_scheduling(tiny_fp32):
     """Lower priority value finishes first when capacity forces queueing."""
     params, cfg = tiny_fp32
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=1, n_pages=3, n_slabs=3,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=1, n_pages=3, n_slabs=3,
         scheduler=SchedulerConfig(policy="priority")))
     rng = np.random.default_rng(5)
     for i, prio in enumerate((5, 0, 3)):

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from repro.configs import get_smoke_config
 from repro.core.paged import PAGE_TOKENS, pages_for
@@ -119,16 +120,14 @@ def test_fork_matches_unshared_reprefill(tiny_fp32):
     assert st["shared_page_hits"] == 1
 
     # the acceptance reference is the unshared *dense* re-prefill path:
-    # the fixed-slot engine prefills the full context into contiguous
-    # caches -- no pages, no sharing, no chunking
-    ref_eng = Engine(params, cfg, ServeConfig(backend="slots", batch=2,
-                                              cache_capacity=256))
-    ref = ref_eng.submit(_full_context(parent, child), max_new_tokens=5)
-    ref.result()
-    assert child.output == ref.output, (child.output, ref.output)
-    # sharing saved prefill work vs the unshared run
-    rst = ref_eng.stats()
-    assert st["prefill_tokens"] - len(prompt) < rst["prefill_tokens"]
+    # the full context prefilled into contiguous caches -- no pages, no
+    # sharing, no chunking
+    context = _full_context(parent, child)
+    ref = greedy_reference(params, cfg, context, 5)
+    assert child.output == ref, (child.output, ref)
+    # sharing saved prefill work vs the unshared run, which prefills the
+    # whole context
+    assert st["prefill_tokens"] - len(prompt) < len(context)
 
 
 def test_parallel_forks_share_prefix_and_agree(tiny_fp32):

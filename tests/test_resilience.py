@@ -401,14 +401,6 @@ def test_request_timeout_expires_stale_queue_entries(llama):
     assert eng.obs.metrics.value("request_timeouts_total") >= 1
 
 
-def test_slots_backend_rejects_resilience_options():
-    for kw in ({"fault_plan": "nan:rid=0"}, {"nan_guard": True},
-               {"max_queued": 4}, {"request_timeout_s": 1.0},
-               {"step_budget_s": 0.5}):
-        with pytest.raises(ValueError, match="paged"):
-            ServeConfig(backend="slots", **kw)
-
-
 # ---------------------------------------------------------------------------
 # seeded chaos: random plans under open-loop traffic always drain
 # ---------------------------------------------------------------------------

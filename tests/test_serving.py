@@ -1,13 +1,15 @@
 """Serving engine integration: continuous batching correctness + throughput
 accounting on a tiny model."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import greedy_reference
 
 from repro.configs import get_smoke_config
+from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
-from repro.serving.engine import EngineConfig, Request, ServingEngine
+from repro.serving.api import ServeConfig
+from repro.serving.engine import PagedServingEngine, Request
 
 
 @pytest.fixture(scope="module")
@@ -17,27 +19,9 @@ def tiny():
     return params, cfg
 
 
-def _greedy_reference(params, cfg, prompt, n_new):
-    """Single-request greedy decode, no engine."""
-    B, S = 1, len(prompt)
-    batch = {"tokens": jnp.asarray(prompt, jnp.int32)[None],
-             "targets": jnp.asarray(prompt, jnp.int32)[None]}
-    logits, caches = M.prefill(params, cfg, batch)
-    lengths = jnp.full((1,), S, jnp.int32)
-    caches = M.set_cache_lengths(caches, lengths)
-    out = [int(jnp.argmax(logits[0]))]
-    tok = jnp.asarray([out[-1]], jnp.int32)
-    for step in range(n_new - 1):
-        logits, caches = M.decode_step(params, cfg, tok, caches,
-                                       lengths + step, seed=step + 1)
-        out.append(int(jnp.argmax(logits[0])))
-        tok = jnp.asarray([out[-1]], jnp.int32)
-    return out
-
-
 def test_engine_single_request(tiny):
     params, cfg = tiny
-    eng = ServingEngine(params, cfg, EngineConfig(slots=2, cache_capacity=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(batch=2, n_pages=9))
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, cfg.vocab_size, 12).astype(np.int32)
     eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=6))
@@ -49,9 +33,9 @@ def test_engine_single_request(tiny):
 
 def test_engine_batched_requests_complete(tiny):
     params, cfg = tiny
-    eng = ServingEngine(params, cfg, EngineConfig(slots=3, cache_capacity=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(batch=3, n_pages=9))
     rng = np.random.default_rng(1)
-    n_req = 7   # > slots: exercises admission + slot reuse
+    n_req = 7   # > batch: exercises admission + row reuse
     for i in range(n_req):
         eng.submit(Request(rid=i,
                            prompt=rng.integers(0, cfg.vocab_size,
@@ -66,22 +50,23 @@ def test_engine_batched_requests_complete(tiny):
     assert stats["tokens_per_s"] > 0
 
 
-def test_engine_matches_unbatched_greedy(tiny):
+@pytest.mark.parametrize("arch", ["llama3.2-1b", "mamba2-2.7b",
+                                  "zamba2-2.7b"])
+def test_engine_matches_unbatched_greedy(arch):
     """Continuous batching must not change any request's greedy tokens.
 
     Note: the decode seed differs between engine steps and the reference
     loop, so run the quant-free config where SR seeds cannot matter."""
-    from repro.core.state_update import StateQuantConfig
-    cfg = get_smoke_config("llama3.2-1b").with_(
+    cfg = get_smoke_config(arch).with_(
         state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
                                      backend="jnp"))
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in (10, 13, 9)]
-    refs = [_greedy_reference(params, cfg, p, 5) for p in prompts]
+    refs = [greedy_reference(params, cfg, p, 5) for p in prompts]
 
-    eng = ServingEngine(params, cfg, EngineConfig(slots=3, cache_capacity=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(batch=3, n_pages=9))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=5))
     done = sorted(eng.run(), key=lambda r: r.rid)
@@ -92,7 +77,7 @@ def test_engine_matches_unbatched_greedy(tiny):
 def test_engine_hybrid_model():
     cfg = get_smoke_config("zamba2-2.7b")
     params = M.init_model(jax.random.PRNGKey(3), cfg)
-    eng = ServingEngine(params, cfg, EngineConfig(slots=2, cache_capacity=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(batch=2, n_pages=9))
     rng = np.random.default_rng(3)
     for i in range(3):
         eng.submit(Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 6

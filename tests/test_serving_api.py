@@ -1,5 +1,5 @@
-"""Request-lifecycle serving API: ServeConfig backend selection, streaming
-handles, open-loop step(), abort at every lifecycle point, stats schema."""
+"""Request-lifecycle serving API: ServeConfig, streaming handles, open-loop
+step(), abort at every lifecycle point, truncation, stats schema."""
 import jax
 import numpy as np
 import pytest
@@ -8,7 +8,7 @@ from repro.configs import get_smoke_config
 from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
 from repro.serving.api import Engine, RequestHandle, ServeConfig
-from repro.serving.engine import PagedEngineConfig, EngineConfig
+from repro.serving.engine import PagedServingEngine
 
 
 @pytest.fixture(scope="module")
@@ -20,10 +20,23 @@ def tiny_fp32():
     return params, cfg
 
 
-def _mk(params, cfg, backend, **kw):
-    base = dict(batch=2, cache_capacity=128, n_pages=9, n_slabs=5)
+#: attention alone, and the benchmark's state-space family
+ARCHS = ("llama3.2-1b", "mamba2-2.7b")
+
+
+@pytest.fixture(scope="module", params=ARCHS)
+def arch_fp32(request):
+    cfg = get_smoke_config(request.param).with_(
+        state_quant=StateQuantConfig(fmt="fp32", rounding="nearest",
+                                     backend="jnp"))
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    return params, cfg
+
+
+def _mk(params, cfg, **kw):
+    base = dict(batch=2, n_pages=9, n_slabs=5)
     base.update(kw)
-    return Engine(params, cfg, ServeConfig(backend=backend, **base))
+    return Engine(params, cfg, ServeConfig(**base))
 
 
 # ---------------------------------------------------------------------------
@@ -32,34 +45,25 @@ def _mk(params, cfg, backend, **kw):
 
 def test_serve_config_selects_backend(tiny_fp32):
     params, cfg = tiny_fp32
-    assert isinstance(ServeConfig(backend="slots").engine_config(),
-                      EngineConfig)
-    pcfg = ServeConfig(backend="paged", batch=3).engine_config()
-    assert isinstance(pcfg, PagedEngineConfig)
-    assert pcfg.max_decode_batch == 3 and pcfg.n_slabs == 7  # 2B+1 default
-    with pytest.raises(ValueError):
-        ServeConfig(backend="gpu")
-    for backend in ("slots", "paged"):
-        assert _mk(params, cfg, backend).backend == backend
+    eng = Engine(params, cfg, ServeConfig(batch=3)).engine
+    assert isinstance(eng, PagedServingEngine)
+    assert len(eng.rows) == 3
+    assert eng.pool.n_slabs == 7                 # 2*batch + 1 default
 
 
-def test_slots_backend_rejects_fork_and_retain(tiny_fp32):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "slots")
-    with pytest.raises(ValueError, match="paged"):
-        eng.submit(np.arange(4, dtype=np.int32), retain=True)
-    with pytest.raises(ValueError, match="paged"):
-        eng.session()
+def test_serve_config_accepts_only_paged():
+    for backend in ("slots", "gpu"):
+        with pytest.raises(ValueError, match="only serving engine"):
+            ServeConfig(backend=backend)
 
 
 # ---------------------------------------------------------------------------
 # streaming: tokens surface per step, handle iteration drives the loop
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_streaming_order_matches_final_output(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend)
+def test_streaming_order_matches_final_output(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(0)
     hs = [eng.submit(rng.integers(0, cfg.vocab_size, 8 + 3 * i
                                   ).astype(np.int32), max_new_tokens=5)
@@ -81,7 +85,7 @@ def test_streaming_order_matches_final_output(tiny_fp32, backend):
 
 def test_handle_iteration_drives_engine(tiny_fp32):
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "paged")
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(1)
     h1 = eng.submit(rng.integers(0, cfg.vocab_size, 10).astype(np.int32),
                     max_new_tokens=6)
@@ -98,10 +102,9 @@ def test_handle_iteration_drives_engine(tiny_fp32):
 # abort: queued, mid-decode, spilled
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_abort_mid_decode_frees_capacity(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend)
+def test_abort_mid_decode_frees_capacity(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg)
     rng = np.random.default_rng(2)
     ha = eng.submit(rng.integers(0, cfg.vocab_size, 10).astype(np.int32),
                     max_new_tokens=12)
@@ -123,18 +126,16 @@ def test_abort_mid_decode_frees_capacity(tiny_fp32, backend):
     assert hc.status == "done" and len(hc.output) == 3
     # the aborted handle kept its streamed tokens, and no more arrived
     assert len(ha.output) == seen
-    if backend == "paged":
-        pool = eng.engine.pool
-        assert pool.free_pages == pool.usable_pages
-        assert len(pool.page_table) == 0
+    pool = eng.engine.pool
+    assert pool.free_pages == pool.usable_pages
+    assert len(pool.page_table) == 0
     st = eng.stats()
     assert st["requests_aborted"] == 1 and st["requests_done"] == 2
 
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_abort_queued_request_never_runs(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend, batch=1)
+def test_abort_queued_request_never_runs(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg, batch=1)
     rng = np.random.default_rng(3)
     h1 = eng.submit(rng.integers(0, cfg.vocab_size, 8).astype(np.int32),
                     max_new_tokens=4)
@@ -146,15 +147,14 @@ def test_abort_queued_request_never_runs(tiny_fp32, backend):
     assert h1.status == "done"
     assert h2.output == []
     assert {r.rid for r in done} == {h1.rid, h2.rid}
-    if backend == "paged":
-        assert len(eng.engine.sched) == 0
+    assert len(eng.engine.sched) == 0
 
 
 def test_abort_spilled_request_drops_pages(tiny_fp32):
     """Preempt a victim into host spill, then abort it: the blob and its
     page references must be dropped, the survivor must finish normally."""
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "paged", batch=2, n_pages=4, n_slabs=5)
+    eng = _mk(params, cfg, batch=2, n_pages=4, n_slabs=5)
     rng = np.random.default_rng(4)
     hs = [eng.submit(rng.integers(0, cfg.vocab_size, 120).astype(np.int32),
                      max_new_tokens=12) for _ in range(2)]
@@ -179,10 +179,9 @@ def test_abort_spilled_request_drops_pages(tiny_fp32):
 # run(max_steps) + stats schema (the {"tokens": 0} bugfix)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_run_step_cap_surfaces_active_requests(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend, batch=1)
+def test_run_step_cap_surfaces_active_requests(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg, batch=1)
     rng = np.random.default_rng(5)
     h1 = eng.submit(rng.integers(0, cfg.vocab_size, 8).astype(np.int32),
                     max_new_tokens=10)
@@ -199,11 +198,11 @@ def test_run_step_cap_surfaces_active_requests(tiny_fp32, backend):
     assert all(r.status == "done" for r in done)
 
 
-def test_slots_capacity_clip_is_truncated_not_done(tiny_fp32):
-    """A request stopped by slot capacity (not max_new/eos) was clipped:
-    it must end `truncated`, matching the paged pool's contract."""
+def test_paged_engine_truncates_when_pages_run_out(tiny_fp32):
+    """A request stopped because the pool has no page left for its next
+    token (not by max_new/eos) was clipped: it must end `truncated`."""
     params, cfg = tiny_fp32
-    eng = _mk(params, cfg, "slots", batch=1, cache_capacity=128)
+    eng = _mk(params, cfg, batch=1, n_pages=2)
     rng = np.random.default_rng(6)
     h = eng.submit(rng.integers(0, cfg.vocab_size, 120).astype(np.int32),
                    max_new_tokens=50)
@@ -222,16 +221,14 @@ _SCHEMA = ("tokens", "wall_s", "tokens_per_s", "prefill_tokens",
            "p50_tok_latency_s", "p99_tok_latency_s")
 
 
-@pytest.mark.parametrize("backend", ["slots", "paged"])
-def test_stats_full_schema_before_any_finish(tiny_fp32, backend):
-    params, cfg = tiny_fp32
-    eng = _mk(params, cfg, backend)
+def test_stats_full_schema_before_any_finish(arch_fp32):
+    params, cfg = arch_fp32
+    eng = _mk(params, cfg)
     st = eng.stats()
     for key in _SCHEMA:
         assert key in st, key
         assert st[key] == 0.0, (key, st[key])
-    if backend == "paged":
-        for key in ("preemptions", "occupancy", "fragmentation",
-                    "gather_bytes", "pages_allocated", "shared_page_hits",
-                    "shared_page_savings"):
-            assert key in st and st[key] == 0.0, key
+    for key in ("preemptions", "occupancy", "fragmentation",
+                "gather_bytes", "pages_allocated", "shared_page_hits",
+                "shared_page_savings"):
+        assert key in st and st[key] == 0.0, key

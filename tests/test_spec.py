@@ -17,8 +17,7 @@ from repro.configs import get_smoke_config
 from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
 from repro.serving.api import Engine, ServeConfig
-from repro.serving.engine import (PagedEngineConfig, PagedServingEngine,
-                                  Request)
+from repro.serving.engine import PagedServingEngine, Request
 from repro.serving.memory import PAGE_TOKENS, PagedStatePool, pages_for
 from repro.serving.sampler import SamplingConfig
 from repro.serving.spec import KController, ModelDraft, NGramDraft
@@ -44,8 +43,8 @@ def _build(arch, fmt="fp32", backend="jnp"):
 
 
 def _serve(params, cfg, prompts, spec, max_new=5, spec_k=3, **kw):
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=17, n_slabs=5, prefill_chunk=128,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=17, n_slabs=5, prefill_chunk=128,
         spec=spec, spec_k=spec_k, **kw))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=max_new))
@@ -227,8 +226,8 @@ def test_spec_acceptance_accounting_and_stream_order():
 
 def test_spec_stats_schema_stable_when_off():
     params, cfg = _build("llama3.2-1b")
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=9, n_slabs=5, prefill_chunk=128))
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=9, n_slabs=5, prefill_chunk=128))
     eng.submit(Request(rid=0, prompt=_prompts(cfg)[1], max_new_tokens=2))
     eng.run()
     st = eng.stats()
@@ -340,8 +339,8 @@ def test_spec_abort_mid_speculation_unwinds_cleanly():
     shadow-ledger teardown for both pools."""
     params, cfg = _build("llama3.2-1b")
     prompts = _prompts(cfg)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=40, n_slabs=5, prefill_chunk=128,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=40, n_slabs=5, prefill_chunk=128,
         spec="model:llama3.2-1b", spec_k=3))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=12))
@@ -369,8 +368,8 @@ def test_spec_preempt_mid_speculation_stays_bit_exact():
     params, cfg = _build("llama3.2-1b")
     prompts = _prompts(cfg)
     _, ref = _serve(params, cfg, prompts, spec=None, max_new=8)
-    eng = PagedServingEngine(params, cfg, PagedEngineConfig(
-        max_decode_batch=2, n_pages=17, n_slabs=5, prefill_chunk=128,
+    eng = PagedServingEngine(params, cfg, ServeConfig(
+        batch=2, n_pages=17, n_slabs=5, prefill_chunk=128,
         spec="ngram", spec_k=3))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=8))
