@@ -173,24 +173,33 @@ def _pair_index(shape) -> jnp.ndarray:
     return (lane % MX8_GROUP) // MX8_PAIR
 
 
-def _group_selector(n: int, stride: int = 1) -> jnp.ndarray:
+def _group_selector(n: int, stride: int = 1,
+                    groups_first: bool = False) -> jnp.ndarray:
     """(n, n/16) 0/1 matrix selecting, for group g, its lanes ``l`` with
-    ``l % stride == 0``."""
-    shape = (n, n // MX8_GROUP)
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    sel = lane // MX8_GROUP == jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return (sel & (lane % stride == 0)).astype(jnp.bfloat16)
+    ``l % stride == 0``; ``(n/16, n)`` with ``groups_first``."""
+    lane_ax = 1 if groups_first else 0
+    shape = (n, n // MX8_GROUP)[::-1 if groups_first else 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, lane_ax)
+    group = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - lane_ax)
+    return ((lane // MX8_GROUP == group)
+            & (lane % stride == 0)).astype(jnp.bfloat16)
+
+
+def _small_int_dot(a: jnp.ndarray, b: jnp.ndarray, dims) -> jnp.ndarray:
+    """``dot_general`` over the contracting ``dims`` of two small-integer
+    operands, one of them a 0/1 selector and the other in 0..255: every
+    product and sum is an integer below 256, so bf16 inputs with f32
+    accumulation are exact."""
+    bf16 = lambda x: x.astype(jnp.float32).astype(jnp.bfloat16)
+    out = jax.lax.dot_general(
+        bf16(a), bf16(b), (dims, ((), ())),
+        precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+    return out.astype(jnp.int32)
 
 
 def _small_int_matmul(x: jnp.ndarray, sel: jnp.ndarray) -> jnp.ndarray:
-    """Contract the last axis of integer ``x`` (0..255) with a 0/1 selector;
-    every product and sum is an integer below 256, so bf16 inputs with f32
-    accumulation are exact."""
-    out = jax.lax.dot_general(
-        x.astype(jnp.float32).astype(jnp.bfloat16), sel,
-        (((x.ndim - 1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
-    return out.astype(jnp.int32)
+    """Contract the last axis of integer ``x`` with a 0/1 selector."""
+    return _small_int_dot(x, sel, ((x.ndim - 1,), (0,)))
 
 
 def _pow2(k: jnp.ndarray) -> jnp.ndarray:
@@ -205,10 +214,11 @@ def _pow2(k: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(k >= -126, normal, subnormal)
 
 
-def mx8_quantize(x: jnp.ndarray, rounding: str = "nearest",
-                 bits: Optional[jnp.ndarray] = None) -> QuantizedTensor:
-    """Quantize to MX8 along the last axis (length must divide MX8_GROUP)."""
-    orig_shape = x.shape
+def _mx8_encode(x: jnp.ndarray, rounding: str,
+                bits: Optional[jnp.ndarray]):
+    """Mantissas of ``x`` along its last axis, plus on every lane its
+    group's biased exponent and its micro-exponent bit shifted to its pair
+    position (a group's sum of those is its packed micro byte)."""
     n = x.shape[-1]
     assert n % MX8_GROUP == 0, f"last dim {n} not divisible by {MX8_GROUP}"
     xf = x.astype(jnp.float32)
@@ -226,18 +236,51 @@ def mx8_quantize(x: jnp.ndarray, rounding: str = "nearest",
     scale = _pow2(e - MX8_MBITS - micro)
     q = _round(xf / scale, rounding, bits)
     mant = jnp.clip(q, -63, 63).astype(jnp.int8)
+    return (mant, e + MX8_EXP_BIAS,
+            jnp.left_shift(micro, _pair_index(x.shape)))
 
+
+def mx8_quantize(x: jnp.ndarray, rounding: str = "nearest",
+                 bits: Optional[jnp.ndarray] = None) -> QuantizedTensor:
+    """Quantize to MX8 along the last axis (length must divide MX8_GROUP)."""
+    n = x.shape[-1]
+    mant, e, micro = _mx8_encode(x, rounding, bits)
     # one lane per group carries the exponent; one lane per pair its bit,
     # shifted into place so the group's sum is its packed micro byte
-    exp_stored = _small_int_matmul(e + MX8_EXP_BIAS,
-                                   _group_selector(n, MX8_GROUP))
-    micro_packed = _small_int_matmul(
-        jnp.left_shift(micro, _pair_index(x.shape)),
-        _group_selector(n, MX8_PAIR))
-    return QuantizedTensor("mx8", orig_shape, {
+    exp_stored = _small_int_matmul(e, _group_selector(n, MX8_GROUP))
+    micro_packed = _small_int_matmul(micro, _group_selector(n, MX8_PAIR))
+    return QuantizedTensor("mx8", x.shape, {
         "mantissa": mant, "exponent": exp_stored.astype(jnp.uint8),
         "micro": micro_packed.astype(jnp.uint8),
     })
+
+
+def mx8_quantize_t(x: jnp.ndarray, rounding: str = "nearest",
+                   bits: Optional[jnp.ndarray] = None):
+    """:func:`mx8_quantize` of a 2-D ``(N, n)`` ``x``, its group bytes
+    returned transposed: ``(mantissa (N, n), exponent (n/16, N), micro
+    (n/16, N))``, the groups down the rows and ``x``'s rows on the lanes.
+    The same bytes, as the state-update kernel stores them."""
+    n = x.shape[-1]
+    mant, e, micro = _mx8_encode(x, rounding, bits)
+    contract = ((1,), (1,))
+    exp_t = _small_int_dot(_group_selector(n, MX8_GROUP, True), e, contract)
+    micro_t = _small_int_dot(_group_selector(n, MX8_PAIR, True), micro,
+                             contract)
+    return mant, exp_t.astype(jnp.uint8), micro_t.astype(jnp.uint8)
+
+
+def mx8_dequantize_t(mant: jnp.ndarray, exp_t: jnp.ndarray,
+                     micro_t: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of :func:`mx8_quantize_t`: ``mant`` ``(N, n)``, ``exp_t``
+    and ``micro_t`` ``(n/16, N)``.  The same values as
+    :func:`mx8_dequantize` of the untransposed payload."""
+    sel = _group_selector(mant.shape[-1], groups_first=True)     # (G, n)
+    contract = ((0,), (0,))
+    e = _small_int_dot(exp_t.astype(jnp.int32), sel, contract) - MX8_EXP_BIAS
+    mp = _small_int_dot(micro_t.astype(jnp.int32), sel, contract)
+    micro = (mp >> _pair_index(mant.shape)) & 1
+    return mant.astype(jnp.float32) * _pow2(e - MX8_MBITS - micro)
 
 
 def mx8_dequantize(qt: QuantizedTensor) -> jnp.ndarray:

@@ -22,7 +22,8 @@ instead of a host-side compatibility shim:
     One mixer's recurrent-state slab pool plus the step's slab ids.  The
     paged ``state_update`` op updates exactly the ``B`` owned slab rows in
     place (the slabs are per-request already, so this is the minimal
-    traffic), running the same fused kernel as the dense layout on the rows.
+    traffic): the fused MX8 kernel addresses each row's slab in the pool
+    by scalar prefetch.
 
 Both carry a ``group`` index: scanned models stack their per-group leaves
 ``(G, ...)`` inside the pool content, and one container is shared by all
@@ -144,8 +145,10 @@ class PagedKVCache:
 @jax.tree_util.register_pytree_with_keys_class
 @dataclasses.dataclass
 class PagedState:
-    """Slab-pool view of one mixer's recurrent state (stored ``(B,H,dv,dk)``
-    rows living at ``pool[slab_id, group]``)."""
+    """Slab-pool view of one mixer's recurrent state (``(B,H,dv,dk)`` rows
+    living at ``pool[slab_id, group]``).  An MX8 pool's logical shape is
+    ``(n_slabs, G, H, dv, dk)``; its payload is stored as the state-update
+    kernel reads it (:mod:`repro.kernels.mx_state_update`)."""
     pool: object                     # (n_slabs, G, H, dv, d) pool (QT or array)
     slabs: jnp.ndarray               # (B,) int32 slab ids
     group: jnp.ndarray               # () int32 stacked-layer index
@@ -170,7 +173,7 @@ class PagedState:
     @property
     def shape(self) -> Tuple[int, int, int, int]:
         """Logical dense-state shape (B, H, dv, dk) of the viewed rows."""
-        n_slabs, g, h, dv, dk = _payload_dims(self.pool)
+        n_slabs, g, h, dv, dk = self.pool.shape
         return (self.batch, h, dv, dk)
 
     def with_step(self, group, lengths=None) -> "PagedState":

@@ -918,7 +918,11 @@ def _element_spec_decode(p: Params, x, cache, cfg: ModelConfig, kind: str,
             else:
                 raise ValueError(kind)
             ys.append(yi)
-            rows.append(_state_snapshot(cache))
+            # the next position updates the slab pool in place: order the
+            # snapshot's reads before it, so the pool is not copied
+            cache, snap = jax.lax.optimization_barrier(
+                (cache, _state_snapshot(cache)))
+            rows.append(snap)
         y = jnp.concatenate(ys, axis=1)
         snap = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
     x = x + y

@@ -22,8 +22,9 @@ Typical call sites::
 """
 # NOTE: import order matters -- base and registry first (no repro deps
 # beyond core.formats), then the op implementations (which register
-# themselves on import; dense before paged, the paged ops delegate to the
-# dense kernels on gathered rows), then the model-level traffic bridge.
+# themselves on import; dense before paged, the paged jnp references
+# delegate to the dense ops on gathered rows), then the model-level traffic
+# bridge.
 from repro.ops.base import (LAYOUTS, OpPlan, SpuDeprecationWarning, SpuOp,
                             StateQuantConfig, TrafficBytes, fmt_bits,
                             fmt_of_state)

@@ -24,10 +24,13 @@ a gathered dense cache tree:
     path, a one-slot ``.at[].set`` scatter on jnp.
 
 ``state_update`` (pallas mx8 / jnp every format)
-    State slabs are per-request already, so the paged op reads exactly the
-    ``B`` owned slab rows, runs the registered *dense* kernel on them
-    (same fused ``mx_state_update``, bit-identical), and writes the rows
-    back in place.
+    State slabs are per-request already, so the op touches exactly the
+    ``B`` owned slab rows.  The pallas kernel
+    (:func:`repro.kernels.mx_state_update.mx_paged_state_update`) takes the
+    slab pool whole, aliased input to output, and blocks each row's heads
+    straight out of ``pool[slabs[b], group]`` -- nothing is gathered or
+    scattered around it.  The jnp reference gathers the rows, runs the
+    registered *dense* op on them (bit-identical) and scatters them back.
 
 Traffic descriptors are page-granular: attention reads whole 128-token
 pages (``ceil(T/128)`` of them -- a partially-filled tail page still
@@ -45,6 +48,7 @@ from repro.core import attention_cache as AC
 from repro.core import formats as F
 from repro.core.paged import (PAGE_TOKENS, PagedKVCache, PagedState,
                               pages_for)
+from repro.kernels import mx_state_update as SU
 from repro.kernels.mx_paged_attention import (mx_paged_attention_decode,
                                               mx_paged_kv_append)
 from repro.ops import registry
@@ -256,43 +260,64 @@ class _PagedStateUpdateBase(SpuOp):
     kind = "state_update"
     layout = "paged"
 
+    def in_place(self, heads: int, dv: int, dk: int) -> bool:
+        """Whether the op updates a pool of ``(heads, dv, dk)`` states in
+        place; otherwise it gathers the batch rows and scatters them back."""
+        return False
     def traffic(self, plan: OpPlan) -> TrafficBytes:
         # identical bytes to the dense layout: the slabs are per-request, so
         # the op touches exactly the B owned rows (read + write in place)
         dense = registry.get_op("state_update", "jnp", plan.fmt, "dense")
         return dense.traffic(plan)
 
-    def execute(self, state: PagedState, inputs: Dict[str, Any],
-                plan: OpPlan) -> Tuple[PagedState, jnp.ndarray]:
-        pool, slabs = state.pool, state.slabs
-        grp = jnp.asarray(state.group, jnp.int32)
-        if isinstance(pool, F.QuantizedTensor):
-            rows = F.QuantizedTensor(
-                pool.fmt, state.shape,
-                {f: a[slabs, grp] for f, a in pool.payload.items()})
-        else:
-            rows = pool[slabs, grp]
-        dense_op = registry.get_op("state_update", self.backend, plan.fmt,
-                                   "dense")
-        new_rows, y = dense_op.execute(rows, inputs, plan)
-        if isinstance(pool, F.QuantizedTensor):
-            npool = F.QuantizedTensor(
-                pool.fmt, pool.shape,
-                {f: pool.payload[f].at[slabs, grp].set(new_rows.payload[f])
-                 for f in pool.payload})
-        else:
-            npool = pool.at[slabs, grp].set(new_rows.astype(pool.dtype))
-        return dataclasses.replace(state, pool=npool), y
-
 
 @registry.register
 class PagedStateUpdatePallas(_PagedStateUpdateBase):
-    """Slab rows through the fused dense MX8 kernel, written back in place."""
+    """The fused MX8 kernel over the slab pool, in place."""
     backend = "pallas"
     formats = ("mx8",)
+
+    def in_place(self, heads: int, dv: int, dk: int) -> bool:
+        return SU.plan_blocks(heads, dv, dk) is not None
+
+    def execute(self, state: PagedState, inputs: Dict[str, Any],
+                plan: OpPlan) -> Tuple[PagedState, jnp.ndarray]:
+        if not self.in_place(*state.shape[1:]):
+            # no lane-aligned block of such heads: the reference
+            return registry.get_op("state_update", "jnp", plan.fmt,
+                                   "paged").execute(state, inputs, plan)
+        pool, y = SU.mx_paged_state_update(
+            state.pool, state.slabs, state.group, inputs["d"], inputs["k"],
+            inputs["v"], inputs["q"],
+            jnp.asarray(inputs.get("seed", 0), jnp.int32),
+            rounding=plan.rounding, interpret=interpret_pallas())
+        return dataclasses.replace(state, pool=pool), y
 
 
 @registry.register
 class PagedStateUpdateJnp(_PagedStateUpdateBase):
+    """Reference: the rows gathered, through the dense op, scattered back."""
     backend = "jnp"
     formats = ("mx8", "int8", "fp8_e4m3", "fp8_e5m2", "fp32", "bf16", "fp16")
+
+    def execute(self, state: PagedState, inputs: Dict[str, Any],
+                plan: OpPlan) -> Tuple[PagedState, jnp.ndarray]:
+        pool, slabs = state.pool, state.slabs
+        grp = jnp.asarray(state.group, jnp.int32)
+        dense_op = registry.get_op("state_update", "jnp", plan.fmt, "dense")
+        if not isinstance(pool, F.QuantizedTensor):
+            new_rows, y = dense_op.execute(pool[slabs, grp], inputs, plan)
+            npool = pool.at[slabs, grp].set(new_rows.astype(pool.dtype))
+            return dataclasses.replace(state, pool=npool), y
+        # an MX8 pool keeps the kernel's stored layout
+        mx8 = pool.fmt == "mx8"
+        dims = state.shape[1:]
+        rows = F.QuantizedTensor(pool.fmt, state.shape, {
+            f: SU.unstore(f, a[slabs, grp], *dims) if mx8 else a[slabs, grp]
+            for f, a in pool.payload.items()})
+        new_rows, y = dense_op.execute(rows, inputs, plan)
+        npool = F.QuantizedTensor(pool.fmt, pool.shape, {
+            f: a.at[slabs, grp].set(SU.store(f, new_rows.payload[f]) if mx8
+                                    else new_rows.payload[f])
+            for f, a in pool.payload.items()})
+        return dataclasses.replace(state, pool=npool), y

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.core import formats as F
 from repro.kernels import ref as _ref
-from repro.kernels.mx_state_update import mx_state_update as _su_pallas
+from repro.kernels.mx_state_update import (mx_state_update as _su_pallas,
+                                           plan_blocks)
 from repro.ops import registry
 from repro.ops.base import (OPERAND_BYTES, OUTPUT_BYTES, OpPlan, SpuOp,
                             StateQuantConfig, TrafficBytes, fmt_bits,
@@ -81,6 +82,10 @@ class StateUpdatePallas(_StateUpdateBase):
 
     def execute(self, state, inputs: Dict[str, Any],
                 plan: OpPlan) -> Tuple[StateLike, jnp.ndarray]:
+        if plan_blocks(*state.shape[1:]) is None:
+            # no lane-aligned block of such heads: the reference
+            return registry.get_op("state_update", "jnp", plan.fmt).execute(
+                state, inputs, plan)
         return _su_pallas(state, inputs["d"], inputs["k"], inputs["v"],
                           inputs["q"],
                           jnp.asarray(inputs.get("seed", 0), jnp.int32),

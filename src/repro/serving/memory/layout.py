@@ -24,7 +24,12 @@ A page pool stores its tokens on the last axis: a page whose content is
 whatever the head width, the TPU keeps the pool in the row-major layout the
 paged kernels read, and no decode step copies a pool into another layout.
 The host-side page blobs (spill, prefix-store demotion) keep the logical
-``(n, 128, *lead, *rest)`` order.
+``(n, 128, *lead, *rest)`` order.  Likewise the payload of an MX8 recurrent
+state (the ``"S"`` leaves) is stored as the state-update kernel reads it
+(:func:`repro.kernels.mx_state_update.stored_shape`): rows are converted
+where they enter or leave the pool through a dense tree (prefill insert,
+the gather reference path); spill blobs, forks and snapshots copy stored
+rows as they are.
 
 All gather/scatter functions are pure jnp and run inside jit.
 """
@@ -41,6 +46,7 @@ from repro.core import attention_cache as AC
 from repro.core import formats as F
 from repro.core import paged as PG
 from repro.core.paged import PAGE_TOKENS  # noqa: F401  (canonical home moved)
+from repro.kernels import mx_state_update as SU
 from repro.ops.base import fmt_of_state
 
 
@@ -52,6 +58,9 @@ class LeafSpec:
     time_axis: int         # leaf coordinates; -1 for slabs
     shape: Tuple[int, ...]  # template leaf shape at B=1, T=PAGE_TOKENS
     dtype: Any
+    #: payload field of an MX8 recurrent state, stored in the state-update
+    #: kernel's layout; logical content ``(*lead, H, dv, c)``
+    mx8_field: Optional[str] = None
 
     @property
     def content_shape(self) -> Tuple[int, ...]:
@@ -61,10 +70,20 @@ class LeafSpec:
         return tuple(s)
 
     @property
+    def state_dims(self) -> Tuple[int, int, int]:
+        """``(H, dv, dk)`` of an MX8 state leaf."""
+        h, dv, c = self.content_shape[-3:]
+        return h, dv, (c if self.mx8_field == "mantissa" else c * F.MX8_GROUP)
+
+    @property
     def stored_shape(self) -> Tuple[int, ...]:
-        """One page or slab as its pool stores it: a page's tokens last."""
+        """One page or slab as its pool stores it: a page's tokens last, an
+        MX8 state in the state-update kernel's layout."""
         if self.kind == "slab":
-            return self.content_shape
+            if self.mx8_field is None:
+                return self.content_shape
+            return (self.content_shape[:-3]
+                    + SU.stored_shape(self.mx8_field, *self.state_dims))
         c, ct = self.content_shape, self.content_time_axis
         return c[:ct] + (int(np.prod(c[ct + 1:])), c[ct])
 
@@ -100,13 +119,17 @@ class CachePaging:
         ``t_b2``/``t_t2`` are abstract skeletons at (B=2, T) and (B, 2T)."""
         self.template = template
         self.specs: List[LeafSpec] = []
+        #: storage format and ``(H, dv, dk)`` of each recurrent-state
+        #: (``"S"``) leaf
+        self.state_leaves: List[Tuple[str, Tuple[int, ...]]] = []
         self._build_specs(template, t_b2, t_t2, in_kv=False)
 
     # ------------------------------------------------------------------
     # traversal -- the one canonical order every operation below follows
     # ------------------------------------------------------------------
 
-    def _build_specs(self, t, b2, t2, in_kv: bool):
+    def _build_specs(self, t, b2, t2, in_kv: bool, mx8_state=False,
+                     field=None):
         if t is None:
             return
         if isinstance(t, AC.KVCache):
@@ -118,11 +141,17 @@ class CachePaging:
         if isinstance(t, F.QuantizedTensor):
             for f in sorted(t.payload):
                 self._build_specs(t.payload[f], b2.payload[f], t2.payload[f],
-                                  in_kv=in_kv)
+                                  in_kv=in_kv, field=f if mx8_state else None)
             return
         if isinstance(t, dict):
             for k in sorted(t):
-                self._build_specs(t[k], b2[k], t2[k], in_kv=in_kv)
+                if k == "S":
+                    self.state_leaves.append((fmt_of_state(t[k]),
+                                              tuple(t[k].shape[-3:])))
+                # an MX8 recurrent state is stored for the state update
+                mx8 = k == "S" and self.state_leaves[-1][0] == "mx8"
+                self._build_specs(t[k], b2[k], t2[k], in_kv=in_kv,
+                                  mx8_state=mx8)
             return
         if isinstance(t, (tuple, list)):
             for a, b, c in zip(t, b2, t2):
@@ -140,7 +169,7 @@ class CachePaging:
         else:
             assert t_ax == -1, f"state leaf {t.shape} scales with T"
             self.specs.append(LeafSpec("slab", b_ax, -1,
-                                       tuple(t.shape), t.dtype))
+                                       tuple(t.shape), t.dtype, field))
 
     # ------------------------------------------------------------------
     # pools
@@ -163,8 +192,8 @@ class CachePaging:
             else:
                 content = jnp.squeeze(jnp.asarray(leaf), axis=spec.batch_axis)
                 pools.append(jnp.broadcast_to(
-                    content[None], (n_slabs,) + spec.content_shape
-                ).astype(spec.dtype))
+                    self._store(content, spec)[None],
+                    (n_slabs,) + spec.stored_shape).astype(spec.dtype))
         return pools
 
     def _iter_template_leaves(self, t):
@@ -203,16 +232,22 @@ class CachePaging:
     # ------------------------------------------------------------------
 
     @staticmethod
-    def _store(pages, spec: LeafSpec):
+    def _store(x, spec: LeafSpec):
         """Pages ``(..., *lead, 128, *rest)`` -> stored ``(..., *lead, R,
-        128)``."""
+        128)``; slabs ``(..., *content)`` -> stored."""
+        if spec.kind == "slab":
+            return (x if spec.mx8_field is None
+                    else SU.store(spec.mx8_field, x))
         n_rest = len(spec.content_shape) - spec.content_time_axis - 1
-        flat = pages.reshape(pages.shape[:pages.ndim - n_rest] + (-1,))
+        flat = x.reshape(x.shape[:x.ndim - n_rest] + (-1,))
         return jnp.swapaxes(flat, -1, -2)
 
     @staticmethod
     def _unstore(stored, spec: LeafSpec):
         """Inverse of :meth:`_store`."""
+        if spec.kind == "slab":
+            return (stored if spec.mx8_field is None
+                    else SU.unstore(spec.mx8_field, stored, *spec.state_dims))
         rest = spec.content_shape[spec.content_time_axis + 1:]
         x = jnp.swapaxes(stored, -1, -2)
         return x.reshape(x.shape[:-1] + rest)
@@ -229,9 +264,10 @@ class CachePaging:
         g = g.reshape(shape)
         return jnp.moveaxis(g, 0, spec.batch_axis)
 
-    @staticmethod
-    def _gather_slab_leaf(pool, slabs, spec: LeafSpec):
-        return jnp.moveaxis(pool[slabs], 0, spec.batch_axis)
+    @classmethod
+    def _gather_slab_leaf(cls, pool, slabs, spec: LeafSpec):
+        return jnp.moveaxis(cls._unstore(pool[slabs], spec), 0,
+                            spec.batch_axis)
 
     @staticmethod
     def _scatter_token_leaf(pool, dense, bt, pos, spec: LeafSpec):
@@ -246,10 +282,10 @@ class CachePaging:
         # pool (P, *lead, R, 128): the slot is a column of the page
         return pool.at[(phys,) + (slice(None),) * (ct + 1) + (off,)].set(vals)
 
-    @staticmethod
-    def _scatter_slab_leaf(pool, dense, slabs, spec: LeafSpec):
+    @classmethod
+    def _scatter_slab_leaf(cls, pool, dense, slabs, spec: LeafSpec):
         vals = jnp.moveaxis(dense, spec.batch_axis, 0)
-        return pool.at[slabs].set(vals)
+        return pool.at[slabs].set(cls._store(vals, spec))
 
     @staticmethod
     def _row_to_pages(row, spec: LeafSpec):
@@ -357,13 +393,14 @@ class CachePaging:
                 out.append(self._insert_pages_leaf(pool, vals, page_ids, spec))
             else:
                 vals = jnp.moveaxis(row, spec.batch_axis, 0)[0]
-                out.append(pool.at[slab].set(vals))
+                out.append(pool.at[slab].set(self._store(vals, spec)))
         return out
 
     def extract_request(self, pools: Sequence[jnp.ndarray],
                         page_ids: jnp.ndarray, slab: jnp.ndarray
                         ) -> List[jnp.ndarray]:
-        """Pull one request's pages+slab out of the pools (for host spill)."""
+        """Pull one request's pages+slab out of the pools (for host spill);
+        the slab rows as stored."""
         out = []
         for pool, spec in zip(pools, self.specs):
             if spec.kind == "page":
@@ -487,7 +524,8 @@ class CachePaging:
     def _view_stream(self, t, take):
         """Template KV/state stream -> (pool-backed stream, lead shape,
         logical rest shape).  A page stream's logical shape is (P, G, 128,
-        *rest); a slab stream's is its pool's."""
+        *rest); a slab stream's is (S, G, *content) with the state's
+        logical content."""
         if t is None:
             return None, (), ()
         quantized = isinstance(t, F.QuantizedTensor)
@@ -503,8 +541,8 @@ class CachePaging:
                 if spec.kind == "page" else ())
         if not quantized:
             return arr, lead, rest
-        shape = (arr.shape[:2] + (PAGE_TOKENS,) + rest
-                 if spec.kind == "page" else arr.shape)
+        shape = arr.shape[:2] + ((PAGE_TOKENS,) + rest if spec.kind == "page"
+                                 else spec.content_shape[-3:])
         return F.QuantizedTensor(t.fmt, shape, payload), lead, rest
 
     def paged_view(self, pools: Sequence[jnp.ndarray], bt: jnp.ndarray,
@@ -627,7 +665,7 @@ class CachePaging:
                 "snapshot leaf aligned with a page spec"
             vals = snap_leaf[sel, bidx]            # (B, *row)
             out.append(pool.at[slabs].set(
-                vals.reshape((B,) + spec.content_shape)))
+                vals.reshape((B,) + spec.stored_shape)))
 
         def walk(t, s):
             if t is None:

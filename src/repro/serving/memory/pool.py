@@ -92,6 +92,14 @@ class PagedStatePool:
         t_b2 = M.abstract_decode_caches(cfg, 2, PAGE_TOKENS)
         t_t2 = M.abstract_decode_caches(cfg, 1, 2 * PAGE_TOKENS)
         self.paging = CachePaging(template, t_b2, t_t2)
+        # the recurrent-state leaves the paged decode step updates in the
+        # pool in place, and those it gathers and scatters back
+        in_place = [OPS.get_op("state_update", OPS.resolve_backend(
+                        "state_update", fmt, cfg.state_quant.backend,
+                        layout="paged"), fmt, "paged").in_place(*dims)
+                    for fmt, dims in self.paging.state_leaves]
+        self.state_slab_leaves = {"in_place": sum(in_place),
+                                  "gathered": len(in_place) - sum(in_place)}
 
         if byte_budget is not None:
             assert n_pages is None, "give n_pages or byte_budget, not both"
@@ -177,7 +185,10 @@ class PagedStatePool:
     def attach_obs(self, obs) -> None:
         """Attach an engine's :class:`repro.obs.Observability` bundle: the
         jitted pool steppers get recompile watchers, the placement mirrors
-        page alloc/free/ref into the metrics registry, and page movement
+        page alloc/free/ref into the metrics registry, the gauges
+        ``state_slab_leaves_in_place`` / ``_gathered`` count the
+        recurrent-state leaves the decode step updates in the pool in place
+        and those it gathers, and page movement
         (register / grow / fork / spill / resume / release) emits instants
         on the pool track."""
         self._obs = obs
@@ -192,6 +203,8 @@ class PagedStatePool:
         self._insert_blob = obs.wrap_jit(self._insert_blob,
                                          "pool.resume_insert")
         self.placement.metrics = obs.metrics
+        for how, n in self.state_slab_leaves.items():
+            obs.metrics.gauge(f"state_slab_leaves_{how}").set(n)
 
     def _span(self, name: str):
         """A span of the attached engine's trace (none on a bare pool)."""

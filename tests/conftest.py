@@ -62,3 +62,24 @@ def greedy_reference(params, cfg, prompt, n_new):
                                 lengths=lengths + step, seed=step + 1)
         out.append(int(jnp.argmax(logits[0])))
     return out
+
+
+#: suffix of a test architecture name that asks for :func:`cell_state_config`
+CELL = "@cell"
+
+
+def cell_state_config(arch, backend="pallas"):
+    """``arch``'s smoke model with the recurrent state of its chat cell: 80
+    Mamba-2 heads of 64 at the published ``d_state`` (MX8, stochastic
+    rounding), reached from the smoke width by a wider in-projection.
+    Depth, width and vocabulary stay cut for the CPU."""
+    import dataclasses
+
+    from repro.configs import get_config, get_smoke_config
+    from repro.core.state_update import StateQuantConfig
+
+    cfg = get_smoke_config(arch)
+    ssm = dataclasses.replace(cfg.ssm, d_state=get_config(arch).ssm.d_state,
+                              head_dim=64, expand=80 * 64 // cfg.d_model)
+    return cfg.with_(ssm=ssm, state_quant=StateQuantConfig(
+        fmt="mx8", rounding="stochastic", backend=backend))

@@ -38,7 +38,8 @@ def _su_inputs(B, H, dk, dv, seed=0, dtype=jnp.float32):
     (1, 1, 128, 1040),     # mlstm-like augmented dv
     (3, 5, 128, 64),       # B·H = 15: no divisor but itself past 5
     (2, 80, 128, 64),      # mamba2 heads: several blocks of pairs
-    (2, 10, 256, 512),     # retnet width, vector decay, two dv tiles
+    (2, 10, 256, 512),     # retnet width, vector decay, two heads a step
+    (1, 1, 1024, 1024),    # a head past the budget: 128-row tiles
 ])
 @pytest.mark.parametrize("rounding", ["nearest", "stochastic"])
 def test_state_update_kernel_bitwise(B, H, dk, dv, rounding):
@@ -83,20 +84,37 @@ _SU_FAMILIES = {
 @pytest.mark.parametrize("family", sorted(_SU_FAMILIES))
 @pytest.mark.parametrize("B", [1, 8, 20])
 def test_state_update_block_rule(family, B):
-    """Each grid step takes the most (row, head) pairs that divide B·H and
-    fit the fast-memory budget, for every family's shape."""
+    """Each grid step takes the most heads of one batch row that divide H,
+    fit the fast-memory budget and span whole 128-lane columns of the
+    stored group bytes, for every family's shape; a call of B rows runs B
+    times one row's steps over every (row, head) pair once."""
     H, dk, dv = _SU_FAMILIES[family]
-    dv_blk, rows, grid = plan_blocks(B * H, dv, dk)
-    assert (B * H) % rows == 0
-    assert block_bytes(rows, dv_blk, dk) <= VMEM_BUDGET
-    assert grid == (B * H // rows, dv // dv_blk)
-    larger = [r for r in range(rows + 1, B * H + 1) if (B * H) % r == 0]
-    assert all(block_bytes(r, dv_blk, dk) > VMEM_BUDGET for r in larger)
+    plan = plan_blocks(H, dv, dk)
+    if family == "mlstm":
+        # 1040 rows of 1024 have no lane-aligned tile: the op runs the
+        # reference
+        assert plan is None
+        return
+    f, dv_blk, rows, grid = plan
+    width, stored_rows = f * dk, dv // f
+    assert f * dk <= max(dk, 128) and stored_rows % dv_blk == 0
+    assert H % rows == 0
+    assert block_bytes(rows, dv_blk, width) <= VMEM_BUDGET
+    assert grid == (H // rows, stored_rows // dv_blk)
+    assert B * grid[0] * rows == B * H
+    lane_dense = lambda r: r == H or (r * dv_blk) % 128 == 0
+    assert lane_dense(rows) or dv_blk < stored_rows
+    larger = [r for r in range(rows + 1, H + 1)
+              if H % r == 0 and lane_dense(r)]
+    assert all(block_bytes(r, dv_blk, width) > VMEM_BUDGET for r in larger)
 
 
 def test_state_update_block_rule_at_the_chat_cell():
-    """mamba2-2.7b at 20 decode rows: 40 pairs a step, 40 steps a call."""
-    assert plan_blocks(20 * 80, 64, 128) == (64, 40, (40, 1))
+    """mamba2-2.7b at the chat cell: 40 heads a step, 2 steps a row;
+    zamba2-2.7b's dk 64 stores two rows a 128-lane row, all 80 heads a
+    step."""
+    assert plan_blocks(80, 64, 128) == (1, 64, 40, (2, 1))
+    assert plan_blocks(80, 64, 64) == (2, 32, 80, (1, 1))
 
 
 def test_state_update_multi_step_matches_ref():

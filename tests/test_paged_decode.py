@@ -6,8 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import CELL, cell_state_config
+
 from repro import ops as OPS
 from repro.configs import get_smoke_config
+from repro.core import formats as F
 from repro.core.state_update import StateQuantConfig
 from repro.models import model as M
 from repro.serving.api import ServeConfig
@@ -16,9 +19,12 @@ from repro.serving.memory import PAGE_TOKENS, PagedStatePool, pages_for
 
 
 def _build(arch, fmt, backend, rounding):
-    cfg = get_smoke_config(arch).with_(
-        state_quant=StateQuantConfig(fmt=fmt, rounding=rounding,
-                                     backend=backend))
+    if arch.endswith(CELL):
+        cfg = cell_state_config(arch[:-len(CELL)], backend)
+    else:
+        cfg = get_smoke_config(arch).with_(
+            state_quant=StateQuantConfig(fmt=fmt, rounding=rounding,
+                                         backend=backend))
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     return params, cfg
 
@@ -66,6 +72,12 @@ PARITY_MATRIX = [
     (arch, "mx8", "pallas", L)
     for arch in ("deepseek-v2-236b", "zamba2-2.7b")
     for L in (127, 129)
+] + [
+    # the chat cells' state heads (80 of 64; dk 128 and 64), updated in
+    # place in the pool's stored layout
+    ("mamba2-2.7b" + CELL, "mx8", "pallas", 129),
+    ("mamba2-2.7b" + CELL, "mx8", "jnp", 129),
+    ("zamba2-2.7b" + CELL, "mx8", "pallas", 127),
 ]
 
 
@@ -96,6 +108,113 @@ def test_paged_decode_bit_identical_to_dense_gather(arch, fmt, backend,
     for step, (a, b) in enumerate(zip(ref, got)):
         np.testing.assert_array_equal(
             a, b, err_msg=f"{arch}/{fmt}/{backend}/L={length} step {step}")
+
+
+# state heads (H, dv, dk) of the kernel-level in-place check: the two chat
+# cells', the smoke models', and a retnet-like width of two row tiles
+IN_PLACE_HEADS = [(80, 64, 128), (80, 64, 64), (8, 16, 16), (2, 64, 32),
+                  (2, 512, 256)]
+
+
+@pytest.mark.parametrize("heads,dv,dk", IN_PLACE_HEADS,
+                         ids=[f"H{h}-dv{v}-dk{k}" for h, v, k in IN_PLACE_HEADS])
+def test_paged_state_update_in_place_matches_gathered_rows(heads, dv, dk):
+    """The in-place kernel over a slab pool equals the dense kernel on the
+    gathered rows, bit for bit: live slabs in no sorted order, pad rows all
+    on the scratch slab 0, one layer of two.  Slabs and layers no row owns
+    are left as they were; the stochastic-rounding counter is the batch
+    row's."""
+    from repro.kernels import mx_state_update as SU
+    from repro.ops import interpret_pallas
+    n_slabs, layer = 6, 1
+    slabs = jnp.asarray([4, 0, 2, 0, 5], jnp.int32)
+    live = np.asarray(slabs) != 0
+    B = slabs.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(dk), 6)
+    logical = F.mx8_quantize(jax.random.normal(
+        ks[0], (n_slabs, 2, heads, dv, dk)))
+    pool = F.QuantizedTensor("mx8", logical.shape, {
+        f: SU.store(f, a) for f, a in logical.payload.items()})
+    d = jax.nn.sigmoid(jax.random.normal(ks[1], (B, heads, 1)))
+    k = jax.random.normal(ks[2], (B, heads, dk))
+    v = jax.random.normal(ks[3], (B, heads, dv))
+    q = jax.random.normal(ks[4], (B, heads, dk))
+    seed = jnp.int32(17)
+
+    new_pool, y = SU.mx_paged_state_update(
+        pool, slabs, layer, d, k, v, q, seed, interpret=interpret_pallas())
+    rows = F.QuantizedTensor("mx8", (B, heads, dv, dk), {
+        f: a[slabs, layer] for f, a in logical.payload.items()})
+    ref_rows, ref_y = SU.mx_state_update(rows, d, k, v, q, seed,
+                                         interpret=interpret_pallas())
+    jnp_rows, _ = OPS.state_update_step(
+        rows, d, k, v, q, StateQuantConfig(fmt="mx8", rounding="stochastic",
+                                           backend="jnp"), seed=seed)
+    for f, a in new_pool.payload.items():
+        got = SU.unstore(f, a, heads, dv, dk)
+        want = logical.payload[f].at[slabs[live], layer].set(
+            ref_rows.payload[f][live])
+        untouched = np.ones(n_slabs, bool)
+        untouched[np.asarray(slabs)] = False
+        np.testing.assert_array_equal(got[untouched], want[untouched])
+        np.testing.assert_array_equal(got[:, 0], want[:, 0])
+        np.testing.assert_array_equal(got[slabs[live], layer],
+                                      ref_rows.payload[f][live], err_msg=f)
+        np.testing.assert_array_equal(ref_rows.payload[f],
+                                      jnp_rows.payload[f], err_msg=f)
+    np.testing.assert_array_equal(y[live], ref_y[live])
+
+
+def test_state_update_ops_fall_back_where_no_block_is_lane_aligned():
+    """A head whose stored rows have no 128-lane tile that fits the
+    kernel's budget (mlstm's 1040 rows of 1024) runs the jnp reference:
+    the pallas ops give its bits, and the paged op reports it gathers."""
+    from repro.kernels import mx_state_update as SU
+    H, dv, dk, B = 1, 1040, 1024, 2
+    assert SU.plan_blocks(H, dv, dk) is None
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    logical = F.mx8_quantize(jax.random.normal(ks[0], (3, 1, H, dv, dk)))
+    slabs = jnp.asarray([2, 1], jnp.int32)
+    rows = F.QuantizedTensor("mx8", (B, H, dv, dk), {
+        f: a[slabs, 0] for f, a in logical.payload.items()})
+    inputs = {"d": jax.nn.sigmoid(jax.random.normal(ks[1], (B, H, 1))),
+              "k": jax.random.normal(ks[2], (B, H, dk)),
+              "v": jax.random.normal(ks[3], (B, H, dv)),
+              "q": jax.random.normal(ks[4], (B, H, dk)), "seed": 9}
+    quant = lambda b: StateQuantConfig(fmt="mx8", rounding="stochastic",
+                                       backend=b)
+    want, y_want = OPS.state_update_step(rows, **inputs, cfg=quant("jnp"))
+    got, y_got = OPS.state_update_step(rows, **inputs, cfg=quant("pallas"))
+    pool = OPS.PagedState(F.QuantizedTensor("mx8", logical.shape, {
+        f: SU.store(f, a) for f, a in logical.payload.items()}),
+        slabs, jnp.int32(0))
+    paged, y_paged = OPS.state_update_step(pool, **inputs,
+                                           cfg=quant("pallas"))
+    for f in want.payload:
+        np.testing.assert_array_equal(got.payload[f], want.payload[f])
+        np.testing.assert_array_equal(
+            SU.unstore(f, paged.pool.payload[f][slabs, 0], H, dv, dk),
+            want.payload[f])
+    np.testing.assert_array_equal(y_got, y_want)
+    np.testing.assert_array_equal(y_paged, y_want)
+    assert not OPS.get_op("state_update", "pallas", "mx8",
+                          "paged").in_place(H, dv, dk)
+
+
+@pytest.mark.parametrize("backend,want", [("pallas", (1, 0)),
+                                          ("jnp", (0, 1))])
+def test_state_slab_gauges_count_in_place_updates(backend, want):
+    """The pool reports whether the decode step updates its recurrent-state
+    leaves in place (pallas) or gathers them (the jnp reference)."""
+    from repro.obs import Observability
+    cfg = get_smoke_config("mamba2-2.7b").with_(
+        state_quant=StateQuantConfig(fmt="mx8", rounding="stochastic",
+                                     backend=backend))
+    pool = PagedStatePool(cfg, n_pages=3, n_slabs=3)
+    obs = Observability()
+    pool.attach_obs(obs)
+    assert tuple(obs.metrics.value(f"state_slab_leaves_{how}")
+                 for how in ("in_place", "gathered")) == want
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import greedy_reference
+from conftest import CELL, cell_state_config, greedy_reference
 
 from repro.configs import get_smoke_config
 from repro.core import attention_cache as AC
@@ -177,6 +177,63 @@ def test_preemption_roundtrip_bit_identical_logits(tiny):
     np.testing.assert_array_equal(lg_a[0], lg_b[0])
     # placement may differ; identity must not depend on physical page ids
     assert len(pool.page_table[7]) == len(pages_before)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b" + CELL,
+                                  "zamba2-2.7b" + CELL])
+def test_state_slab_round_trips_bit_identical(arch):
+    """The recurrent state lives in the pool in the state-update kernel's
+    layout: a prefilled row reads back as it went in, a spilled and resumed
+    request and a fork's copied slab decode as the original does."""
+    cfg = cell_state_config(arch[:-len(CELL)])
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    pool = PagedStatePool(cfg, n_pages=9, n_slabs=5)
+    n = PAGE_TOKENS
+    prompt = np.random.default_rng(1).integers(0, cfg.vocab_size, n)
+    pr = jnp.asarray(prompt, jnp.int32)[None]
+    logits, row = jax.jit(lambda p, b: M.prefill(p, cfg, b))(
+        params, {"tokens": pr, "targets": pr})
+    assert pool.register(1, pages_for(n + 1))
+    pool.insert_prefill(1, row)
+    # insert_request stores the rows, the gather path reads them back
+    dense = pool.paging.gather(
+        pool.pools, jnp.asarray([pool.page_table[1]], jnp.int32),
+        jnp.asarray([pool.slab_of[1]], jnp.int32),
+        jnp.asarray([n], jnp.int32))
+    states = [(spec, a, b) for spec, a, b in zip(
+        pool.paging.specs, jax.tree.leaves(dense), jax.tree.leaves(row))
+        if spec.mx8_field]
+    assert len(states) == 3
+    for spec, a, b in states:
+        np.testing.assert_array_equal(a, b, err_msg=spec.mx8_field)
+
+    tok = np.array([int(jnp.argmax(logits[0])), 0], np.int32)
+    lengths = np.array([n, 0], np.int32)
+
+    def step(rid):
+        return np.asarray(pool.decode(params, [rid, None], tok, lengths,
+                                      seed=5))[0]
+
+    snapshot = [np.array(x) for x in pool.pools]
+    want = step(1)
+    pool.pools = [jnp.asarray(x) for x in snapshot]
+    # a fork at the page boundary copies the slab row alone; the child's
+    # first token opens a page of its own
+    assert pool.fork(1, 2, n) and pool.grow(2, 1)
+    slab_rows = lambda rid: [np.array(p[pool.slab_of[rid]]) for p, spec
+                             in zip(pool.pools, pool.paging.specs)
+                             if spec.kind == "slab"]
+    for a, b in zip(slab_rows(1), slab_rows(2)):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(step(2), want)
+    pool.release(2)
+    pool.pools = [jnp.asarray(x) for x in snapshot]
+    # spill to the host and resume into another slab
+    sp = pool.spill(1, n)
+    assert pool.register(3, 1)                 # take the freed slab
+    assert pool.resume(1, sp)
+    assert pool.slab_of[1] != 1 or pool.slab_of[3] != 1
+    np.testing.assert_array_equal(step(1), want)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import CELL, cell_state_config
 
 from repro.configs import get_smoke_config
 from repro.core.state_update import StateQuantConfig
@@ -35,9 +36,11 @@ _CACHE = {}
 def _build(arch, fmt="fp32", backend="jnp"):
     key = (arch, fmt, backend)
     if key not in _CACHE:
-        cfg = get_smoke_config(arch).with_(
-            state_quant=StateQuantConfig(fmt=fmt, rounding="nearest",
-                                         backend=backend))
+        # a cell's state heads run MX8 through the pallas kernel
+        cfg = (cell_state_config(arch[:-len(CELL)]) if arch.endswith(CELL)
+               else get_smoke_config(arch).with_(
+                   state_quant=StateQuantConfig(fmt=fmt, rounding="nearest",
+                                                backend=backend)))
         _CACHE[key] = (M.init_model(jax.random.PRNGKey(0), cfg), cfg)
     return _CACHE[key]
 
@@ -110,7 +113,8 @@ def test_spec_model_draft_greedy_bit_identical(arch, fmt, backend):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch,length", [("mamba2-2.7b", 127),
-                                         ("zamba2-2.7b", 128)])
+                                         ("zamba2-2.7b", 128),
+                                         ("mamba2-2.7b" + CELL, 127)])
 def test_spec_verify_positions_and_rollback_bit_exact(arch, length, n=3):
     """decode_spec position i's logits == the i-th sequential decode step,
     and commit_spec restores the state slab of *exactly* the selected

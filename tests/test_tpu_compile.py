@@ -10,9 +10,10 @@ results or times is checked.
 Widths: zamba2-2.7b's state update (80 Mamba-2 heads with dk = dv = 64)
 and paged attention at its published 32 KV heads of 160 stacked over its 9
 shared-block applications, and its whole paged decode step at the chat
-cell's 20 rows; mamba2-2.7b's state update (dk = 128) at 8 and at 20 rows.
-The attention kernels also compile at 32 heads of 80, a width whose
-exponent rows pad to more lanes.
+cell's 20 rows; mamba2-2.7b's state update (dk = 128) at 8 and at 20 rows,
+and its whole paged decode step at the chat cell's pool.  The attention
+kernels also compile at 32 heads of 80, a width whose exponent rows pad to
+more lanes.
 """
 import re
 
@@ -123,12 +124,11 @@ def test_paged_attn_decode_compiles_at_zamba2_width(one_chip):
              i32(20, 8), i32(), i32(20))
 
 
-def test_zamba2_paged_decode_step_compiles(one_chip, monkeypatch):
-    """The whole paged decode step of zamba2-2.7b at its published widths
-    (2.66 B parameters, 54 layers, 9 shared-block applications) over the
-    chat cell's pool: 20 rows, 141 pages, 21 slabs, 8-page block tables.
-    It fits v5e's 15.75 GB, and no K/V page pool is copied into another
-    layout around the kernels.  Shapes only: nothing is allocated."""
+def _paged_decode_step_hlo(arch, sharding, monkeypatch, rows=20,
+                           n_pages=141, n_slabs=21):
+    """The whole paged decode step of ``arch`` at its published widths over
+    a chat cell's pool (8-page block tables), compiled: (HLO text, memory
+    analysis, the cache paging).  Shapes only: nothing is allocated."""
     from repro.configs import get_config
     from repro.core.paged import PAGE_TOKENS
     from repro.models import model as M
@@ -137,19 +137,18 @@ def test_zamba2_paged_decode_step_compiles(one_chip, monkeypatch):
     from repro.serving.memory.layout import CachePaging
     for mod in (attention, paged_ops, spec_verify, state_update):
         monkeypatch.setattr(mod, "interpret_pallas", lambda: False)
-    cfg = get_config("zamba2-2.7b").with_(state_quant=StateQuantConfig(
+    cfg = get_config(arch).with_(state_quant=StateQuantConfig(
         fmt="mx8", rounding="stochastic", backend="pallas"))
-    rows, n_pages, n_slabs = 20, 141, 21
     paging = CachePaging(M.init_decode_caches(cfg, 1, PAGE_TOKENS),
                          M.abstract_decode_caches(cfg, 2, PAGE_TOKENS),
                          M.abstract_decode_caches(cfg, 1, 2 * PAGE_TOKENS))
     pools = [_sds(((n_pages if s.kind == "page" else n_slabs),)
-                  + s.stored_shape, s.dtype, one_chip)
+                  + s.stored_shape, s.dtype, sharding)
              for s in paging.specs]
     params = jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one_chip),
+        lambda a: _sds(a.shape, a.dtype, sharding),
         jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg)))
-    i32 = lambda *s: _sds(s, jnp.int32, one_chip)
+    i32 = lambda *s: _sds(s, jnp.int32, sharding)
 
     def step(params, pools, bt, slabs, lengths, tokens, seed):
         views = paging.paged_view(pools, bt, slabs, lengths)
@@ -161,13 +160,64 @@ def test_zamba2_paged_decode_step_compiles(one_chip, monkeypatch):
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pools, i32(rows, 8), i32(rows), i32(rows), i32(rows),
         i32()).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), compiled.memory_analysis(), paging
+
+
+#: what may produce a whole state pool or a batch of its rows: the step's
+#: parameters and loop plumbing, and the state-update kernel itself
+_STATE_POOL_OPS = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                   "custom-call", "while", "opt-barrier")
+
+
+def _state_pool_moves(text, paging, rows=20, n_slabs=21):
+    """Instructions whose result is an MX8 state payload pool, or ``rows``
+    rows of one (stored, or in the logical ``(rows, H, dv, c)`` order),
+    other than the kernel and the plumbing: the copies, gathers, scatters
+    and slices of a state path that relays the pool out."""
+    shapes = set()
+    for s in paging.specs:
+        if s.mx8_field:
+            lead, stored = s.content_shape[:-3], s.stored_shape[len(
+                s.content_shape) - 3:]
+            for shape in ((n_slabs,) + s.stored_shape,
+                          (n_slabs,) + s.content_shape,
+                          (rows,) + stored, (rows,) + s.content_shape[-3:],
+                          (rows,) + lead + stored):
+                shapes.add(",".join(map(str, shape)))
+    found = re.findall(r"= [us]8\[([\d,]+)\]\S* ([\w-]+)\(", text)
+    return [(shape, op) for shape, op in found
+            if shape in shapes and op not in _STATE_POOL_OPS]
+
+
+def test_zamba2_paged_decode_step_compiles(one_chip, monkeypatch):
+    """The whole paged decode step of zamba2-2.7b at its published widths
+    (2.66 B parameters, 54 layers, 9 shared-block applications) over the
+    chat cell's pool: 20 rows, 141 pages, 21 slabs, 8-page block tables.
+    It fits v5e's 15.75 GB, no K/V page pool is copied into another
+    layout around the kernels, and the MX8 state slabs are updated in the
+    pool: nothing copies, gathers or scatters them."""
+    text, mem, paging = _paged_decode_step_hlo("zamba2-2.7b", one_chip,
+                                               monkeypatch)
     for kind in ("state_update", "attn_decode", "kv_append"):
         assert f"spu_{kind}" in text
-    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
-    page_copies = re.findall(rf"= \w+\[{n_pages},\S* copy\(", text)
+    page_copies = re.findall(r"= \w+\[141,\S* copy\(", text)
     assert not page_copies, page_copies
+    moves = _state_pool_moves(text, paging)
+    assert not moves, moves
+
+
+def test_mamba2_paged_decode_step_compiles(one_chip, monkeypatch):
+    """The whole paged decode step of mamba2-2.7b (64 layers, 80 heads of
+    64 with dk 128) over the chat cell's pool: 20 rows, 21 slabs.  It fits
+    v5e's 15.75 GB, and the MX8 state slabs are updated in the pool:
+    nothing copies, gathers or scatters them."""
+    text, mem, paging = _paged_decode_step_hlo("mamba2-2.7b", one_chip,
+                                               monkeypatch)
+    assert "spu_state_update" in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    moves = _state_pool_moves(text, paging)
+    assert not moves, moves
 
 
 def test_paged_kv_append_compiles(one_chip):
